@@ -19,7 +19,7 @@ from repro.core import workloads as W
 from repro.core.experiment import ExperimentSpec, WorkloadSpec
 from repro.core.sim import SimParams
 
-from benchmarks.common import csv_row, save, timed
+from benchmarks.common import csv_row, enable_compile_cache, save, timed
 
 M = 256
 K_CLUSTERED = 16
@@ -80,4 +80,5 @@ def run(verbose: bool = True, ks=KS, pair_periods=PAIR_PERIODS,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

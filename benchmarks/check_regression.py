@@ -34,7 +34,7 @@ import argparse
 import json
 import sys
 
-from benchmarks.common import save
+from benchmarks.common import enable_compile_cache, save
 from benchmarks.topology_frontier import BENCH_PATH
 
 ROW_KEY = ("k", "topology", "queue_impl", "batch_pop")
@@ -173,4 +173,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -21,7 +21,8 @@ import numpy as np
 
 from repro.core.transport import TOPOLOGIES
 
-RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results")
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RESULTS_DIR = os.path.join(REPO_ROOT, "results")
 
 # JSON schema version of the benchmark payloads.  v2 added the "meta"
 # block (topology_meta below): results/*.json are self-describing about
@@ -64,6 +65,19 @@ RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results")
 # generalizes to beacons_rx + msgs_lost == (k-1)*beacons_tx +
 # retries_tx.  Existing keys are unchanged.
 SCHEMA_VERSION = 8
+
+
+def enable_compile_cache() -> None:
+    """Keep compiled programs in JAX's persistent cache, for entry points.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it and nothing is
+    set here.  Otherwise the cache lives at ``<repo>/.jax_cache/``: a fixed
+    path, because the path is part of every entry's key."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    jax.config.update("jax_compilation_cache_dir",
+                      os.path.join(REPO_ROOT, ".jax_cache"))
 
 
 def topology_meta(topologies=("ideal",), **extra) -> dict:

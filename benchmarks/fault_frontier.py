@@ -97,7 +97,8 @@ from repro.core.experiment import ExperimentSpec, WorkloadSpec
 from repro.core.faults import FaultSpec, gmn_outages
 from repro.core.sim import SimParams
 
-from benchmarks.common import (csv_row, determinism_digest, save, timed,
+from benchmarks.common import (csv_row, determinism_digest,
+                               enable_compile_cache, save, timed,
                                topology_meta)
 
 # The PR-2 frozen goldens (tests/test_sweep.py): the (dn_th x seed) grid
@@ -516,6 +517,7 @@ def _partition_links(k: int) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--grid", choices=sorted(GRIDS), default="tiny")
     args = ap.parse_args()

@@ -14,7 +14,7 @@ from repro.core import analytic as A
 from repro.core.experiment import ExperimentSpec, WorkloadSpec
 from repro.core.sim import SimParams
 
-from benchmarks.common import csv_row, save, timed
+from benchmarks.common import csv_row, enable_compile_cache, save, timed
 
 KS = (1, 2, 4, 8, 16, 32, 64, 128, 256)
 
@@ -51,4 +51,5 @@ def run(verbose: bool = True, ks=KS, c_s_values=(1.0, 8.0, 64.0)) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -11,7 +11,7 @@ import numpy as np
 from repro.core.experiment import ExperimentSpec, WorkloadSpec
 from repro.core.sim import SimParams
 
-from benchmarks.common import csv_row, save, timed
+from benchmarks.common import csv_row, enable_compile_cache, save, timed
 
 KS = (1, 8, 16, 32, 256)
 THRESHOLDS = (1, 2, 4, 8, 16, 32)
@@ -71,4 +71,5 @@ def run(verbose: bool = True, ks=KS, thresholds=THRESHOLDS,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -12,7 +12,7 @@ from __future__ import annotations
 from repro.core.experiment import ExperimentSpec, WorkloadSpec
 from repro.core.sim import SimParams
 
-from benchmarks.common import csv_row, save, timed
+from benchmarks.common import csv_row, enable_compile_cache, save, timed
 
 KS = (8, 16, 32, 64)
 THRESHOLDS = (1, 2, 4, 8, 16, 32)
@@ -60,4 +60,5 @@ def run(verbose: bool = True, ks=KS, thresholds=THRESHOLDS,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -13,7 +13,7 @@ import numpy as np
 from repro.configs import get_config, reduced_config
 from repro.models import moe as MOE
 
-from benchmarks.common import csv_row, save, timed
+from benchmarks.common import csv_row, enable_compile_cache, save, timed
 
 
 def run(verbose: bool = True) -> dict:
@@ -40,4 +40,5 @@ def run(verbose: bool = True) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

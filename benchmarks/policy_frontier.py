@@ -38,7 +38,8 @@ from repro.core.metrics import mean_response
 from repro.core.policies import BEACON_POLICIES, MAPPING_POLICIES
 from repro.core.sim import SimParams, run as sim_run
 
-from benchmarks.common import csv_row, save, timed, topology_meta
+from benchmarks.common import (csv_row, enable_compile_cache, save,
+                               timed, topology_meta)
 
 # Pair periods / arrival rates keep the offered load below 1
 # (workloads.offered_load): a saturated system backlogs until the event
@@ -240,6 +241,7 @@ def run(verbose: bool = True, grid: str = "default",
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--grid", choices=sorted(GRIDS), default="default")
     args = ap.parse_args()

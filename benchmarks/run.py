@@ -31,6 +31,8 @@ def main(argv=None) -> None:
                          "times")
     args = ap.parse_args(argv)
 
+    from benchmarks.common import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import baseline_compare, fig2a, fig2b, fig3a, fig3b, table5
     from benchmarks import fault_frontier, moe_balance, scheduler_overhead
     from benchmarks import topology_frontier

@@ -18,7 +18,7 @@ from repro.core.experiment import ExperimentSpec, WorkloadSpec
 from repro.core.sim import SimParams
 from repro.kernels import ops
 
-from benchmarks.common import csv_row, save
+from benchmarks.common import csv_row, enable_compile_cache, save
 
 
 def _bench(fn, *args, iters=20):
@@ -104,4 +104,5 @@ def run(verbose: bool = True, m: int = 256, n_tasks: int = 100) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -15,7 +15,7 @@ import numpy as np
 from repro.core.experiment import ExperimentSpec, WorkloadSpec
 from repro.core.sim import SimParams
 
-from benchmarks.common import csv_row, save, timed
+from benchmarks.common import csv_row, enable_compile_cache, save, timed
 
 PAPER = {1: 28.1, 8: 73.5, 16: 78.7, 256: 44.3}
 
@@ -59,4 +59,5 @@ def run(verbose: bool = True, sim_len: float = 4e6, seeds=(1, 2, 3)) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -74,7 +74,8 @@ from repro.core.sim import SimParams
 from repro.core.sim import run as sim_run
 from repro.core.transport import TOPOLOGIES
 
-from benchmarks.common import csv_row, save, timed, topology_meta
+from benchmarks.common import (csv_row, enable_compile_cache, save,
+                               timed, topology_meta)
 
 # PR 1 measured the sweep engine's marginal cost per design-space point
 # at 2.4 s (m=256, 4e6 ticks, ideal fabric, linear queue; CHANGES.md).
@@ -435,6 +436,7 @@ def run(verbose: bool = True, grid: str = "default",
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--grid", choices=sorted(GRIDS), default="default")
     args = ap.parse_args()

@@ -34,8 +34,8 @@ import numpy as np
 from repro.core.experiment import ExperimentSpec, WorkloadSpec
 from repro.core.trace import TraceSpec, validate_perfetto
 
-from benchmarks.common import RESULTS_DIR, csv_row, save, timed, \
-    topology_meta
+from benchmarks.common import RESULTS_DIR, csv_row, \
+    enable_compile_cache, save, timed, topology_meta
 from benchmarks.topology_frontier import GRIDS, _shape_for
 
 # ring sized for the CI tiers (tiny ~1k events, paper_tiny ~40k): the
@@ -177,6 +177,7 @@ def run(verbose: bool = True, grid: str = "paper_tiny") -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--grid", choices=sorted(GRIDS), default="paper_tiny")
     args = ap.parse_args()

@@ -510,10 +510,11 @@ class ExperimentSpec:
                                         self.trace)
                         pending.append((combo, wi, f, lanes, lens, out))
             for combo, wi, f, lanes, lens, out in pending:
+                dev = _device_of(out)
                 st = jax.tree.map(np.asarray, jax.block_until_ready(out))
                 groups.append(_GroupResult(combo, wi, lanes, st,
                                            np.asarray(lens), np.nan, None,
-                                           f))
+                                           f, dev))
         else:
             for combo in plan.combos:
                 for wi in range(len(self.workloads)):
@@ -525,17 +526,18 @@ class ExperimentSpec:
                             st = SW._sweep(combo.shape, self.knobs, arr,
                                            gmns, lens, sl, combo.policy,
                                            combo.topology, fs, self.trace)
+                            dev = _device_of(st)
                             st = jax.tree.map(np.asarray,
                                               jax.block_until_ready(st))
                             lane_walls = None
                         else:
-                            st, lane_walls = _exec_seq(
+                            st, lane_walls, dev = _exec_seq(
                                 combo, self.knobs, arr, gmns, lens, sl, fs,
                                 self.trace)
                         groups.append(_GroupResult(combo, wi, lanes, st,
                                                    np.asarray(lens),
                                                    time.time() - tg,
-                                                   lane_walls, f))
+                                                   lane_walls, f, dev))
         wall = time.time() - t0
         return ResultFrame(self, plan, requested, resolved, groups, wall,
                            SW.cache_size() - compiles0)
@@ -629,7 +631,7 @@ def _exec_seq(combo: StaticCombo, knobs: SimKnobs, arr, gmns, lens, sl,
     """Warm replays of the single-config program — the identical
     ``sim._run`` calls and (B, S)-stacking ``sweep(mode="seq")`` performs,
     with per-lane wall-clock recorded (lane 0 of a fresh group carries
-    the XLA compile)."""
+    the XLA compile).  Also returns the device the lanes ran on."""
     b, s = knobs.dn_th.shape[0], arr.shape[0]
     outs, lane_walls = [], []
     for i in range(b):
@@ -641,10 +643,18 @@ def _exec_seq(combo: StaticCombo, knobs: SimKnobs, arr, gmns, lens, sl,
                      combo.topology, faults, trace))
             lane_walls.append(time.time() - tl)
             outs.append(out)
+    dev = _device_of(outs[0])
     st = jax.tree.map(
         lambda *leaves: np.stack(leaves).reshape((b, s) + leaves[0].shape),
         *[jax.tree.map(np.asarray, o) for o in outs])
-    return st, lane_walls
+    return st, lane_walls, dev
+
+
+def _device_of(out) -> str:
+    """The one device a group's output lives on (read before the leaves
+    are copied to the host, where the placement is lost)."""
+    (dev,) = jax.tree.leaves(out)[0].devices()
+    return str(dev)
 
 
 # --------------------------------------------------------------------------
@@ -680,6 +690,7 @@ class _GroupResult:
     wall_s: float
     lane_wall_s: list | None            # B*S entries (seq mode) or None
     fault: object = None                # FaultSpec or None (no-fault)
+    device: str | None = None           # device the group's program ran on
 
     @property
     def fault_label(self) -> str:
@@ -875,9 +886,10 @@ class ResultFrame:
     # -- run manifest (per-group wall telemetry) --------------------------
 
     def manifest(self) -> dict:
-        """Per-group dispatch telemetry: coordinates, wall seconds, and
-        (seq mode) a compile/execute split estimated from lane walls —
-        lane 0 of a fresh group carries the XLA compile, so
+        """Per-group dispatch telemetry: coordinates, the device the group
+        ran on, wall seconds, and (seq mode) a compile/execute split
+        estimated from lane walls — lane 0 of a fresh group carries the
+        XLA compile, so
         ``compile_s_est = lane0 - median(warm lanes)``."""
         groups = []
         for g in self.groups:
@@ -886,6 +898,7 @@ class ResultFrame:
                 "coords": dict(g.combo.coords(), fault=g.fault_label),
                 "workload_index": g.workload_index,
                 "n_lanes": len(g.lanes),
+                "device": g.device,
                 "wall_s": None if np.isnan(g.wall_s) else float(g.wall_s),
                 "lane_wall_s": None if lw is None else [float(x)
                                                         for x in lw],

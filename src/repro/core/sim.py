@@ -1593,11 +1593,10 @@ def run(p: SimParams, arrivals, arrival_gmns, lengths, sim_len: float = 1e7,
 
 def compile_cache_size() -> int:
     """Number of XLA programs compiled for ``run`` (one per
-    (SimShape, SimPolicy, Topology) triple).
-    Relies on jit's private cache introspection; returns 0 if a future
-    JAX drops it (degrading compile-count reporting, not simulation)."""
-    counter = getattr(_run, "_cache_size", None)
-    return counter() if callable(counter) else 0
+    (SimShape, SimPolicy, Topology) triple), read from jit's private
+    cache introspection (the installed JAX has it; the no-recompile
+    tests fail loudly if a later one drops it)."""
+    return _run._cache_size()
 
 
 # --------------------------------------------------------------------------

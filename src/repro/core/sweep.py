@@ -298,11 +298,8 @@ def sweep_topologies(shape, knobs: SimKnobs, workload, topologies=None,
 def cache_size() -> int:
     """Total XLA programs compiled for sweeping: one per
     (SimShape, SimPolicy, B, S) in vmap mode plus one per
-    (SimShape, SimPolicy) in seq mode.  Returns only the seq count if a
-    future JAX drops jit's private cache introspection."""
-    counter = getattr(_sweep, "_cache_size", None)
-    vmap_count = counter() if callable(counter) else 0
-    return vmap_count + compile_cache_size()
+    (SimShape, SimPolicy) in seq mode."""
+    return _sweep._cache_size() + compile_cache_size()
 
 
 # Batched metrics (response_times, mean_response, speedup, beacons,

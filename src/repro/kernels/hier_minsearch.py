@@ -61,10 +61,12 @@ def assign_tasks(loads, costs, *, interpret=False):
         grid=(),
         in_specs=[
             pl.BlockSpec(loads.shape, lambda: (0,) * loads.ndim),
-            pl.BlockSpec(costs.shape, lambda: (0,)),
+            # per-task scalars are read and written one at a time with a
+            # dynamic index: Mosaic takes those only from/to SMEM
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((T, 2), lambda: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(loads.shape, lambda: (0,) * loads.ndim),
         ],
         out_shape=[

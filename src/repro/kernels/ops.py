@@ -17,14 +17,9 @@ import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 
-_INTERPRET_PALLAS = False   # tests flip this to exercise kernels on CPU
-
 
 def on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 # ==========================================================================

@@ -18,10 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams in jax 0.5; support both
-_compiler_params = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, h_scr, *,
                  chunk, block_d, n_state):
@@ -32,7 +28,7 @@ def _scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, h_scr, *,
         h_scr[...] = jnp.zeros_like(h_scr)
 
     a = a_ref[...].astype(jnp.float32)               # (bd, N)
-    d_skip = d_ref[...].astype(jnp.float32)          # (bd,)
+    d_skip = d_ref[0].astype(jnp.float32)            # (bd,)
 
     def step(t, h):
         xt = x_ref[0, t].astype(jnp.float32)         # (bd,)
@@ -63,7 +59,8 @@ def selective_scan(x, dt, A, Bc, Cc, D_skip, *, chunk=128, block_d=256,
     kernel = functools.partial(_scan_kernel, chunk=chunk, block_d=block_d,
                                n_state=N)
     # grid: (batch, channel-block) parallel, chunks sequential innermost so
-    # the state scratch legitimately carries across chunk steps.
+    # the state scratch legitimately carries across chunk steps.  D goes in
+    # as (1, Di): a 1-D operand gets an XLA tiling Mosaic refuses.
     return pl.pallas_call(
         kernel,
         grid=(B, nd, nc),
@@ -73,12 +70,12 @@ def selective_scan(x, dt, A, Bc, Cc, D_skip, *, chunk=128, block_d=256,
             pl.BlockSpec((block_d, N), lambda b, d, c: (d, 0)),            # A
             pl.BlockSpec((1, chunk, N), lambda b, d, c: (b, c, 0)),        # B
             pl.BlockSpec((1, chunk, N), lambda b, d, c: (b, c, 0)),        # C
-            pl.BlockSpec((block_d,), lambda b, d, c: (d,)),                # D
+            pl.BlockSpec((1, block_d), lambda b, d, c: (0, d)),            # D
         ],
         out_specs=pl.BlockSpec((1, chunk, block_d), lambda b, d, c: (b, c, d)),
         out_shape=jax.ShapeDtypeStruct((B, S, Di), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(x, dt, A, Bc, Cc, D_skip)
+    )(x, dt, A, Bc, Cc, D_skip.reshape(1, Di))

@@ -163,6 +163,8 @@ def test_pmap_dispatches_across_forced_host_devices():
         fp = spec.run(mode="pmap")
         fs = spec.run(mode="seq")
         assert fp.mode == "pmap"
+        devs = [g["device"] for g in fp.manifest()["groups"]]
+        assert len(set(devs)) == 2, devs
         for topo in ("ideal", "hier_tree"):
             a, b = fp.state(topology=topo), fs.state(topology=topo)
             assert all(np.array_equal(a[k], b[k]) for k in a), topo
