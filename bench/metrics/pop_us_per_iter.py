@@ -1,0 +1,7 @@
+"""Device self time per loop trip of the operations under the
+``sim.pop`` scope, copies left out, in us (``bench/scopes.py``)."""
+import scopes
+
+
+def read(run):
+    return scopes.per_trip_us(run, "sim.pop")

@@ -124,12 +124,15 @@ def reference_check(spec, frame) -> None:
         (rg,) = ref.groups
         want = {k: v[0, 0] for k, v in rg.state.items()}
         got = {k: v[ki, si] for k, v in g.state.items()}
-        common = sorted(set(want) & set(got))
+        # the loop's trip count differs by design: batch_pop groups a
+        # same-time BEACON_RX cohort into one trip
+        common = sorted(set(want) & set(got) - {"iterations"})
         bad = diff_leaves(want, got, common)
         say(f"reference k={shape.k}: {qi} queue, batch_pop=1, seq mode, "
             f"wall_s={rg.wall_s:.3f}, {len(common)} leaves compared, "
-            f"not compared (queue internals): "
-            f"{sorted(set(want) ^ set(got))}, mismatched: {bad}")
+            f"not compared (queue internals, loop trips): "
+            f"{sorted(set(want) ^ set(got) | {'iterations'})}, "
+            f"mismatched: {bad}")
         if bad:
             raise SystemExit(f"k={shape.k}: vmap lane differs from the "
                              f"singleton reference on {bad}")

@@ -492,53 +492,56 @@ class ExperimentSpec:
                               for s in built_]
             return f_cache[k]
 
-        t0 = time.time()
+        t0 = time.perf_counter()
         groups = []
         if resolved == "pmap":
+            # groups interleave here (all dispatched, then all gathered),
+            # so each step has its span and no group span encloses them
             devs = jax.devices()
             pending = []
             for gi, combo in enumerate(plan.combos):
                 dev = devs[gi % len(devs)]
                 for wi in range(len(self.workloads)):
-                    lanes, (arr, gmns, lens) = built(combo, wi)
                     for fi, f in enumerate(self.faults):
-                        kn, ar, gm, ln, sl_d, fs = jax.device_put(
-                            (self.knobs, arr, gmns, lens, sl,
-                             scheds(combo.shape.k)[fi]), dev)
-                        out = SW._sweep(combo.shape, kn, ar, gm, ln, sl_d,
-                                        combo.policy, combo.topology, fs,
-                                        self.trace)
+                        with _Span("experiment.build"):
+                            lanes, (arr, gmns, lens) = built(combo, wi)
+                            kn, ar, gm, ln, sl_d, fs = jax.device_put(
+                                (self.knobs, arr, gmns, lens, sl,
+                                 scheds(combo.shape.k)[fi]), dev)
+                        with _Span("experiment.dispatch"):
+                            out = SW._sweep(combo.shape, kn, ar, gm, ln,
+                                            sl_d, combo.policy,
+                                            combo.topology, fs, self.trace)
                         pending.append((combo, wi, f, lanes, lens, out))
             for combo, wi, f, lanes, lens, out in pending:
                 dev = _device_of(out)
-                st = jax.tree.map(np.asarray, jax.block_until_ready(out))
+                with _Span("experiment.execute"):
+                    out = jax.block_until_ready(out)
+                with _Span("experiment.fetch"):
+                    st = jax.tree.map(np.asarray, out)
                 groups.append(_GroupResult(combo, wi, lanes, st,
                                            np.asarray(lens), np.nan, None,
                                            f, dev))
         else:
             for combo in plan.combos:
                 for wi in range(len(self.workloads)):
-                    lanes, (arr, gmns, lens) = built(combo, wi)
                     for fi, f in enumerate(self.faults):
-                        fs = scheds(combo.shape.k)[fi]
-                        tg = time.time()
-                        if resolved == "vmap":
-                            st = SW._sweep(combo.shape, self.knobs, arr,
-                                           gmns, lens, sl, combo.policy,
-                                           combo.topology, fs, self.trace)
-                            dev = _device_of(st)
-                            st = jax.tree.map(np.asarray,
-                                              jax.block_until_ready(st))
-                            lane_walls = None
-                        else:
-                            st, lane_walls, dev = _exec_seq(
-                                combo, self.knobs, arr, gmns, lens, sl, fs,
-                                self.trace)
+                        with _Span("experiment.group"):
+                            with _Span("experiment.build"):
+                                lanes, (arr, gmns, lens) = built(combo, wi)
+                                fs = scheds(combo.shape.k)[fi]
+                            if resolved == "vmap":
+                                st, lane_walls, dev, wall = _exec_vmap(
+                                    combo, self.knobs, arr, gmns, lens, sl,
+                                    fs, self.trace)
+                            else:
+                                st, lane_walls, dev, wall = _exec_seq(
+                                    combo, self.knobs, arr, gmns, lens, sl,
+                                    fs, self.trace)
                         groups.append(_GroupResult(combo, wi, lanes, st,
-                                                   np.asarray(lens),
-                                                   time.time() - tg,
+                                                   np.asarray(lens), wall,
                                                    lane_walls, f, dev))
-        wall = time.time() - t0
+        wall = time.perf_counter() - t0
         return ResultFrame(self, plan, requested, resolved, groups, wall,
                            SW.cache_size() - compiles0)
 
@@ -626,28 +629,71 @@ def spec_from_dict(d: dict) -> ExperimentSpec:
         mode=d["mode"])
 
 
+class _Span:
+    """One step of a run, timed twice on one path: a
+    ``jax.profiler.TraceAnnotation`` (recorded only while the profiler
+    traces, on its clock) and ``time.perf_counter`` (``t0``/``t1``, the
+    clock of every wall time a ResultFrame reports)."""
+
+    def __init__(self, name: str):
+        self._annotation = jax.profiler.TraceAnnotation(name)
+        self.t0 = self.t1 = float("nan")
+
+    def __enter__(self) -> "_Span":
+        self._annotation.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.perf_counter()
+        return self._annotation.__exit__(*exc)
+
+
+def _exec_vmap(combo: StaticCombo, knobs: SimKnobs, arr, gmns, lens, sl,
+               faults=None, trace=None):
+    """One dispatch of the group's batched program.  Returns the state
+    on the host, no lane walls, the device, and the wall seconds from
+    dispatch to the state's arrival on the host."""
+    from repro.core import sweep as SW
+    with _Span("experiment.dispatch") as dispatch:
+        out = SW._sweep(combo.shape, knobs, arr, gmns, lens, sl,
+                        combo.policy, combo.topology, faults, trace)
+    dev = _device_of(out)
+    with _Span("experiment.execute"):
+        out = jax.block_until_ready(out)
+    with _Span("experiment.fetch") as fetch:
+        st = jax.tree.map(np.asarray, out)
+    return st, None, dev, fetch.t1 - dispatch.t0
+
+
 def _exec_seq(combo: StaticCombo, knobs: SimKnobs, arr, gmns, lens, sl,
               faults=None, trace=None):
     """Warm replays of the single-config program — the identical
     ``sim._run`` calls and (B, S)-stacking ``sweep(mode="seq")`` performs,
     with per-lane wall-clock recorded (lane 0 of a fresh group carries
-    the XLA compile).  Also returns the device the lanes ran on."""
+    the XLA compile).  Also returns the device the lanes ran on and the
+    wall seconds from the first dispatch to the stacked state."""
     b, s = knobs.dn_th.shape[0], arr.shape[0]
-    outs, lane_walls = [], []
+    outs, lane_walls, t_start = [], [], None
     for i in range(b):
         for j in range(s):
-            tl = time.time()
-            out = jax.block_until_ready(
-                _run(combo.shape, SimKnobs(*(leaf[i] for leaf in knobs)),
-                     arr[j], gmns[j], lens[j], sl, combo.policy,
-                     combo.topology, faults, trace))
-            lane_walls.append(time.time() - tl)
+            with _Span("experiment.dispatch") as dispatch:
+                out = _run(combo.shape,
+                           SimKnobs(*(leaf[i] for leaf in knobs)), arr[j],
+                           gmns[j], lens[j], sl, combo.policy,
+                           combo.topology, faults, trace)
+            with _Span("experiment.execute") as execute:
+                out = jax.block_until_ready(out)
+            lane_walls.append(execute.t1 - dispatch.t0)
+            t_start = dispatch.t0 if t_start is None else t_start
             outs.append(out)
     dev = _device_of(outs[0])
-    st = jax.tree.map(
-        lambda *leaves: np.stack(leaves).reshape((b, s) + leaves[0].shape),
-        *[jax.tree.map(np.asarray, o) for o in outs])
-    return st, lane_walls, dev
+    with _Span("experiment.fetch") as fetch:
+        st = jax.tree.map(
+            lambda *leaves: np.stack(leaves).reshape((b, s)
+                                                     + leaves[0].shape),
+            *[jax.tree.map(np.asarray, o) for o in outs])
+    return st, lane_walls, dev, fetch.t1 - t_start
 
 
 def _device_of(out) -> str:

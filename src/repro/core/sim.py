@@ -90,6 +90,7 @@ continuity with the published golden results.
 """
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from typing import NamedTuple
 
@@ -411,6 +412,11 @@ def make_state(p):
         "app_arrive": jnp.full((A,), INF),
         "app_done": jnp.full((A,), INF),
         "events_processed": jnp.zeros((), jnp.int32),
+        # loop trips: one pop, or one same-time BEACON_RX cohort of up
+        # to batch_pop events.  Under vmap a grid's trip count is the
+        # maximum over its lanes; a device trace divided by it gives the
+        # cost of one iteration.  Pure telemetry, like evq_peak.
+        "iterations": jnp.zeros((), jnp.int32),
         "dropped": jnp.zeros((), jnp.int32),
         # always-on queue-occupancy telemetry (DESIGN.md §14): the live
         # entry count and its high-water mark.  The peak candidate is
@@ -782,9 +788,10 @@ def _fire_beacon(st, p, g, t, fire, load_g):
     # come back in the staged fan record (a branch-region scatter into
     # a big buffer would copy the buffer — module comment above) and
     # the body applies them after the switch.
-    return jax.lax.cond(fire,
-                        lambda s: _beacon_fanout(s, p, g, t, fire, load_g),
-                        lambda s: (s, _fan_zero(p)), st)
+    with jax.named_scope("sim.fanout"):
+        return jax.lax.cond(
+            fire, lambda s: _beacon_fanout(s, p, g, t, fire, load_g),
+            lambda s: (s, _fan_zero(p)), st)
 
 
 def _beacon_fanout(st, p, g, t, fire, load_g):
@@ -1295,32 +1302,36 @@ def simulate(shape: SimShape, knobs: SimKnobs, arrivals, arrival_gmns,
     ``trace=None`` compiles the uninstrumented program)."""
     p = _Ctx(shape, knobs, policy, topology, faults_on=faults is not None,
              trace=trace)
-    st = make_state(p)
+    with jax.named_scope("sim.setup"):
+        st = make_state(p)
 
-    n_apps = arrivals.shape[0]
-    st = _bulk_push(st, p, arrivals < sim_len, arrivals, EV_ARRIVE,
-                    jnp.arange(n_apps), arrival_gmns,
-                    jnp.zeros((n_apps,), jnp.int32))
-    if faults is not None:
-        st = _push_faults(st, p, faults, sim_len)
-    hb_on = policy.beacon == "heartbeat"
-    if hb_on and p.k > 1:
-        # timer-driven beacon plane: one self-rescheduling EV_HEARTBEAT
-        # chain per GMN, first tick at T_b (a static policy value, so
-        # the frozen activity-driven programs never compile this)
-        hb_t = jnp.broadcast_to(jnp.asarray(p.T_b, jnp.float32), (p.k,))
-        st = _bulk_push(st, p, hb_t < sim_len, hb_t, EV_HEARTBEAT,
-                        jnp.arange(p.k), jnp.zeros((p.k,), jnp.int32),
-                        jnp.zeros((p.k,), jnp.int32))
-    # live-entry counter seed: everything accepted so far (the counter
-    # is pure telemetry — no simulation value reads it)
-    seeded = jnp.sum(arrivals < sim_len).astype(jnp.int32)
-    if faults is not None and faults.times.shape[0]:
-        seeded = seeded + jnp.sum(faults.times < sim_len).astype(jnp.int32)
-    if hb_on and p.k > 1:
-        seeded = seeded + jnp.sum(hb_t < sim_len).astype(jnp.int32)
-    st["evq_len"] = seeded - st["dropped"]
-    st["evq_peak"] = st["evq_len"]
+        n_apps = arrivals.shape[0]
+        st = _bulk_push(st, p, arrivals < sim_len, arrivals, EV_ARRIVE,
+                        jnp.arange(n_apps), arrival_gmns,
+                        jnp.zeros((n_apps,), jnp.int32))
+        if faults is not None:
+            st = _push_faults(st, p, faults, sim_len)
+        hb_on = policy.beacon == "heartbeat"
+        if hb_on and p.k > 1:
+            # timer-driven beacon plane: one self-rescheduling
+            # EV_HEARTBEAT chain per GMN, first tick at T_b (a static
+            # policy value, so the frozen activity-driven programs never
+            # compile this)
+            hb_t = jnp.broadcast_to(jnp.asarray(p.T_b, jnp.float32),
+                                    (p.k,))
+            st = _bulk_push(st, p, hb_t < sim_len, hb_t, EV_HEARTBEAT,
+                            jnp.arange(p.k), jnp.zeros((p.k,), jnp.int32),
+                            jnp.zeros((p.k,), jnp.int32))
+        # live-entry counter seed: everything accepted so far (the
+        # counter is pure telemetry — no simulation value reads it)
+        seeded = jnp.sum(arrivals < sim_len).astype(jnp.int32)
+        if faults is not None and faults.times.shape[0]:
+            seeded = seeded + jnp.sum(faults.times < sim_len) \
+                .astype(jnp.int32)
+        if hb_on and p.k > 1:
+            seeded = seeded + jnp.sum(hb_t < sim_len).astype(jnp.int32)
+        st["evq_len"] = seeded - st["dropped"]
+        st["evq_peak"] = st["evq_len"]
 
     qi = p.queue_impl
     if qi in ("tree", "calendar"):
@@ -1336,12 +1347,17 @@ def simulate(shape: SimShape, knobs: SimKnobs, arrivals, arrival_gmns,
     # big-matrix writes happen AFTER the switch (see the fused-commit
     # staging block above): a scatter into a big buffer inside a branch
     # region forces XLA:CPU to copy the whole buffer every iteration.
+    # (scope name, handler) per event type; the body runs each handler
+    # under the named scope "sim.handlers.<name>", and the structural
+    # placeholders (name None) under none.
+    none = (None, lambda s, t, a: (s, _stage_none(p)))
     branches = [
-        lambda s, t, a: _handle_arrive(s, p, t, a[0], a[1], a[2], lengths),
-        lambda s, t, a: _handle_local_spawn(s, p, t, a[0], a[1], a[2],
-                                            lengths),
-        lambda s, t, a: _handle_join_exit(s, p, t, a[0], a[1], a[2], lengths,
-                                          arrival_gmns),
+        ("arrive", lambda s, t, a: _handle_arrive(s, p, t, a[0], a[1], a[2],
+                                                  lengths)),
+        ("local_spawn", lambda s, t, a: _handle_local_spawn(
+            s, p, t, a[0], a[1], a[2], lengths)),
+        ("join_exit", lambda s, t, a: _handle_join_exit(
+            s, p, t, a[0], a[1], a[2], lengths, arrival_gmns)),
     ]
     rx_on = topology.kind != "ideal"
     if rx_on or p.faults_on or hb_on:
@@ -1349,97 +1365,99 @@ def simulate(shape: SimShape, knobs: SimKnobs, arrivals, arrival_gmns,
         # vectorized OUTSIDE the switch (_handle_beacon_rx_batch) so same-
         # timestamp deliveries batch; slot 3 stays a structural placeholder
         # keeping the fault event types fixed at 4..7 even under ideal.
-        branches.append(lambda s, t, a: (s, _stage_none(p)))
+        branches.append(none)
     if p.faults_on:
         branches += [
-            lambda s, t, a: (_handle_link_down(s, p, t, a[0], a[1]),
-                             _stage_none(p)),
-            lambda s, t, a: (_handle_link_up(s, p, t, a[0], a[1]),
-                             _stage_none(p)),
-            lambda s, t, a: (_handle_gmn_fail(s, p, t, a[0]),
-                             _stage_none(p)),
-            lambda s, t, a: _handle_gmn_heal(s, p, t, a[0]),
+            ("link_down", lambda s, t, a: (
+                _handle_link_down(s, p, t, a[0], a[1]), _stage_none(p))),
+            ("link_up", lambda s, t, a: (
+                _handle_link_up(s, p, t, a[0], a[1]), _stage_none(p))),
+            ("gmn_fail", lambda s, t, a: (
+                _handle_gmn_fail(s, p, t, a[0]), _stage_none(p))),
+            ("gmn_heal", lambda s, t, a: _handle_gmn_heal(s, p, t, a[0])),
         ]
     elif hb_on:
         # fault slots 4..7 as structural placeholders so EV_HEARTBEAT
         # keeps its fixed index 8 on fault-free heartbeat programs
-        branches += [lambda s, t, a: (s, _stage_none(p))] * 4
+        branches += [none] * 4
     if hb_on:
-        branches.append(
-            lambda s, t, a: _handle_heartbeat(s, p, t, a[0], sim_len))
+        branches.append(("heartbeat", lambda s, t, a: _handle_heartbeat(
+            s, p, t, a[0], sim_len)))
 
     bp = p.batch_pop if rx_on else 1    # only BEACON_RX events batch
     q = p.queue_cap
 
     def body(st):
-        # -- root read (the root row IS the event; no payload gathers) ----
-        # Tree/calendar read the small evq_root mirror, NOT the queue
-        # buffer: tree slices get rematerialized by fusion at their
-        # consumers, and a consumer sitting after the commit chain's
-        # first in-place write forces copy-insertion to clone the whole
-        # buffer every iteration.
-        if qi in ("tree", "calendar"):
-            root = st["evq_root"]
-            t = root[0]
-            slot = root[1].astype(jnp.int32)
-            typ = root[2].astype(jnp.int32)
-            a = root[3:].astype(jnp.int32)
-        else:
-            slot = jnp.argmin(st["ev_time"]).astype(jnp.int32)  # O(Q)
-            t = st["ev_time"][slot]
-            typ = st["ev_type"][slot]
-            a = st["ev_a"][slot]
+        with jax.named_scope("sim.pop"):
+            # -- root read (the root row IS the event; no payload gathers) ----
+            # Tree/calendar read the small evq_root mirror, NOT the queue
+            # buffer: tree slices get rematerialized by fusion at their
+            # consumers, and a consumer sitting after the commit chain's
+            # first in-place write forces copy-insertion to clone the whole
+            # buffer every iteration.
+            if qi in ("tree", "calendar"):
+                root = st["evq_root"]
+                t = root[0]
+                slot = root[1].astype(jnp.int32)
+                typ = root[2].astype(jnp.int32)
+                a = root[3:].astype(jnp.int32)
+            else:
+                slot = jnp.argmin(st["ev_time"]).astype(jnp.int32)  # O(Q)
+                t = st["ev_time"][slot]
+                typ = st["ev_type"][slot]
+                a = st["ev_a"][slot]
 
-        # -- same-timestamp BEACON_RX batch selection ---------------------
-        if bp > 1:
-            # The O(Q) cohort scan AND the lane payload gathers live
-            # inside the cond: non-RX iterations skip them, and the
-            # conditional boundary materializes the results so no
-            # consumer re-reads the queue buffer later in the body
-            # (reads in a cond branch are safe — only scatters force
-            # branch-region buffer copies).
-            def _rx_cohort():
-                if qi == "tree":
-                    lt = EQ.leaf_times(st)[:q]
-                    lpay = EQ.leaf_payloads(st)[:q]
-                elif qi == "calendar":
-                    lt = EQ.cal_leaf_times(st, q)
-                    lpay = EQ.cal_leaf_payloads(st, q)
-                else:
-                    lt = st["ev_time"]
-                    lpay = jnp.concatenate(
-                        [st["ev_type"][:, None].astype(jnp.float32),
-                         st["ev_a"].astype(jnp.float32)], -1)
-                sel, ok = EQ.batch_take(lt, lpay[:, 0], t, slot,
-                                        EV_BEACON_RX, bp)
-                return sel, ok, lpay[sel].astype(jnp.int32)
+            # -- same-timestamp BEACON_RX batch selection ---------------------
+            if bp > 1:
+                # The O(Q) cohort scan AND the lane payload gathers live
+                # inside the cond: non-RX iterations skip them, and the
+                # conditional boundary materializes the results so no
+                # consumer re-reads the queue buffer later in the body
+                # (reads in a cond branch are safe — only scatters force
+                # branch-region buffer copies).
+                def _rx_cohort():
+                    if qi == "tree":
+                        lt = EQ.leaf_times(st)[:q]
+                        lpay = EQ.leaf_payloads(st)[:q]
+                    elif qi == "calendar":
+                        lt = EQ.cal_leaf_times(st, q)
+                        lpay = EQ.cal_leaf_payloads(st, q)
+                    else:
+                        lt = st["ev_time"]
+                        lpay = jnp.concatenate(
+                            [st["ev_type"][:, None].astype(jnp.float32),
+                             st["ev_a"].astype(jnp.float32)], -1)
+                    sel, ok = EQ.batch_take(lt, lpay[:, 0], t, slot,
+                                            EV_BEACON_RX, bp)
+                    return sel, ok, lpay[sel].astype(jnp.int32)
 
-            def _singleton():
-                lanes = jnp.zeros((bp, 4), jnp.int32) \
-                    .at[0].set(jnp.concatenate([typ[None], a]))
-                return (jnp.zeros((bp,), jnp.int32).at[0].set(slot),
-                        jnp.arange(bp) < 1, lanes)
+                def _singleton():
+                    lanes = jnp.zeros((bp, 4), jnp.int32) \
+                        .at[0].set(jnp.concatenate([typ[None], a]))
+                    return (jnp.zeros((bp,), jnp.int32).at[0].set(slot),
+                            jnp.arange(bp) < 1, lanes)
 
-            slots, okl, lanes = jax.lax.cond(
-                typ == EV_BEACON_RX, _rx_cohort, _singleton)
-            lane_typ, la0, la1, la2 = (lanes[:, 0], lanes[:, 1],
-                                       lanes[:, 2], lanes[:, 3])
-        else:
-            slots = slot[None]
-            okl = jnp.ones((1,), bool)
-            lane_typ, la0, la1, la2 = (typ[None], a[0][None], a[1][None],
-                                       a[2][None])
+                slots, okl, lanes = jax.lax.cond(
+                    typ == EV_BEACON_RX, _rx_cohort, _singleton)
+                lane_typ, la0, la1, la2 = (lanes[:, 0], lanes[:, 1],
+                                           lanes[:, 2], lanes[:, 3])
+            else:
+                slots = slot[None]
+                okl = jnp.ones((1,), bool)
+                lane_typ, la0, la1, la2 = (typ[None], a[0][None], a[1][None],
+                                           a[2][None])
 
-        st = dict(st)
-        # queue high-water mark, sampled BEFORE the pop: every batch_pop
-        # grouping and queue impl visits this point with the same value
-        # (intermediate singleton iterations inside a same-timestamp
-        # cohort only ever see smaller queues), so the counter stays
-        # bitwise-invariant across the head-to-head gates
-        st["evq_peak"] = jnp.maximum(st["evq_peak"], st["evq_len"])
-        ml0 = st["mgmt_latency"]        # per-iteration delta -> ring lat
-        st["events_processed"] = st["events_processed"] + \
-            jnp.sum(okl).astype(jnp.int32)
+            st = dict(st)
+            # queue high-water mark, sampled BEFORE the pop: every batch_pop
+            # grouping and queue impl visits this point with the same value
+            # (intermediate singleton iterations inside a same-timestamp
+            # cohort only ever see smaller queues), so the counter stays
+            # bitwise-invariant across the head-to-head gates
+            st["evq_peak"] = jnp.maximum(st["evq_peak"], st["evq_len"])
+            ml0 = st["mgmt_latency"]        # per-iteration delta -> ring lat
+            st["events_processed"] = st["events_processed"] + \
+                jnp.sum(okl).astype(jnp.int32)
+            st["iterations"] = st["iterations"] + 1
 
         # -- handlers -----------------------------------------------------
         if rx_on or p.faults_on:
@@ -1447,8 +1465,9 @@ def simulate(shape: SimShape, knobs: SimKnobs, arrivals, arrival_gmns,
             # The fault program compiles it in even under "ideal": retry
             # re-beacons (DESIGN.md §15) are BEACON_RX events on every
             # fabric (none exist when retries are off — bitwise no-op).
-            st = _handle_beacon_rx_batch(st, p, t, okl, lane_typ,
-                                         la0, la1, la2)
+            with jax.named_scope("sim.rx"):
+                st = _handle_beacon_rx_batch(st, p, t, okl, lane_typ,
+                                             la0, la1, la2)
         if p.faults_on:
             # failure detector (DESIGN.md §15): refresh the traced
             # (k, k) suspicion matrix at every event pop, after beacon
@@ -1459,35 +1478,36 @@ def simulate(shape: SimShape, knobs: SimKnobs, arrivals, arrival_gmns,
             # accounting run against the ground-truth masks.  Compiled
             # only into the fault-aware program and written only to its
             # own leaves, so every pre-detector golden stays bitwise.
-            peers = jnp.logical_not(jnp.eye(p.k, dtype=bool))
-            # receipt clocks floored at the suspector's detector epoch
-            # (rejoin reset — see det_floor in make_state); a dead
-            # manager runs no detector, so its whole row is masked.
-            # Both gates are exact no-ops on an all-up fabric.
-            seen = jnp.maximum(st["view_t"], st["det_floor"][:, None])
-            sus = jnp.logical_and((t - seen) > p.susp_mult * p.T_b, peers)
-            sus = jnp.logical_and(sus, st["gmn_alive"][:, None] > 0)
-            # the detector is defined only on the observation window
-            # [0, sim_len): both beacon planes stop generating traffic
-            # at sim_len while the event queue drains its workload tail
-            # (possibly far) past it, and staleness measured against a
-            # deliberately silenced fabric would manufacture false
-            # positives.  Freeze the matrix at its end-of-window value.
-            sus = jnp.where(t < sim_len, sus, st["suspect"] > 0)
-            prev = st["suspect"] > 0
-            onset = jnp.logical_and(sus, jnp.logical_not(prev))
-            clear = jnp.logical_and(prev, jnp.logical_not(sus))
-            # ground truth: peer c is *actually fine* from g's
-            # standpoint when c is alive and the (c -> g) beacon
-            # direction is up — a suspicion onset against a fine peer
-            # is a false positive
-            truth_ok = jnp.logical_and(st["gmn_alive"][None, :] > 0,
-                                       jnp.transpose(st["link_up"]) > 0)
-            st["suspect"] = sus.astype(jnp.float32)
-            st["susp_onsets"] = st["susp_onsets"] + onset.astype(jnp.int32)
-            st["susp_clears"] = st["susp_clears"] + clear.astype(jnp.int32)
-            st["susp_false_pos"] = st["susp_false_pos"] + jnp.sum(
-                jnp.logical_and(onset, truth_ok)).astype(jnp.int32)
+            with jax.named_scope("sim.detector"):
+                peers = jnp.logical_not(jnp.eye(p.k, dtype=bool))
+                # receipt clocks floored at the suspector's detector epoch
+                # (rejoin reset — see det_floor in make_state); a dead
+                # manager runs no detector, so its whole row is masked.
+                # Both gates are exact no-ops on an all-up fabric.
+                seen = jnp.maximum(st["view_t"], st["det_floor"][:, None])
+                sus = jnp.logical_and((t - seen) > p.susp_mult * p.T_b, peers)
+                sus = jnp.logical_and(sus, st["gmn_alive"][:, None] > 0)
+                # the detector is defined only on the observation window
+                # [0, sim_len): both beacon planes stop generating traffic
+                # at sim_len while the event queue drains its workload tail
+                # (possibly far) past it, and staleness measured against a
+                # deliberately silenced fabric would manufacture false
+                # positives.  Freeze the matrix at its end-of-window value.
+                sus = jnp.where(t < sim_len, sus, st["suspect"] > 0)
+                prev = st["suspect"] > 0
+                onset = jnp.logical_and(sus, jnp.logical_not(prev))
+                clear = jnp.logical_and(prev, jnp.logical_not(sus))
+                # ground truth: peer c is *actually fine* from g's
+                # standpoint when c is alive and the (c -> g) beacon
+                # direction is up — a suspicion onset against a fine peer
+                # is a false positive
+                truth_ok = jnp.logical_and(st["gmn_alive"][None, :] > 0,
+                                           jnp.transpose(st["link_up"]) > 0)
+                st["suspect"] = sus.astype(jnp.float32)
+                st["susp_onsets"] = st["susp_onsets"] + onset.astype(jnp.int32)
+                st["susp_clears"] = st["susp_clears"] + clear.astype(jnp.int32)
+                st["susp_false_pos"] = st["susp_false_pos"] + jnp.sum(
+                    jnp.logical_and(onset, truth_ok)).astype(jnp.int32)
         # Hold the big read-only buffers OUT of the switch outputs: a
         # buffer that is both operand and output of a conditional pays a
         # full copy in the executed branch even when no branch writes it.
@@ -1515,43 +1535,48 @@ def simulate(shape: SimShape, knobs: SimKnobs, arrivals, arrival_gmns,
                           "tl_load", "tl_qdepth"]
         held = {kk: st[kk] for kk in held_keys if kk in st}
 
-        def wrap(b):
+        def wrap(name, b):
             def f(s):
-                s2, stg2 = b(s, t, a)
+                with (jax.named_scope(f"sim.handlers.{name}") if name
+                      else contextlib.nullcontext()):
+                    s2, stg2 = b(s, t, a)
                 return {k2: v for k2, v in s2.items()
                         if k2 not in held}, stg2
             return f
 
-        out, stg = jax.lax.switch(typ, [wrap(b) for b in branches], st)
-        st = {**out, **held}
-        st = _apply_staged(st, p, stg)
+        with jax.named_scope("sim.handlers"):
+            out, stg = jax.lax.switch(typ, [wrap(*b) for b in branches], st)
+            st = {**out, **held}
+            st = _apply_staged(st, p, stg)
 
         # -- fused end-of-body commit: pop(s) + all pushes ----------------
-        pm, pt = stg["push_mask"], stg["push_t"]
-        pty = stg["push_typ"]
-        pa0, pa1, pa2 = stg["push_a0"], stg["push_a1"], stg["push_a2"]
-        d0 = st["dropped"]
-        if qi == "tree":
-            st = EQ.commit(st, slots, okl, pm, pt, pty, pa0, pa1, pa2,
-                           p.qdepth, q)
-        elif qi == "calendar":
-            st = EQ.cal_commit(st, slots, okl, t, pm, pt, pty, pa0, pa1,
-                               pa2, q, p.cal_width)
-        else:
-            st["ev_time"] = st["ev_time"].at[
-                jnp.where(okl, slots, q)].set(INF, mode="drop")
-            st = _bulk_push(st, p, pm, pt, pty, pa0, pa1, pa2)
-        # live-entry accounting: accepted pushes minus retired pops
-        accepted = jnp.sum(pm).astype(jnp.int32) - (st["dropped"] - d0)
-        st["evq_len"] = st["evq_len"] + accepted \
-            - jnp.sum(okl).astype(jnp.int32)
+        with jax.named_scope("sim.commit"):
+            pm, pt = stg["push_mask"], stg["push_t"]
+            pty = stg["push_typ"]
+            pa0, pa1, pa2 = stg["push_a0"], stg["push_a1"], stg["push_a2"]
+            d0 = st["dropped"]
+            if qi == "tree":
+                st = EQ.commit(st, slots, okl, pm, pt, pty, pa0, pa1, pa2,
+                               p.qdepth, q)
+            elif qi == "calendar":
+                st = EQ.cal_commit(st, slots, okl, t, pm, pt, pty, pa0, pa1,
+                                   pa2, q, p.cal_width)
+            else:
+                st["ev_time"] = st["ev_time"].at[
+                    jnp.where(okl, slots, q)].set(INF, mode="drop")
+                st = _bulk_push(st, p, pm, pt, pty, pa0, pa1, pa2)
+            # live-entry accounting: accepted pushes minus retired pops
+            accepted = jnp.sum(pm).astype(jnp.int32) - (st["dropped"] - d0)
+            st["evq_len"] = st["evq_len"] + accepted \
+                - jnp.sum(okl).astype(jnp.int32)
         if p.trace is not None:
             # straight-line end-of-body instrumentation (DESIGN.md §14):
             # ring append next to the fused commit, then at most one
             # timeline row on the events_processed stride
-            st = TR.ring_commit(st, p.trace, t, okl, slots, lane_typ,
-                                la0, la1, st["mgmt_latency"] - ml0)
-            st = TR.timeline_sample(st, p.trace, t)
+            with jax.named_scope("sim.trace_ring"):
+                st = TR.ring_commit(st, p.trace, t, okl, slots, lane_typ,
+                                    la0, la1, st["mgmt_latency"] - ml0)
+                st = TR.timeline_sample(st, p.trace, t)
         return st
 
     return jax.lax.while_loop(cond, body, st)
