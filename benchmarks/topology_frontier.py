@@ -34,13 +34,13 @@ port.  The tree-vs-linear bitwise gate rides the declarative
 Grid tiers (schema v4, benchmarks/README.md):
 
   tiny        CI smoke at m=16, every fabric, linear queue.
-  paper_tiny  CI proxy for the paper grid at m=64 with the tournament-
-              tree queue (``queue_impl="tree"``, core/eventq.py) and
+  paper_tiny  CI proxy for the paper grid at m=64 with the tree
+              queue (``queue_impl="tree"``, core/eventq.py) and
               the fused same-timestamp batch window (batch_pop=64).
   default     the PR-3 m=64 saturation-regime grid (c_s raised
               uniformly), unchanged for trajectory continuity.
   paper       the true paper scale: m=256, k ∈ {1, 16, 32, 256} across
-              ideal/hier_tree/mesh2d on the tournament-tree queue with
+              ideal/hier_tree/mesh2d on the tree queue with
               batch_pop=64 (DESIGN.md §11's measured sweet spot).
 
 Every row reports ``events`` / ``events_per_sec`` / ``wall_s`` (total
@@ -130,7 +130,7 @@ GRIDS = {
                  c_s=256.0, dn_th=4, sim_len=4e5,
                  pair_periods=(33_000.0,), seeds=(0,),
                  queue_impl="linear", topologies=TOPOLOGIES),
-    # CI proxy for the paper grid: small Q, m=64, tournament-tree queue
+    # CI proxy for the paper grid: small Q, m=64, tree queue
     "paper_tiny": dict(m=64, ks=(1, 8, 64), n_childs=50, max_apps=128,
                        queue_cap={64: 4096}, default_queue_cap=2048,
                        c_s=40.0, dn_th=4, sim_len=4e5,
@@ -145,7 +145,7 @@ GRIDS = {
     # the true paper scale (Sec 5 / Table 5): m=256 with the calibrated
     # interference stimulus; k=256 is the fully-distributed extreme whose
     # 255-wide beacon fan-out (hundreds of thousands of BEACON_RX
-    # events through a 32k-slot queue) needs the tournament-tree queue
+    # events through a 32k-slot queue) needs the tree queue
     "paper": dict(m=256, ks=(1, 16, 32, 256), n_childs=100, max_apps=64,
                   queue_cap={256: 32768}, default_queue_cap=8192,
                   c_s=8.0, dn_th=4, sim_len=1e6,
@@ -307,7 +307,7 @@ def run(verbose: bool = True, grid: str = "default",
 
     # bitwise anchor: the ideal row's first lane reproduces a direct
     # (topology- and queue-default) sim.run — neither the transport
-    # subsystem nor the tournament-tree queue is visible until opted into
+    # subsystem nor the tree queue is visible until opted into
     pd = SimParams(m=m, k=clustered, n_childs=g["n_childs"],
                    max_apps=g["max_apps"], c_s=g["c_s"], dn_th=g["dn_th"],
                    queue_cap=g["queue_cap"].get(clustered,

@@ -2,7 +2,7 @@
 
 The §11 discovery: a scatter that XLA cannot prove in-place is "demoted"
 to a whole-buffer ``copy`` per loop iteration, silently turning the
-O(log Q) tournament-tree pop into O(Q) traffic.  PR 7 killed the copies
+O(1) root-row pop into O(Q) traffic.  PR 7 killed the copies
 with the fused end-of-body commit + the ``evq_root`` mirror; until now
 the only guard was a 0.7x throughput gate.  This pass checks the
 compiled artifact directly:

@@ -5,8 +5,7 @@ The PR-7 commit chains (``eventq.commit``, ``eventq.cal_commit``,
 rests on a per-scatter disjointness discipline: within ONE ``.set``
 call, duplicate indices have unspecified write order in XLA, so every
 ``.set`` must either write unique positions or write *identical values*
-to every duplicated position (the level-repair idempotence claim in
-eventq.py).  ``.add``/``.min``/``.max`` are order-independent and always
+to every duplicated position.  ``.add``/``.min``/``.max`` are order-independent and always
 safe.  Sequential ``.at[]`` calls are ordered (later wins), so
 cross-call overlap — e.g. the allocator reusing just-freed pop slots —
 is the *design*, not a race.
@@ -242,7 +241,6 @@ def check_commit_chains(queue_cap: int = 64, batch_pop: int = 8) -> dict:
     from repro.core import eventq as EQ
     from repro.core import trace as TR
 
-    depth = EQ.tree_depth(queue_cap)
     rec = ScatterRecorder()
     with rec:
         # -- tree: prefill, then pop batches + pushes with reuse ---------
@@ -250,22 +248,22 @@ def check_commit_chains(queue_cap: int = 64, batch_pop: int = 8) -> dict:
         n = queue_cap
         t0 = np.full(n, 1e18, np.float32)
         t0[:40] = 10.0 * (np.arange(40) % 7 + 1)       # heavy time ties
-        st = EQ.bulk_push(st, *_push_args(n, t0), depth, queue_cap)
+        st = EQ.bulk_push(st, *_push_args(n, t0), queue_cap)
         pop_slots = jnp.asarray([0, 1, 2, 7, 7, 63], jnp.int32)
         pop_ok = jnp.asarray([1, 1, 1, 1, 0, 1], bool)  # dup slot masked
         t1 = np.full(n, 1e18, np.float32)
         t1[:8] = [5.0, 5.0, 5.0, 70.0, 70.0, 1e18, 2.0, 2.0]
         st = EQ.commit(st, pop_slots, pop_ok, *_push_args(n, t1),
-                       depth, queue_cap)
+                       queue_cap)
         # overflow: push a full queue's worth into the near-full tree
         st = EQ.commit(st, jnp.zeros((0,), jnp.int32),
                        jnp.zeros((0,), bool),
                        *_push_args(n, np.full(n, 3.0, np.float32)),
-                       depth, queue_cap)
+                       queue_cap)
         # all-dropped pop lanes
         st = EQ.commit(st, pop_slots, jnp.zeros((6,), bool),
                        *_push_args(n, np.full(n, 1e18, np.float32)),
-                       depth, queue_cap)
+                       queue_cap)
 
         # -- calendar: same scenarios through cal_commit -----------------
         cs = EQ.cal_empty(queue_cap)

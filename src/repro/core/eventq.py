@@ -13,27 +13,31 @@ hurts most).
 This module provides two replacements, selected through the static
 ``queue_impl`` axis on ``SimShape`` (one XLA program per value):
 
-``"tree"`` — a **static-depth tournament tree**, a segmented
-pairwise-min reduction over the event times.  The whole queue lives in
-ONE ``(2*Qp + S + S2, 6)`` f32 array ``evq_tree`` (Qp = 2**depth >=
-queue_cap; S per-segment free counters; S2 super-segment counters):
+``"tree"`` — the name is kept for the configurations and goldens that
+name it; the structure is a **flat leaf array with a dense root**.  The
+whole queue lives in ONE ``(Q + S + S2, 6)`` f32 array ``evq_tree``
+(Q = queue_cap; S per-segment free counters; S2 super-segment counters):
 
-  rows 1..2Qp     the implicit-heap tournament tree (node 0 unused,
-                  root at 1, node n's children at 2n and 2n+1, leaf for
-                  queue slot j at Qp + j).  A row is the full record of
-                  the minimal event in the node's subtree:
-                  [time, slot, ev_type, a0, a1, a2] — each pairwise
-                  reduction copies the winning child's row wholesale, so
-                  the ROOT row is the next event including its payload.
-                  Slot indices and payloads are small exact integers in
-                  f32 (queue_cap is capped at 2**24, event arguments are
+  rows 0..Q       per-slot leaf rows [time, slot, ev_type, a0, a1, a2],
+                  slot j at row j; INF time marks a free slot.  Slot
+                  indices and payloads are small exact integers in f32
+                  (queue_cap is capped at 2**24, event arguments are
                   app/cluster/PE indices and counts far below it).
-  rows 2Qp..2Qp+S     per-ALLOC_SEG-slot free counters (column 0).
-  rows 2Qp+S..+S2     per-SUPER_SEG-segment super counters (column 0) —
+  rows Q..Q+S     per-ALLOC_SEG-slot free counters (column 0).
+  rows Q+S..+S2   per-SUPER_SEG-segment super counters (column 0) —
                   each the sum of its 64 segment counters, kept in sync
                   by every pop/push so large-Q allocation can search the
                   counter hierarchy instead of cumsum-ing all S segments
                   (see ``_alloc``).
+
+  The next event is the ``(6,)`` root mirror ``evq_root``: every commit
+  ends with ONE ``jnp.argmin`` over the Q leaf times and copies that
+  leaf's row.  An earlier layout kept a tournament tree of winner rows
+  above the leaves and repaired the touched root paths level by level.
+  On a TPU v5e each level's gather and scatter ran one after another
+  (13-15 levels of 81-262 us each at Q = 8192 / 32768), while one dense
+  reduction over the leaf times reads a few MB; the internal rows also
+  doubled every whole-buffer copy and select of the batched loop.
 
 ``"calendar"`` — a **bucketed calendar prototype** for the Q=32k+
 regime: ONE ``(1 + Q + NB + S + S2, 6)`` f32 array ``evq_cal``:
@@ -70,13 +74,13 @@ a full copy of the big buffer per event (measured ~60-100 us at
 Q=32768 — more than the whole pop).  Fusing payloads, summaries and
 counters into one buffer keeps every per-event write on one array:
 
-  cond/peek  read the root row: O(1) instead of the O(Q) ``min``; pop
-             needs no payload gathers at all.
+  cond/peek  read the root mirror: O(1) instead of a queue-wide ``min``;
+             pop needs no payload gathers at all.
   pop        the root IS (t, slot, type, args).
   commit     ``commit`` applies a whole body iteration's pops AND pushes
              as one scatter chain (clear pops -> return counters ->
-             allocate -> write push leaves -> take counters -> repair
-             the union of touched paths once).  Handlers stage their
+             allocate -> write push leaves -> take counters), then sets
+             the root from the final leaf times.  Handlers stage their
              push requests instead of pushing mid-handler, so the body
              issues exactly one commit — the fused-commit architecture
              that keeps every queue scatter out of ``lax.switch``
@@ -88,27 +92,20 @@ counters into one buffer keeps every per-event write on one array:
              entry's segment, a gathered (n, 64) window of leaf times
              finds the exact slot — so the j-th masked entry takes the
              j-th lowest free slot, bitwise the linear impl's
-             first-free-slot rule.  Leaf writes then repair the touched
-             paths **level-parallel**: per level one (n, 2, 6)
-             child-pair gather + one (n, 6) row scatter (duplicate
-             parents write identical rows, so scatter order is
-             irrelevant), O(n + log Q) small ops per batch instead of
-             the queue-wide argsort.
+             first-free-slot rule — then one scatter writes the leaves.
 
-Everything is fixed-shape with no data-dependent control flow: the depth
-is a static Python int (loops unroll at trace time), updates are
-``.at[].set`` writes with traced indices (out-of-range lanes dropped via
-``mode="drop"``), and repairs are idempotent, so masked entries simply
-re-write unchanged rows.  That keeps the structure vmap-able and
-scan-friendly — ``sweep.py``'s "vmap" and "seq" modes stay bitwise
-identical under ``queue_impl="tree"`` (tests/test_eventq.py), and the
-whole queue state is one ordinary state-dict leaf.
+Everything is fixed-shape with no data-dependent control flow: updates
+are ``.at[].set`` writes with traced indices (out-of-range lanes dropped
+via ``mode="drop"``), so masked entries simply write nothing.  That
+keeps the structure vmap-able and scan-friendly — ``sweep.py``'s "vmap"
+and "seq" modes stay bitwise identical under ``queue_impl="tree"``
+(tests/test_eventq.py), and the whole queue state is one ordinary
+state-dict leaf.
 
 Tie-breaking contract: ``jnp.argmin`` returns the LOWEST index among
 equal minima, and same-timestamp events must pop in identical order
-under every impl, so every pairwise reduction here takes the left child
-on ties (``l <= r``) — the left subtree holds the lower slot indices,
-hence the root is the lowest-index argmin at every level
+under every impl.  The tree's root is that argmin over the leaf times
+itself, so it is the lexmin of (time, slot)
 (tests/test_eventq.py::test_pop_slot_matches_argmin_under_ties); the
 calendar keeps the same contract through its explicit (time, slot)
 lexmin.  ``batch_take`` extends the contract to same-timestamp batching:
@@ -124,8 +121,6 @@ other impls route pop/push through this module with bitwise-identical
 results.
 """
 from __future__ import annotations
-
-import math
 
 import jax.numpy as jnp
 
@@ -158,17 +153,6 @@ MAX_QUEUE_CAP = 1 << 24
 ROW_W = 6
 
 
-def tree_depth(queue_cap: int) -> int:
-    """Static tree depth: the smallest d with 2**d >= queue_cap."""
-    return max(1, math.ceil(math.log2(max(queue_cap, 2))))
-
-
-def leaf_count(queue_cap: int) -> int:
-    """Padded leaf count Qp = 2**depth (slots >= queue_cap stay INF
-    forever, so the padding is invisible to the simulation)."""
-    return 1 << tree_depth(queue_cap)
-
-
 def seg_count(queue_cap: int) -> int:
     """Number of ALLOC_SEG-slot segments covering the queue."""
     return -(-queue_cap // ALLOC_SEG)
@@ -185,8 +169,7 @@ def cal_buckets(queue_cap: int) -> int:
 
 
 # --------------------------------------------------------------------------
-# Full rebuilds (vectorized, O(Q)): initial state + the reference the
-# incremental path repairs are property-tested against.
+# Full builds (vectorized, O(Q)): initial state + test fixtures.
 # --------------------------------------------------------------------------
 
 def _leaf_rows(times, typ, a):
@@ -215,28 +198,19 @@ def _counter_rows(times):
 
 def build_tree(times, typ=None, a=None):
     """(queue_cap,) event times (+ optional payloads: ``typ`` (Q,) and
-    ``a`` (Q, 3)) -> the full ``evq_tree`` array: pairwise winner-row
-    reduction with lowest-index tie-breaking, free counters appended."""
+    ``a`` (Q, 3)) -> the full ``evq_tree`` array: the leaf rows, then the
+    free counters."""
     q = times.shape[0]
     if q > MAX_QUEUE_CAP:
         raise ValueError(f"queue_cap {q} exceeds the exact-f32 slot-index "
                          f"range ({MAX_QUEUE_CAP})")
-    qp = leaf_count(q)
-    leaves = _leaf_rows(times, typ, a)
-    pad = jnp.concatenate([
-        jnp.stack([jnp.full((qp - q,), INF),
-                   jnp.arange(q, qp, dtype=jnp.float32),
-                   jnp.zeros((qp - q,))], -1),
-        jnp.zeros((qp - q, 3))], axis=-1)
-    rows = jnp.concatenate([leaves, pad])
-    levels = [rows]
-    for _ in range(tree_depth(q)):
-        left, right = rows[0::2], rows[1::2]
-        take_l = left[:, 0] <= right[:, 0]   # ties -> left = lower slot
-        rows = jnp.where(take_l[:, None], left, right)
-        levels.append(rows)
-    return jnp.concatenate([jnp.zeros((1, ROW_W))] + levels[::-1]
-                           + [_counter_rows(times)])
+    return jnp.concatenate([_leaf_rows(times, typ, a), _counter_rows(times)])
+
+
+def _root(tree, queue_cap: int):
+    """The next event of an ``evq_tree`` array: the row of the leaf at
+    the lowest slot among equal minimum times (``jnp.argmin``'s rule)."""
+    return tree[jnp.argmin(tree[:queue_cap, 0])]
 
 
 def build_freecnt(free_mask):
@@ -280,17 +254,17 @@ def queue_state(queue_cap: int) -> dict:
     ``ev_type`` / ``ev_a`` arrays do not exist in tree mode — times and
     payloads live in the tree rows (``leaf_times``/``leaf_payloads``).
 
-    ``evq_root`` mirrors the root row in a separate (ROW_W,) buffer,
-    maintained by ``pop``/``commit``.  The simulator body and loop
-    condition read the next event from the mirror, NEVER from the big
-    tree buffer: any read of the tree outside the commit chain gets
+    ``evq_root`` holds the root row (``_root``) in a separate
+    (ROW_W,) buffer, maintained by ``pop``/``commit``.  The simulator
+    body and loop condition read the next event from it, NEVER from the
+    big tree buffer: any read of the tree outside the commit chain gets
     rematerialized by XLA fusion at its consumers, and a read of the
     original value after the in-place chain has started makes
     copy-insertion clone the whole buffer every iteration (measured
     ~850 us/event at the k=256 paper point — the entire cost of the
     pre-fused simulator)."""
     tr = build_tree(jnp.full((queue_cap,), INF))
-    return {"evq_tree": tr, "evq_root": tr[1]}
+    return {"evq_tree": tr, "evq_root": _root(tr, queue_cap)}
 
 
 def cal_state(queue_cap: int) -> dict:
@@ -304,51 +278,27 @@ def cal_state(queue_cap: int) -> dict:
 # Views (tests, debugging).
 # --------------------------------------------------------------------------
 
-def _leaf_base(tree) -> int:
-    """Static leaf offset Qp from the array length 2*Qp + S + S2
-    (S + S2 < 2*Qp)."""
-    return 1 << int(math.floor(math.log2(tree.shape[0] // 2)))
-
-
-def _seg_split(extra_rows: int) -> int:
-    """Recover S from S + ceil(S / SUPER_SEG) (strictly increasing in S,
-    so the split is unique)."""
-    for s in range(max(0, extra_rows - extra_rows // SUPER_SEG - 2),
-                   extra_rows + 1):
-        if s + -(-s // SUPER_SEG) == extra_rows:
-            return s
-    raise ValueError(f"no valid segment split for {extra_rows} rows")
-
-
-def leaf_times(st):
-    """(Qp,) per-slot event times from the leaf rows — INF marks a free
+def leaf_times(st, queue_cap: int):
+    """(Q,) per-slot event times from the leaf rows — INF marks a free
     slot.  Authoritative in tree mode (there is no ``ev_time``)."""
-    tree = st["evq_tree"]
-    qp = _leaf_base(tree)
-    return tree[qp:2 * qp, 0]
+    return st["evq_tree"][:queue_cap, 0]
 
 
-def leaf_payloads(st):
-    """(Qp, 4) per-slot [ev_type, a0, a1, a2] from the leaf rows."""
-    tree = st["evq_tree"]
-    qp = _leaf_base(tree)
-    return tree[qp:2 * qp, 2:]
+def leaf_payloads(st, queue_cap: int):
+    """(Q, 4) per-slot [ev_type, a0, a1, a2] from the leaf rows."""
+    return st["evq_tree"][:queue_cap, 2:]
 
 
-def freecnt(st):
+def freecnt(st, queue_cap: int):
     """(S,) i32 per-segment free counts from the counter rows."""
-    tree = st["evq_tree"]
-    qp = _leaf_base(tree)
-    s = _seg_split(tree.shape[0] - 2 * qp)
-    return tree[2 * qp:2 * qp + s, 0].astype(jnp.int32)
+    s = seg_count(queue_cap)
+    return st["evq_tree"][queue_cap:queue_cap + s, 0].astype(jnp.int32)
 
 
-def supercnt(st):
+def supercnt(st, queue_cap: int):
     """(S2,) i32 super-segment free counts from the counter rows."""
-    tree = st["evq_tree"]
-    qp = _leaf_base(tree)
-    s = _seg_split(tree.shape[0] - 2 * qp)
-    return tree[2 * qp + s:, 0].astype(jnp.int32)
+    return st["evq_tree"][queue_cap + seg_count(queue_cap):, 0] \
+        .astype(jnp.int32)
 
 
 def cal_leaf_times(st, queue_cap: int):
@@ -373,9 +323,9 @@ def cal_freecnt(st, queue_cap: int):
 # --------------------------------------------------------------------------
 
 def peek_time(st):
-    """Earliest pending event time — the root, O(1).  The tree-mode
-    while-loop condition is ``peek_time(st) < INF``."""
-    return st["evq_tree"][1, 0]
+    """Earliest pending event time — the root mirror, O(1).  The
+    tree-mode while-loop condition is ``peek_time(st) < INF``."""
+    return st["evq_root"][0]
 
 
 def cal_peek_time(st):
@@ -383,50 +333,20 @@ def cal_peek_time(st):
     return st["evq_cal"][0, 0]
 
 
-def pop(st, depth: int):
-    """Pop the earliest event: the root row IS the event — no argmin, no
-    payload gathers.  Clear the leaf and repair its root path with one
-    sibling gather, an unrolled running-winner register chain, and one
-    path scatter (single-buffer: see module docstring).  Returns
-    ``(st, t, slot, typ, a)`` with ``typ`` i32 and ``a`` (3,) i32 —
-    exactly the values linear mode reads from ``ev_type``/``ev_a``."""
-    qp = 1 << depth
-    tree = st["evq_tree"]
-    s = _seg_split(tree.shape[0] - 2 * qp)
-    root = tree[1]
+def pop(st, queue_cap: int):
+    """Pop the earliest event: the root mirror IS the event — no payload
+    gathers.  Commits the pop alone: clear the leaf, return its
+    counters, set the dense root.  Returns ``(st, t, slot, typ, a)``
+    with ``typ`` i32 and ``a`` (3,) i32 — exactly the values linear mode
+    reads from ``ev_type``/``ev_a``."""
+    root = st["evq_root"]
     t = root[0]
     slot = root[1].astype(jnp.int32)
     typ = root[2].astype(jnp.int32)
     a = root[3:].astype(jnp.int32)
-    leaf = slot + qp
-    path = leaf >> jnp.arange(depth + 1)             # leaf .. root
-    sib = tree[path[:-1] ^ 1]                        # (depth, 6) one gather
-    is_left = path[:-1] % 2 == 0                     # path node a left child?
-    seg = slot // ALLOC_SEG
-    sup = seg // SUPER_SEG
-    cnt = tree[2 * qp + seg, 0]                      # free counter row
-    scnt = tree[2 * qp + s + sup, 0]                 # super counter row
-    # running winner row from the cleared leaf upward: each ancestor is
-    # the pairwise winner of the running row and the unchanged sibling
-    # row, the tie going to whichever child is on the left
-    run = jnp.concatenate([jnp.stack([INF, slot.astype(jnp.float32), 0.0]),
-                           jnp.zeros((3,))])
-    rows = [run]
-    for lvl in range(depth):
-        pick = jnp.where(is_left[lvl], run[0] <= sib[lvl, 0],
-                         run[0] < sib[lvl, 0])
-        run = jnp.where(pick, run, sib[lvl])
-        rows.append(run)
-    # one scatter writes the whole path plus the freed-slot counter rows
-    # (indices 2Qp+seg / 2Qp+S+sup are disjoint from the path in [1, 2Qp))
-    idx = jnp.concatenate([path, jnp.reshape(2 * qp + seg, (1,)),
-                           jnp.reshape(2 * qp + s + sup, (1,))])
-    cnt_rows = jnp.zeros((2, ROW_W)).at[:, 0].set(
-        jnp.stack([cnt + 1.0, scnt + 1.0]))
-    new = jnp.concatenate([jnp.stack(rows), cnt_rows])
-    st = dict(st)
-    st["evq_tree"] = tree.at[idx].set(new)
-    st["evq_root"] = run                             # mirror (queue_state)
+    z = jnp.zeros((0,), jnp.float32)
+    st = commit(st, slot[None], jnp.ones((1,), bool), jnp.zeros((0,), bool),
+                z, z, z, z, z, queue_cap)
     return st, t, slot, typ, a
 
 
@@ -485,25 +405,21 @@ def _alloc(arr, leaf_base, cnt_base, sup_base, s, queue_cap, mask, times):
 
 
 def commit(st, pop_slots, pop_ok, mask, times, typ, a0, a1, a2,
-           depth: int, queue_cap: int):
+           queue_cap: int):
     """Apply one body iteration's pops AND pushes as ONE scatter chain on
     ``evq_tree``: clear the popped leaves, return their free counters,
     allocate push slots (the allocator sees the just-freed slots, exactly
     the sequential pop-then-push semantics), write the push leaves, take
-    their counters, then repair the union of all touched root paths once,
-    level-parallel.  ``typ`` may be a scalar or a per-entry array.
+    their counters, then set the root mirror from the final leaf times.
+    ``typ`` may be a scalar or a per-entry array.
 
     ``pop_slots``/``pop_ok`` are (B,) — B = 0 makes this a pure bulk
-    push; masked lanes (``pop_ok`` False) are dropped from every write.
-    Fusing the chain keeps each level's repair single-pass even when a
-    pop path and a push path share ancestors (duplicate parents compute
-    identical winner rows from the already-updated lower level)."""
+    push; masked lanes (``pop_ok`` False) are dropped from every write."""
     q = queue_cap
-    qp = 1 << depth
     tree = st["evq_tree"]
     s = seg_count(q)
-    cnt_base = 2 * qp
-    sup_base = 2 * qp + s
+    cnt_base = q
+    sup_base = q + s
     oob = tree.shape[0]
     pop_slots = jnp.asarray(pop_slots, jnp.int32)
     pop_ok = jnp.asarray(pop_ok, bool)
@@ -513,7 +429,7 @@ def commit(st, pop_slots, pop_ok, mask, times, typ, a0, a1, a2,
     clear_rows = jnp.zeros((nb, ROW_W)) \
         .at[:, 0].set(INF) \
         .at[:, 1].set(pop_slots.astype(jnp.float32))
-    tree = tree.at[jnp.where(pop_ok, pop_slots + qp, oob)] \
+    tree = tree.at[jnp.where(pop_ok, pop_slots, oob)] \
         .set(clear_rows, mode="drop")
     pseg = pop_slots // ALLOC_SEG
     inc_idx = jnp.concatenate([
@@ -525,7 +441,7 @@ def commit(st, pop_slots, pop_ok, mask, times, typ, a0, a1, a2,
     # -- allocation (sees the freed slots) + push leaf writes ------------
     mask = jnp.asarray(mask, bool)
     times = jnp.asarray(times, jnp.float32)
-    slot, segc, ok, n_drop = _alloc(tree, qp, cnt_base, sup_base, s, q,
+    slot, segc, ok, n_drop = _alloc(tree, 0, cnt_base, sup_base, s, q,
                                     mask, times)
     st = dict(st)
     st["dropped"] = st["dropped"] + n_drop
@@ -535,7 +451,7 @@ def commit(st, pop_slots, pop_ok, mask, times, typ, a0, a1, a2,
         jnp.broadcast_to(jnp.asarray(a0, jnp.float32), mask.shape),
         jnp.broadcast_to(jnp.asarray(a1, jnp.float32), mask.shape),
         jnp.broadcast_to(jnp.asarray(a2, jnp.float32), mask.shape)], -1)
-    tree = tree.at[jnp.where(ok, slot + qp, oob)].set(leaf_rows, mode="drop")
+    tree = tree.at[jnp.where(ok, slot, oob)].set(leaf_rows, mode="drop")
     # an ok entry with time >= INF takes its slot in the assignment order
     # (as in linear mode) but leaves the leaf free, so it must not
     # decrement the segment counter — counters always equal the number
@@ -546,36 +462,20 @@ def commit(st, pop_slots, pop_ok, mask, times, typ, a0, a1, a2,
         jnp.where(live, sup_base + segc // SUPER_SEG, oob)])
     tree = tree.at[dec_idx, 0].add(
         jnp.full((2 * mask.shape[0],), -1.0), mode="drop")
-
-    # -- union touched-path repair, level-parallel -----------------------
-    # Per level, the touched parents gather their two children's rows,
-    # take the winner, and scatter back.  Entries sharing a parent
-    # compute identical rows (the gathers see all lower-level writes),
-    # so duplicate scatters are order-independent; untouched nodes are
-    # never written.
-    all_slots = jnp.concatenate([pop_slots, slot])
-    all_ok = jnp.concatenate([pop_ok, ok])
-    two = jnp.arange(2)[None, :]                     # (1, 2) child offsets
-    for lvl in range(depth):
-        parent = (all_slots + qp) >> (lvl + 1)
-        kids = tree[2 * parent[:, None] + two]       # (n, 2, 6) one gather
-        take_l = kids[:, 0, 0] <= kids[:, 1, 0]      # ties -> left child
-        prow = jnp.where(take_l[:, None], kids[:, 0], kids[:, 1])
-        tree = tree.at[jnp.where(all_ok, parent, oob)].set(prow, mode="drop")
     st["evq_tree"] = tree
-    # refresh the root mirror — this slice depends on the final repaired
-    # tree, so it orders AFTER the whole in-place chain (no copy forced)
-    st["evq_root"] = tree[1]
+    # the dense root reads the buffer only after its last in-place write,
+    # so copy-insertion clones nothing (queue_state)
+    st["evq_root"] = _root(tree, q)
     return st
 
 
-def bulk_push(st, mask, times, typ, a0, a1, a2, depth: int, queue_cap: int):
+def bulk_push(st, mask, times, typ, a0, a1, a2, queue_cap: int):
     """Tree-mode twin of ``sim._bulk_push``: insert the masked entries of
     an event batch with the identical slot-assignment rule (the j-th
     masked entry takes the j-th lowest free slot) and identical overflow
     accounting (excess masked entries drop).  A pure-push ``commit``."""
     return commit(st, jnp.zeros((0,), jnp.int32), jnp.zeros((0,), bool),
-                  mask, times, typ, a0, a1, a2, depth, queue_cap)
+                  mask, times, typ, a0, a1, a2, queue_cap)
 
 
 def batch_take(leaf_t, leaf_typ, root_t, root_slot, rx_typ, batch_pop: int):

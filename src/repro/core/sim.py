@@ -14,9 +14,9 @@ over a bounded event queue.  The queue's priority structure is itself a
 static axis (``queue_impl``, core/eventq.py, DESIGN.md §11): ``"linear"``
 pops with an O(queue_cap) ``jnp.argmin`` scan — the historical code,
 kept operation-for-operation as the golden anchor — while ``"tree"``
-maintains a static-depth tournament tree for O(log queue_cap) pop/push
-with bitwise-identical results, which is what makes the paper-scale
-m=256/k=256 distributed runs tractable on CPU
+keeps the next event in a root row refreshed by one dense reduction
+per fused commit, with bitwise-identical results, which is what makes
+the paper-scale m=256/k=256 distributed runs tractable on CPU
 (benchmarks/topology_frontier.py --grid paper).
 
 Parameters are split into three objects (see DESIGN.md §7/§9):
@@ -146,9 +146,10 @@ class SimShape:
                                  # (view/age/choice) for serving.replay
     queue_impl: str = "linear"   # event-queue structure (core/eventq.py):
                                  # "linear" = O(Q) argmin scan (golden
-                                 # anchor), "tree" = O(log Q) tournament
-                                 # tree, "calendar" = bucketed calendar
-                                 # prototype — all bitwise-identical
+                                 # anchor), "tree" = flat leaf array
+                                 # with a dense root, "calendar" =
+                                 # bucketed calendar prototype — all
+                                 # bitwise-identical
     batch_pop: int = 1           # max same-timestamp BEACON_RX events
                                  # popped per body iteration (DESIGN.md
                                  # §11); 1 = singleton (golden anchor
@@ -294,7 +295,7 @@ class _Ctx:
                  "c_b", "c_s", "c_join", "dn_th", "T_b", "c_hop",
                  "susp_mult", "retry_after", "policy",
                  "topology", "hops", "ns", "record_s1", "queue_impl",
-                 "qdepth", "sel_global", "sel_local", "faults_on",
+                 "sel_global", "sel_local", "faults_on",
                  "batch_pop", "stage_fan", "stage_h", "cal_width", "trace")
 
     def __init__(self, shape: SimShape, knobs: SimKnobs,
@@ -323,7 +324,6 @@ class _Ctx:
         self.ns = shape.ns
         self.record_s1 = shape.record_s1
         self.queue_impl = shape.queue_impl
-        self.qdepth = EQ.tree_depth(shape.queue_cap)   # static tree depth
         self.sel_global = knobs.c_s * _log2_levels(shape.k)
         self.sel_local = knobs.c_s * _log2_levels(shape.mpk)
         # static: whether the fault machinery (mask state, fault event
@@ -359,8 +359,8 @@ def make_state(p):
     k, mpk, Q, A = p.k, p.mpk, p.queue_cap, p.max_apps
     qi = getattr(p, "queue_impl", "linear")
     if qi == "tree":
-        # tournament tree (core/eventq.py, DESIGN.md §11): times AND
-        # payloads live in the tree rows; the linear ev_* arrays do not
+        # tree queue (core/eventq.py, DESIGN.md §11): times AND
+        # payloads live in the leaf rows; the linear ev_* arrays do not
         # exist in tree mode
         queue = EQ.queue_state(Q)
     elif qi == "calendar":
@@ -531,9 +531,9 @@ def _bulk_push(st, p, mask, times, typ, a0, a1, a2):
                   the free mask + a stable argsort), O(Q log Q) per
                   batch.  Kept operation-for-operation as the golden
                   anchor.
-      "tree"      the tournament-tree path repair (core/eventq.py):
-                  O(log Q) per entry, only the touched root-to-leaf
-                  paths are recomputed.
+      "tree"      leaf writes plus one dense argmin for the root row
+                  (core/eventq.py): O(n) scatters and one O(Q)
+                  reduction per batch.
       "calendar"  the bucket-summary merge (core/eventq.py): O(n + NB)
                   incremental maintenance per batch.
 
@@ -542,8 +542,7 @@ def _bulk_push(st, p, mask, times, typ, a0, a1, a2):
     pushes in one batch.
     """
     if p.queue_impl == "tree":
-        return EQ.bulk_push(st, mask, times, typ, a0, a1, a2, p.qdepth,
-                            p.queue_cap)
+        return EQ.bulk_push(st, mask, times, typ, a0, a1, a2, p.queue_cap)
     if p.queue_impl == "calendar":
         return EQ.cal_bulk_push(st, mask, times, typ, a0, a1, a2,
                                 p.queue_cap, p.cal_width)
@@ -1417,8 +1416,8 @@ def simulate(shape: SimShape, knobs: SimKnobs, arrivals, arrival_gmns,
                 # branch-region buffer copies).
                 def _rx_cohort():
                     if qi == "tree":
-                        lt = EQ.leaf_times(st)[:q]
-                        lpay = EQ.leaf_payloads(st)[:q]
+                        lt = EQ.leaf_times(st, q)
+                        lpay = EQ.leaf_payloads(st, q)
                     elif qi == "calendar":
                         lt = EQ.cal_leaf_times(st, q)
                         lpay = EQ.cal_leaf_payloads(st, q)
@@ -1556,8 +1555,7 @@ def simulate(shape: SimShape, knobs: SimKnobs, arrivals, arrival_gmns,
             pa0, pa1, pa2 = stg["push_a0"], stg["push_a1"], stg["push_a2"]
             d0 = st["dropped"]
             if qi == "tree":
-                st = EQ.commit(st, slots, okl, pm, pt, pty, pa0, pa1, pa2,
-                               p.qdepth, q)
+                st = EQ.commit(st, slots, okl, pm, pt, pty, pa0, pa1, pa2, q)
             elif qi == "calendar":
                 st = EQ.cal_commit(st, slots, okl, t, pm, pt, pty, pa0, pa1,
                                    pa2, q, p.cal_width)

@@ -1,7 +1,11 @@
-"""Tournament-tree event queue (core/eventq.py, DESIGN.md §11):
-pop order equals sorted order under ties, incremental path repair equals
-full rebuild, the argmin lowest-index tie-break contract, drop parity
-with the linear impl, and vmap == seq bitwise under queue_impl="tree"."""
+"""Tree event queue (core/eventq.py, DESIGN.md §11), a flat leaf array
+with a dense root: pop order equals sorted order under ties, the root
+mirror equals numpy's first-index argmin row after every commit, the
+counters equal the free leaves, drop parity with the linear impl, a
+commit whose structure does not grow with Q, and vmap == seq bitwise
+under queue_impl="tree"."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,18 +20,19 @@ from repro.core.sim import SimParams
 INF = float(EQ.INF)
 
 _jit_pop = jax.jit(EQ.pop, static_argnums=1)
-_jit_push = jax.jit(EQ.bulk_push, static_argnums=(3, 7, 8))
+_jit_push = jax.jit(EQ.bulk_push, static_argnums=(3, 7))
 
 
 def _times(q, cap):
-    """Per-slot event times from the tree's leaf rows (INF = free)."""
-    return np.asarray(EQ.leaf_times(q))[:cap]
+    """Per-slot event times from the leaf rows (INF = free)."""
+    return np.asarray(EQ.leaf_times(q, cap))
 
 
 def _from_times(cap, times):
     """Standalone queue state whose slots hold ``times`` (INF = free)."""
     q = dict(EQ.empty(cap))
     q["evq_tree"] = EQ.build_tree(jnp.asarray(times, jnp.float32))
+    q["evq_root"] = q["evq_tree"][int(np.argmin(times))]
     return q
 
 
@@ -36,17 +41,37 @@ def _push(q, times, mask=None, typ=1, cap=None):
     times = jnp.asarray(times, jnp.float32)
     mask = jnp.ones((n,), bool) if mask is None else jnp.asarray(mask, bool)
     z = jnp.zeros((n,), jnp.int32)
-    cap = cap or (np.asarray(EQ.leaf_times(q)).shape[0])
-    return _jit_push(q, mask, times, typ, z, z, z, EQ.tree_depth(cap), cap)
+    return _jit_push(q, mask, times, typ, z, z, z, cap)
 
 
-def _drain(q, depth):
+def _drain(q, cap):
     """Pop until empty; returns [(t, slot), ...]."""
     out = []
     while float(EQ.peek_time(q)) < INF:
-        q, t, slot, typ, a = _jit_pop(q, depth)
+        q, t, slot, typ, a = _jit_pop(q, cap)
         out.append((float(t), int(slot)))
     return q, out
+
+
+def _assert_root_and_counters(q, cap):
+    """The oracle after every commit: ``evq_root`` is the leaf row at
+    numpy's first-index argmin of the leaf times, and the segment and
+    super counters equal the INF leaves they cover."""
+    tree = np.asarray(q["evq_tree"])
+    lt = tree[:cap, 0]
+    assert np.array_equal(np.asarray(q["evq_root"]),
+                          tree[int(np.argmin(lt))])
+    free = lt >= INF
+    segs = -(-cap // EQ.ALLOC_SEG)
+    seg = np.zeros(segs * EQ.ALLOC_SEG, int)
+    seg[:cap] = free
+    seg = seg.reshape(segs, EQ.ALLOC_SEG).sum(1)
+    sups = -(-segs // EQ.SUPER_SEG)
+    sup = np.zeros(sups * EQ.SUPER_SEG, int)
+    sup[:segs] = seg
+    sup = sup.reshape(sups, EQ.SUPER_SEG).sum(1)
+    assert np.array_equal(seg, np.asarray(EQ.freecnt(q, cap)))
+    assert np.array_equal(sup, np.asarray(EQ.supercnt(q, cap)))
 
 
 def test_pop_order_is_sorted_with_ties():
@@ -54,10 +79,9 @@ def test_pop_order_is_sorted_with_ties():
     heavy timestamp ties."""
     rng = np.random.default_rng(0)
     cap = 128
-    d = EQ.tree_depth(cap)
     times = rng.integers(0, 8, size=100).astype(np.float32)  # many ties
     q = _push(EQ.empty(cap), times, cap=cap)
-    q, popped = _drain(q, d)
+    q, popped = _drain(q, cap)
     assert len(popped) == 100
     # push order == slot order here (fresh queue), so expected pop order
     # sorts by (time, slot)
@@ -75,9 +99,8 @@ def test_pop_returns_payload():
                   jnp.asarray([9.0, 7.0], jnp.float32), 3,
                   jnp.asarray([5, 11], jnp.int32),
                   jnp.asarray([6, 22], jnp.int32),
-                  jnp.asarray([8, 33], jnp.int32),
-                  EQ.tree_depth(cap), cap)
-    _, t, slot, typ, a = _jit_pop(q, EQ.tree_depth(cap))
+                  jnp.asarray([8, 33], jnp.int32), cap)
+    _, t, slot, typ, a = _jit_pop(q, cap)
     assert (float(t), int(slot), int(typ)) == (7.0, 1, 3)
     assert np.asarray(a).tolist() == [11, 22, 33]
 
@@ -86,10 +109,9 @@ def test_pop_returns_payload():
 @settings(max_examples=10, deadline=None)
 def test_interleaved_push_pop_matches_heap(seed, cap):
     """Random interleaving of batch pushes and pops behaves as a priority
-    queue with (time, slot) ordering; the tree always equals a full
-    rebuild from its own leaf rows."""
+    queue with (time, slot) ordering; after every commit the root mirror
+    is the first-index argmin row and the counters match the leaves."""
     rng = np.random.default_rng(seed)
-    d = EQ.tree_depth(cap)
     q = EQ.empty(cap)
     live = {}                               # slot -> time (host reference)
     for _ in range(6):
@@ -98,67 +120,68 @@ def test_interleaved_push_pop_matches_heap(seed, cap):
         mask = rng.random(n) < 0.8
         before_free = sorted(s for s in range(cap) if s not in live)
         q = _push(q, times, mask=mask, cap=cap)
+        _assert_root_and_counters(q, cap)
         for j, s in zip(np.flatnonzero(mask), before_free):
             live[int(s)] = float(times[j])
         for _ in range(int(rng.integers(0, 8))):
             if not live:
                 break
-            q, t, slot, _, _ = _jit_pop(q, d)
+            q, t, slot, _, _ = _jit_pop(q, cap)
+            _assert_root_and_counters(q, cap)
             exp_t = min(live.values())
             exp_s = min(s for s, tv in live.items() if tv == exp_t)
             assert (float(t), int(slot)) == (exp_t, exp_s)
             del live[exp_s]
-        # the incremental repairs must equal a from-scratch rebuild (on
-        # the ordering columns; payload columns checked via behavior)
-        rebuilt = EQ.build_tree(jnp.asarray(_times(q, cap)))
-        assert np.array_equal(np.asarray(rebuilt)[:, :2],
-                              np.asarray(q["evq_tree"])[:, :2])
-        assert np.array_equal(np.asarray(EQ.build_freecnt(
-            _times(q, cap) >= INF)), np.asarray(EQ.freecnt(q)))
+        assert sorted(np.flatnonzero(_times(q, cap) < INF).tolist()) \
+            == sorted(live)
 
 
 def test_bulk_push_path_repair_equals_full_rebuild():
-    """After a large masked batch lands, only the touched paths were
-    repaired — and the result is identical to rebuilding the whole tree
-    from its own leaf rows (payloads included)."""
+    """After a large masked batch lands on scattered free slots, the
+    incrementally written buffer is identical to a full build from its
+    own leaf rows (payloads and counters included), and the root mirror
+    is the first-index argmin row."""
     rng = np.random.default_rng(3)
     cap = 256
     q = _push(EQ.empty(cap), rng.uniform(1, 1e6, 200).astype(np.float32),
               typ=2, cap=cap)
-    d = EQ.tree_depth(cap)
     for _ in range(30):                     # free up scattered slots
-        q, _, _, _, _ = _jit_pop(q, d)
+        q, _, _, _, _ = _jit_pop(q, cap)
     times = rng.uniform(1, 1e6, 64).astype(np.float32)
     q = _push(q, times, mask=rng.random(64) < 0.5, typ=2, cap=cap)
     lt = jnp.asarray(_times(q, cap))
-    pl = np.asarray(EQ.leaf_payloads(q))[:cap]
+    pl = np.asarray(EQ.leaf_payloads(q, cap))
     rebuilt = EQ.build_tree(lt, typ=pl[:, 0], a=pl[:, 1:])
     assert np.array_equal(np.asarray(rebuilt), np.asarray(q["evq_tree"]))
+    _assert_root_and_counters(q, cap)
 
 
 def test_pop_slot_matches_argmin_under_ties():
-    """The tree's root reproduces jnp.argmin's lowest-index-wins rule on
-    adversarially tied inputs."""
+    """The root a commit sets reproduces jnp.argmin's lowest-index-wins
+    rule on adversarially tied inputs, all-free queues included."""
     rng = np.random.default_rng(7)
     cap = 64
-    d = EQ.tree_depth(cap)
-    for _ in range(50):
+    for i in range(50):
         times = rng.integers(0, 3, size=cap).astype(np.float32)
-        q = _from_times(cap, times)
-        _, t, slot, _, _ = _jit_pop(q, d)
-        assert int(slot) == int(np.argmin(times))
-        assert float(t) == float(times.min())
+        if i % 10 == 0:
+            times[:] = INF                  # empty: slot 0's row
+        q = _push(EQ.empty(cap), times, cap=cap)
+        assert int(q["evq_root"][1]) == int(np.argmin(times))
+        assert float(EQ.peek_time(q)) == float(times.min())
+        if times.min() < INF:
+            _, t, slot, _, _ = _jit_pop(q, cap)
+            assert int(slot) == int(np.argmin(times))
+            assert float(t) == float(times.min())
 
 
 def test_slot_assignment_matches_linear_rule():
     """The j-th masked entry takes the j-th lowest free slot — the linear
     impl's first-free-slot search — across segment boundaries."""
     cap = 256                               # spans 4 ALLOC_SEG=64 segments
-    d = EQ.tree_depth(cap)
     q = _push(EQ.empty(cap), np.full(cap, 5.0, np.float32), cap=cap)
     freed = [0, 1, 63, 64, 130, 200, 255]   # free a scattered set
     for _ in range(len(freed)):
-        q, _, _, _, _ = _jit_pop(q, d)      # pops are all t=5, slot order
+        q, _, _, _, _ = _jit_pop(q, cap)    # pops are all t=5, slot order
     assert sorted(np.flatnonzero(_times(q, cap) >= INF).tolist()) \
         == list(range(7))
     # free specific scattered slots instead: rebuild that state directly
@@ -184,13 +207,13 @@ def test_inf_time_push_keeps_counters_in_sync():
     # entry 1 consumed slot 1 in the assignment order but left it free
     assert (float(lt[0]), float(lt[2])) == (5.0, 7.0) and lt[1] >= INF
     assert np.array_equal(np.asarray(EQ.build_freecnt(lt >= INF)),
-                          np.asarray(EQ.freecnt(q)))
+                          np.asarray(EQ.freecnt(q, cap)))
     # the freed-looking slot is allocatable again, counters still exact
     q = _push(q, [9.0], cap=cap)
     lt = _times(q, cap)
     assert float(lt[1]) == 9.0
     assert np.array_equal(np.asarray(EQ.build_freecnt(lt >= INF)),
-                          np.asarray(EQ.freecnt(q)))
+                          np.asarray(EQ.freecnt(q, cap)))
     assert int(q["dropped"]) == 0
 
 
@@ -236,11 +259,16 @@ def test_tree_queue_state_shapes_and_cap_guard():
     qs = EQ.queue_state(512)
     s = 512 // EQ.ALLOC_SEG
     s2 = -(-s // EQ.SUPER_SEG)
-    assert qs["evq_tree"].shape == (2 * 512 + s + s2, EQ.ROW_W)
-    assert int(np.asarray(EQ.freecnt(qs)).sum()) == 512
-    assert int(np.asarray(EQ.supercnt(qs)).sum()) == 512
-    # non-power-of-two caps round up to the padded leaf count
-    assert np.asarray(EQ.leaf_times(EQ.queue_state(100))).shape == (128,)
+    # leaf rows then counter rows: no internal nodes
+    assert qs["evq_tree"].shape == (512 + s + s2, EQ.ROW_W)
+    assert int(np.asarray(EQ.freecnt(qs, 512)).sum()) == 512
+    assert int(np.asarray(EQ.supercnt(qs, 512)).sum()) == 512
+    # an empty queue's root is slot 0's free row
+    assert np.asarray(qs["evq_root"]).tolist() == [INF, 0, 0, 0, 0, 0]
+    # non-power-of-two caps keep exactly queue_cap leaves (no padding)
+    q100 = EQ.queue_state(100)
+    assert q100["evq_tree"].shape == (100 + 2 + 1, EQ.ROW_W)
+    assert _times(q100, 100).shape == (100,)
     with pytest.raises(ValueError):
         EQ.build_tree(jnp.zeros((EQ.MAX_QUEUE_CAP + 1,), jnp.float32))
 
@@ -268,7 +296,7 @@ def test_sim_rejects_bad_batch_pop():
 # PR 7: fused end-of-body commit, batch_take, calendar queue.
 # --------------------------------------------------------------------------
 
-_jit_commit = jax.jit(EQ.commit, static_argnums=(9, 10))
+_jit_commit = jax.jit(EQ.commit, static_argnums=9)
 _jit_cal_pop = jax.jit(EQ.cal_pop, static_argnums=1)
 
 
@@ -299,7 +327,6 @@ def test_fused_commit_equals_sequential_pop_then_push(seed):
     relies on (DESIGN.md §11)."""
     rng = np.random.default_rng(seed)
     cap = 128
-    d = EQ.tree_depth(cap)
     n0 = int(rng.integers(cap - 6, cap))    # near-full: exercises drops
     q0 = _push(EQ.empty(cap), rng.integers(0, 20, n0).astype(np.float32),
                cap=cap)
@@ -307,7 +334,7 @@ def test_fused_commit_equals_sequential_pop_then_push(seed):
     qs = q0
     slots = []
     for _ in range(nb):                     # sequential reference
-        qs, _, slot, _, _ = _jit_pop(qs, d)
+        qs, _, slot, _, _ = _jit_pop(qs, cap)
         slots.append(int(slot))
     push_t = rng.integers(0, 20, 8).astype(np.float32)
     mask = rng.random(8) < 0.7
@@ -315,12 +342,13 @@ def test_fused_commit_equals_sequential_pop_then_push(seed):
     z = jnp.zeros((8,), jnp.int32)
     qf = _jit_commit(q0, jnp.asarray(slots, jnp.int32),
                      jnp.ones((nb,), bool), jnp.asarray(mask),
-                     jnp.asarray(push_t), 1, z, z, z, d, cap)
+                     jnp.asarray(push_t), 1, z, z, z, cap)
     assert np.array_equal(np.asarray(qs["evq_tree"]),
                           np.asarray(qf["evq_tree"]))
     assert int(qs["dropped"]) == int(qf["dropped"])
     assert np.array_equal(np.asarray(qs["evq_root"]),
                           np.asarray(qf["evq_root"]))
+    _assert_root_and_counters(qf, cap)
 
 
 @given(st.integers(0, 2 ** 31 - 1))
@@ -396,7 +424,6 @@ def test_calendar_interleaved_matches_tree(seed):
     tree; both root mirrors stay consistent with their owner array."""
     rng = np.random.default_rng(seed)
     cap = 64
-    d = EQ.tree_depth(cap)
     qt, qc = EQ.empty(cap), EQ.cal_empty(cap)
     live = 0
     for _ in range(5):
@@ -410,14 +437,13 @@ def test_calendar_interleaved_matches_tree(seed):
         for _ in range(int(rng.integers(0, 6))):
             if not live:
                 break
-            qt, tt, ts, _, _ = _jit_pop(qt, d)
+            qt, tt, ts, _, _ = _jit_pop(qt, cap)
             qc, ct, cs, _, _ = _jit_cal_pop(qc, cap, jnp.float32(8.0))
             assert (float(tt), int(ts)) == (float(ct), int(cs))
             live -= 1
         # the evq_root mirror is the contract sim.py's cond/body read —
         # it must equal the owner array's root row after every op
-        assert np.array_equal(np.asarray(qt["evq_root"]),
-                              np.asarray(qt["evq_tree"])[1])
+        _assert_root_and_counters(qt, cap)
         assert np.array_equal(np.asarray(qc["evq_root"]),
                               np.asarray(qc["evq_cal"])[0])
 
@@ -435,14 +461,13 @@ def test_hier_super_counter_alloc_matches_flat(monkeypatch):
         m = jnp.ones((n,), bool) if mask is None else jnp.asarray(mask, bool)
         z = jnp.zeros((n,), jnp.int32)
         return EQ.bulk_push(q, m, jnp.asarray(times, jnp.float32), 1,
-                            z, z, z, EQ.tree_depth(cap), cap)
+                            z, z, z, cap)
 
     def build(q):
         rng = np.random.default_rng(5)
         q = push_raw(q, rng.uniform(1, 1e6, 900).astype(np.float32))
-        d = EQ.tree_depth(cap)
         for _ in range(200):                # scatter frees across segments
-            q, _, _, _, _ = _jit_pop(q, d)
+            q, _, _, _, _ = _jit_pop(q, cap)
         return push_raw(q, rng.uniform(1, 1e6, 300).astype(np.float32),
                         mask=rng.random(300) < 0.6)
 
@@ -471,3 +496,87 @@ def test_queue_impl_and_batch_pop_match_linear_bitwise(qi, bp):
                 "events_processed", "dropped"):
         assert np.array_equal(np.asarray(base[key]),
                               np.asarray(got[key])), key
+
+
+def test_k256_shape_cohort_commits_match_host_model():
+    """The k=256 benchmark's commit shape: Q=32768, 64-wide same-time
+    BEACON_RX cohorts popped with ``batch_take`` and a 356-wide push
+    batch (a 255-wide fan-out plus handler pushes) in each commit.  After
+    every commit the leaf times equal a host model of the queue (pops
+    free their slots, the j-th pushed entry takes the j-th lowest free
+    slot), the root is the first-index argmin row, and the counters
+    match the leaves."""
+    rng = np.random.default_rng(11)
+    cap, bp, n, rx = 32768, 64, 356, 3
+    commit = jax.jit(EQ.commit, static_argnums=9)
+    model = np.full(cap, INF, np.float32)
+    typs = np.zeros(cap, np.float32)
+    q = EQ.empty(cap)
+    t_now = 0.0
+    widths = []
+    for _ in range(6):
+        root = np.asarray(q["evq_root"])
+        if root[0] < INF:
+            slots, ok = EQ.batch_take(
+                EQ.leaf_times(q, cap), EQ.leaf_payloads(q, cap)[:, 0],
+                jnp.float32(root[0]), jnp.int32(root[1]), rx, bp)
+            t_now = float(root[0])
+        else:
+            slots, ok = jnp.zeros((bp,), jnp.int32), jnp.zeros((bp,), bool)
+        slots_np, ok_np = np.asarray(slots), np.asarray(ok)
+        # the cohort: the root-time slots in slot order, all RX here
+        cohort = np.flatnonzero(model == root[0])[:bp] \
+            if root[0] < INF else np.zeros(0, int)
+        assert slots_np[ok_np].tolist() == cohort.tolist()
+        widths.append(len(cohort))
+        # one fan-out of 255 RX events at one time, plus handler pushes
+        # at half-integer times, which never tie with a cohort
+        times = np.concatenate([
+            np.full(255, t_now + 8.0, np.float32),
+            (t_now + 100.5 + rng.integers(0, 40, n - 255))
+            .astype(np.float32)])
+        ptyp = np.concatenate([np.full(255, rx),
+                               rng.integers(0, 3, n - 255)]) \
+            .astype(np.float32)
+        mask = rng.random(n) < 0.95
+        z = jnp.zeros((n,), jnp.float32)
+        q = commit(q, slots, ok, jnp.asarray(mask), jnp.asarray(times),
+                   jnp.asarray(ptyp), z, z, z, cap)
+        model[slots_np[ok_np]] = INF
+        free = np.flatnonzero(model >= INF)
+        land = free[:mask.sum()]
+        model[land] = times[mask]
+        typs[land] = ptyp[mask]
+        assert np.array_equal(_times(q, cap), model)
+        assert np.array_equal(np.asarray(EQ.leaf_payloads(q, cap))[:, 0]
+                              [model < INF], typs[model < INF])
+        _assert_root_and_counters(q, cap)
+    assert widths.count(bp) >= 3, widths
+    assert int(q["dropped"]) == 0
+
+
+def _commit_scatter_count(cap, batched):
+    nb, n = 8, 16
+    st = EQ.empty(cap)
+    args = (jnp.zeros((nb,), jnp.int32), jnp.ones((nb,), bool),
+            jnp.ones((n,), bool), jnp.ones((n,), jnp.float32),
+            jnp.zeros((n,)), jnp.zeros((n,)), jnp.zeros((n,)),
+            jnp.zeros((n,)))
+
+    def f(st, *a):
+        return EQ.commit(st, *a, cap)
+
+    if batched:
+        f = jax.vmap(f)
+        st, args = jax.tree.map(lambda x: jnp.stack([x, x]), (st, args))
+    hlo = jax.jit(f).lower(st, *args).as_text(dialect="hlo")
+    return len(re.findall(r"\bscatter\(", hlo))
+
+
+def test_tree_commit_structure_does_not_grow_with_depth():
+    """One tree commit holds the same number of scatter ops at Q=1024
+    and Q=32768, batched or not: no per-level work is left that grows
+    with log Q.  A structural guard on the CPU backend, not a speed."""
+    counts = {(cap, b): _commit_scatter_count(cap, b)
+              for cap in (1024, 32768) for b in (False, True)}
+    assert len(set(counts.values())) == 1, counts
