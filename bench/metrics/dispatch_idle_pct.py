@@ -1,4 +1,6 @@
-"""Share of the traced window in which chip 0 ran no operation, in %."""
+"""Share of the traced window in which the cell's chips ran no
+operation, averaged over them, in %: on several chips, those that finish
+their group early wait for the one that sets the pace."""
 
 
 def read(run):

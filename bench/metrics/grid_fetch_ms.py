@@ -1,7 +1,8 @@
 """Host time to bring a grid's final state to the host: the
-``experiment.fetch`` span, in ms (``bench/scopes.py``)."""
-import scopes
+``experiment.fetch`` span of each group of the traced grid, in ms
+(``bench/trace_reduce.py``)."""
+import trace_reduce
 
 
 def read(run):
-    return scopes.step_ms(run, "experiment.fetch")
+    return trace_reduce.step_ms(run, "experiment.fetch")

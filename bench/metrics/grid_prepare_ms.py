@@ -1,8 +1,8 @@
-"""Host time from the start of a grid to its program's dispatch: the
-``experiment.build`` and ``experiment.dispatch`` spans, in ms
-(``bench/scopes.py``)."""
-import scopes
+"""Host time from the start of a grid to its programs' dispatch: the
+``experiment.build`` and ``experiment.dispatch`` spans of each group of
+the traced grid, in ms (``bench/trace_reduce.py``)."""
+import trace_reduce
 
 
 def read(run):
-    return scopes.step_ms(run, "experiment.build", "experiment.dispatch")
+    return trace_reduce.step_ms(run, "experiment.build", "experiment.dispatch")

@@ -4,25 +4,34 @@
 
 One cell of ``BENCHMARK.json`` per process, found by name: its
 configuration (``bench/configs/<config>.json``), its traffic mix
-(``bench/traffic/<traffic>.json``), the reference's generators of that
-mix's stimulus and fault schedule (``bench/stimulus/<kind>.py``,
-``bench/faults/<kind>.py``) and, with ``--trace 1``, the readers of its
-per-layer metrics (``bench/metrics/<metric>.py``).
+(``bench/traffic/<traffic>.json``), its plain reference
+(``bench/<reference>.py``, ``bench/reference.py`` unless the configuration
+names another), the reference's generators of that mix's stimulus and
+fault schedule (``bench/stimulus/<kind>.py``, ``bench/faults/<kind>.py``)
+and, with ``--trace 1``, the readers of its per-layer metrics
+(``bench/metrics/<metric>.py``).
+
+A configuration runs one static group, or one per entry of its
+``shapes`` list (a frontier of cluster counts, say), dispatched as its
+``mode`` says (``pmap``: one group per chip).
 
 1. Set-up: find the accelerator (none, or fewer chips than the cell
    needs, exits nonzero with no result), turn on the persistent compile
-   cache, and compile the cell's one program by running its spec at a
-   horizon that admits no event (``sim_len`` is traced, so this is the
-   timed program, and it simulates nothing).
-2. The window: grid after grid through ``ExperimentSpec.run()`` in auto
-   mode, each grid one dispatch of the cell's knob x stimulus lanes, its
-   fixed set of stimulus seeds in an order drawn from ``--seed``, until
-   the first grid that ends after ``--seconds``.  A program compiled inside the window is an
-   error.  With ``--trace 1`` the profiler records the window's first
-   grid, and the per-layer metrics are read from it.
-3. The check: every lane of every grid against the plain reference
-   (``bench/reference.py``), and every lane against the configuration's
-   guarantee that no event is dropped.
+   cache, and compile the cell's programs, one per group, by running its
+   spec at a horizon that admits no event (``sim_len`` is traced, so
+   these are the timed programs, and they simulate nothing).
+2. The window: grid after grid through ``ExperimentSpec.run()``, each
+   grid one dispatch of every group's knob x stimulus lanes, its fixed
+   set of stimulus seeds in an order drawn from ``--seed``, until the
+   first grid that ends after ``--seconds``.  A program compiled inside
+   the window is an error, and so is a ``pmap`` grid whose groups did not
+   run on distinct chips.  With ``--trace 1`` the profiler records the
+   window's first grid, and the per-layer metrics are read from it.
+3. The check: every lane of every group of every grid against the plain
+   reference at that group's own shape, and every lane against the
+   configuration's guarantee that no event is dropped.  A grid whose
+   groups are not the configuration's, one for one, fails the lanes of
+   each group that is missing or extra.
 4. The last line of standard output is one JSON object; the numbers the
    check compared, each with its limit, are the last lines of standard
    error and the last key of that object.
@@ -35,6 +44,7 @@ T_START = time.perf_counter()     # set-up is timed from here
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
+import functools  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -48,13 +58,16 @@ BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 sys.path[:0] = [BENCH]
 
-import reference as REF  # noqa: E402
 import trace_reduce as TRACE  # noqa: E402
 
 # the simulator's parameters a configuration file sets
 SIM_KEYS = ("m", "k", "n_childs", "max_apps", "queue_cap", "c_b", "c_s",
             "c_join", "T_b", "c_hop", "susp_mult", "retry_after", "mapping",
             "beacon", "topology", "queue_impl", "batch_pop")
+# the keys of SIM_KEYS that make a static group; an entry of a
+# configuration's ``shapes`` sets only these
+SHAPE_KEYS = ("m", "k", "n_childs", "max_apps", "queue_cap", "queue_impl",
+              "batch_pop")
 # limits of the check (PERF.md gives the readings they come from): the
 # guarantee and the bitwise comparison are exact; the order-dependent
 # latency sum read 0.0 on every sound chip run, 4.4e-4 and up in the control
@@ -97,8 +110,10 @@ def load_cell(root: str, name: str) -> dict:
             "per_layer": per_layer, "root": root}
 
 
+@functools.lru_cache(maxsize=None)
 def load_part(root: str, part: str, name: str):
-    """``bench/<part>/<name>.py`` as a module, found by name alone."""
+    """``bench/<part>/<name>.py`` as a module, found by name alone
+    (``bench/<name>.py`` where ``part`` is empty)."""
     path = os.path.join(root, "bench", part, name + ".py")
     spec = importlib.util.spec_from_file_location(f"bench_{part}_{name}",
                                                   path)
@@ -109,6 +124,26 @@ def load_part(root: str, part: str, name: str):
 
 def metric_reader(root: str, name: str):
     return load_part(root, "metrics", name).read
+
+
+def reference(cell: dict):
+    """The configuration's plain reference: the module its ``reference``
+    key names under ``bench/``, else ``bench/reference.py``."""
+    return load_part(cell["root"], "",
+                     cell["config"].get("reference", "reference"))
+
+
+def group_shapes(config: dict) -> list:
+    """The simulator's parameters of each static group the configuration
+    runs, in dispatch order: each ``shapes`` entry over the shared keys,
+    or the shared keys alone for a configuration without ``shapes``."""
+    shared = {k: config[k] for k in SIM_KEYS}
+    entries = config.get("shapes", [{}])
+    for e in entries:
+        if set(e) - set(SHAPE_KEYS):
+            raise ValueError(f"a shapes entry sets only {SHAPE_KEYS}, "
+                             f"not {sorted(set(e) - set(SHAPE_KEYS))}")
+    return [shared | e for e in entries]
 
 
 # --------------------------------------------------------------------------
@@ -179,13 +214,15 @@ def make_spec(config: dict, traffic: dict, seeds, sim_len: float):
     fp = fault_params(traffic, config["sim_len"])
     faults = (None,) if fp is None else (FaultSpec.from_dict(
         {"kind": traffic["faults"]["kind"], "params": fp}),)
+    shapes = None if "shapes" not in config else tuple(
+        SimParams(**p) for p in group_shapes(config))
     return ExperimentSpec(
-        base=SimParams(**{k: config[k] for k in SIM_KEYS}),
+        base=SimParams(**{k: config[k] for k in SIM_KEYS}), shapes=shapes,
         knobs={k: tuple(v) for k, v in traffic["knobs"].items()},
         workloads=(WorkloadSpec.make(traffic["kind"], seeds=seeds,
                                      **traffic["params"]),),
         faults=faults, trace=traffic.get("trace"), sim_len=sim_len,
-        mode="auto")
+        mode=config.get("mode", "auto"))
 
 
 # --------------------------------------------------------------------------
@@ -200,8 +237,8 @@ def lane_knobs(spec) -> list:
 
 
 def warm_up(cell: dict, rng_seed: int) -> float:
-    """Compile the cell's program: its spec at a horizon that admits no
-    event.  Returns the seconds it took."""
+    """Compile the cell's programs, one per group: its spec at a horizon
+    that admits no event.  Returns the seconds it took."""
     from repro.core import sweep as SW
     rng = np.random.default_rng(rng_seed)
     spec = make_spec(cell["config"], cell["traffic"],
@@ -212,9 +249,22 @@ def warm_up(cell: dict, rng_seed: int) -> float:
     dt = time.perf_counter() - t0
     log(f"set-up run: mode={frame.mode} programs compiled "
         f"(sweep.cache_size delta)={SW.cache_size() - c0} "
-        f"events={int(np.sum(frame.groups[0].state['events_processed']))} "
-        f"seconds={dt:.3f}")
+        f"groups={len(frame.groups)} devices="
+        f"{[g.device for g in frame.groups]} events="
+        f"{sum(int(np.sum(g.state['events_processed'])) for g in frame.groups)}"
+        f" seconds={dt:.3f}")
     return dt
+
+
+def group_result(g, chips: dict) -> dict:
+    """One group of a grid: its shape, the chip it ran on (the device's id,
+    the ``n`` of the trace's ``/device:TPU:<n>``), its final state less the
+    queue's internals, and its lanes' events."""
+    state = {k: v for k, v in g.state.items() if k not in QUEUE_LEAVES}
+    return {"shape": {k: getattr(g.combo.shape, k) for k in SHAPE_KEYS},
+            "device": g.device, "chip": chips.get(g.device),
+            "state": state,
+            "events": int(np.sum(state["events_processed"]))}
 
 
 def window(cell: dict, seed: int, seconds: float,
@@ -225,6 +275,7 @@ def window(cell: dict, seed: int, seconds: float,
     import jax
     config, traffic = cell["config"], cell["traffic"]
     rng = np.random.default_rng(seed)
+    chips = {str(d): d.id for d in jax.devices()}
     counter = CompileCounter()
     grids = []
     profile = contextlib.ExitStack()
@@ -247,31 +298,50 @@ def window(cell: dict, seed: int, seconds: float,
             with span("bench.run"):
                 frame = spec.run()
             with span("bench.loop"):
-                (g,) = frame.groups
-                state = {k: v for k, v in g.state.items()
-                         if k not in QUEUE_LEAVES}
-                grid = {"seeds": seeds, "state": state,
-                        "knobs": lane_knobs(spec),
-                        "events": int(np.sum(state["events_processed"]))}
+                groups = [group_result(g, chips) for g in frame.groups]
+                grid = {"seeds": seeds, "knobs": lane_knobs(spec),
+                        "groups": groups,
+                        "events": sum(g["events"] for g in groups)}
                 compiled = counter.n - n0
             grids.append(grid)
             profile.close()
             log(f"grid {len(grids)}: mode={frame.mode} "
                 f"events={grid['events']} wall_s={frame.wall_s:.4f} "
-                f"compiles={compiled}")
+                f"compiles={compiled} chips={[g['chip'] for g in groups]}")
             if compiled:
                 raise SystemExit(f"{compiled} programs compiled inside the "
                                  "window")
+            if config.get("mode") == "pmap" and (
+                    frame.mode != "pmap" or
+                    len({g["chip"] for g in groups}) != len(groups)):
+                raise SystemExit(f"the cell dispatches one group per chip; "
+                                 f"{frame.mode} put its groups on "
+                                 f"{[g['device'] for g in groups]}")
             if time.perf_counter() - t0 >= seconds:
                 break
         wall = time.perf_counter() - t0
     return {"grids": grids, "wall_s": wall}
 
 
-def lane_states(grid: dict):
-    """(knobs, stimulus seed, the program's final state) of every lane."""
-    st = grid["state"]
-    n_b, n_s = np.asarray(st["events_processed"]).shape
+def matched_groups(grid: dict, shapes: list):
+    """(shape, group) for each group the configuration runs, group None
+    where the grid's group in that place is missing or of another shape;
+    then (None, group) for each group beyond the configuration's."""
+    groups = grid["groups"]
+    for i, shape in enumerate(shapes):
+        g = groups[i] if i < len(groups) else None
+        same = g is not None and all(g["shape"][k] == shape[k]
+                                     for k in SHAPE_KEYS)
+        yield shape, g if same else None
+    for g in groups[len(shapes):]:
+        yield None, g
+
+
+def lane_states(grid: dict, group: dict | None):
+    """(knobs, stimulus seed, the program's final state) of every lane of
+    one group of a grid; the state None where the group lacks the lane."""
+    st = {} if group is None else group["state"]
+    n_b, n_s = (0, 0) if group is None else np.shape(st["events_processed"])
     for b, knobs in enumerate(grid["knobs"]):
         for s, seed in enumerate(grid["seeds"]):
             lane = ({k: np.asarray(v)[b, s] for k, v in st.items()}
@@ -279,34 +349,40 @@ def lane_states(grid: dict):
             yield knobs, seed, lane
 
 
-def reference_inputs(cell: dict, seed: int):
-    """One lane's stimulus (arrivals, gmns, lengths) and fault schedule,
-    from the benchmark's own generators of the traffic's kinds."""
+def reference_inputs(cell: dict, shape: dict, seed: int):
+    """One lane's stimulus (arrivals, gmns, lengths) and fault schedule at
+    a group's shape, from the benchmark's own generators of the traffic's
+    kinds."""
     config, traffic = cell["config"], cell["traffic"]
     gen = load_part(cell["root"], "stimulus", traffic["kind"]).generate
-    arr, gmns, lens = gen(config["max_apps"], config["n_childs"], config["k"],
+    arr, gmns, lens = gen(shape["max_apps"], shape["n_childs"], shape["k"],
                           sim_len=config["sim_len"], seed=seed,
                           **traffic["params"])
     fp = fault_params(traffic, config["sim_len"])
     faults = None if fp is None else load_part(
         cell["root"], "faults", traffic["faults"]["kind"]).generate(
-            config["k"], **fp)
+            shape["k"], **fp)
     return arr, gmns, lens, faults
 
 
-def reference_lane(cell: dict, knobs: dict, seed: int,
+def reference_lane(cell: dict, shape: dict, knobs: dict, seed: int,
                    queue_cap: int | None = None) -> dict:
+    """One lane of a group of the given shape, run by the configuration's
+    plain reference."""
     config = cell["config"]
-    arr, gmns, lens, faults = reference_inputs(cell, seed)
-    return REF.simulate(config | knobs, arr, gmns, lens, config["sim_len"],
-                        faults=faults, queue_cap=queue_cap)
+    arr, gmns, lens, faults = reference_inputs(cell, shape, seed)
+    return reference(cell).simulate(config | shape | knobs, arr, gmns, lens,
+                                    config["sim_len"], faults=faults,
+                                    queue_cap=queue_cap)
 
 
-def compare_lane(got: dict | None, want: dict) -> tuple[list, float]:
+def compare_lane(got: dict | None, want: dict,
+                 order_dependent=()) -> tuple[list, float]:
     """Leaves of one lane that differ from the reference, and the relative
-    gap of the order-dependent sums.  A leaf differs when its dtype is not
-    the one the reference states (f32 times, int32 counts), when its shape
-    differs, or when a bit differs.  A missing lane differs on all."""
+    gap of the order-dependent sums (the reference's ``ORDER_DEPENDENT``).
+    A leaf differs when its dtype is not the one the reference states (f32
+    times, int32 counts), when its shape differs, or when a bit differs.
+    A missing lane differs on all."""
     keys = [k for k in want if k not in ("iterations",)]
     if got is None:
         return keys, 0.0
@@ -318,7 +394,7 @@ def compare_lane(got: dict | None, want: dict) -> tuple[list, float]:
         g, w = np.asarray(got[k]), np.asarray(want[k])
         if g.dtype != w.dtype or g.shape != w.shape:
             bad.append(k)
-        elif k in REF.ORDER_DEPENDENT:
+        elif k in order_dependent:
             gap = max(gap, abs(float(g) - float(w)) / max(abs(float(w)), 1.0))
         elif not np.array_equal(g, w):
             bad.append(k)
@@ -326,25 +402,36 @@ def compare_lane(got: dict | None, want: dict) -> tuple[list, float]:
 
 
 def check(cell: dict, grids: list) -> dict:
-    """Every lane against the reference and the no-drop guarantee."""
+    """Every lane of every group against the reference at the group's
+    shape, and against the no-drop guarantee."""
+    shapes = group_shapes(cell["config"])
+    order_dependent = reference(cell).ORDER_DEPENDENT
     refs = {}
     failed = lanes = dropped = 0
     gap = 0.0
     for gi, grid in enumerate(grids):
-        for knobs, seed, got in lane_states(grid):
-            lanes += 1
-            key = (tuple(knobs.items()), seed)
-            if key not in refs:
-                refs[key] = reference_lane(cell, knobs, seed)
-            bad, g = compare_lane(got, refs[key])
-            gap = max(gap, g)
-            if got is not None:
-                dropped += int(got["dropped"])
-            if bad or g > LIMITS["mgmt_latency_rel_gap"] or \
-                    (got is not None and int(got["dropped"])):
-                failed += 1
-                log(f"lane failed: grid {gi + 1} knobs={knobs} seed={seed} "
-                    f"leaves={bad} mgmt_latency_rel_gap={g:.3g}")
+        for shape, group in matched_groups(grid, shapes):
+            for knobs, seed, got in lane_states(grid, group):
+                lanes += 1
+                if got is not None:
+                    dropped += int(got["dropped"])
+                if shape is None:
+                    failed += 1
+                    log(f"lane failed: grid {gi + 1} group beyond the "
+                        f"configuration's: {group['shape']}")
+                    continue
+                key = (tuple(sorted(shape.items())), tuple(knobs.items()),
+                       seed)
+                if key not in refs:
+                    refs[key] = reference_lane(cell, shape, knobs, seed)
+                bad, g = compare_lane(got, refs[key], order_dependent)
+                gap = max(gap, g)
+                if bad or g > LIMITS["mgmt_latency_rel_gap"] or \
+                        (got is not None and int(got["dropped"])):
+                    failed += 1
+                    log(f"lane failed: grid {gi + 1} k={shape['k']} "
+                        f"knobs={knobs} seed={seed} leaves={bad} "
+                        f"mgmt_latency_rel_gap={g:.3g}")
     return {"lanes": lanes, "failed": failed,
             "numbers": {"lanes_failed": failed, "events_dropped": dropped,
                         "mgmt_latency_rel_gap": gap}}
@@ -355,18 +442,20 @@ def check(cell: dict, grids: list) -> dict:
 # --------------------------------------------------------------------------
 
 class Reading:
-    """What a per-layer metric reader may read: the window's grids, the
-    reduced trace of its first grid and that grid's events, the set-up
-    compile time and the compiled program."""
+    """What a per-layer metric reader may read: the window's grids (each
+    with its groups), the trace of its first grid as ``trace_reduce``
+    extracts it (``events``) and reduces it (``trace``), the set-up
+    compile time and the compiled program of a one-group cell."""
 
-    def __init__(self, cell, win, trace, compile_s):
+    def __init__(self, cell, win, trace, compile_s, events=None):
         self.cell, self.grids, self.trace = cell, win["grids"], trace
-        self.traced_events = win["grids"][0]["events"]
+        self.events = events
         self.compile_s = compile_s
         self._compiled = None
 
     def compiled(self):
-        """The cell's program as compiled for the chip (from the cache)."""
+        """A one-group cell's program as compiled for the chip (from the
+        cache)."""
         if self._compiled is None:
             import jax.numpy as jnp
             from repro.core import sweep as SW
@@ -417,16 +506,19 @@ def run_cell(cell: dict, seed: int, seconds: float, traced: bool,
     win = window(cell, seed, seconds, profile_dir)
     events = sum(g["events"] for g in win["grids"])
     device = device_info(devs, cell["chips"])
-    trace = None
+    trace = traced_ev = None
     if traced:
         try:
-            trace = TRACE.summarize(TRACE.extract(profile_dir, cell["chips"]))
+            traced_ev = TRACE.extract(profile_dir, cell["chips"])
+            trace = TRACE.summarize(traced_ev)
         finally:
             shutil.rmtree(profile_dir, ignore_errors=True)
         if trace is None:
             raise SystemExit("the trace holds no window span or no device "
                              "operation")
         device |= {"busy_s": trace["busy_s"], "window_s": trace["window_s"]}
+        log(f"trace: window_s={trace['window_s']!r} busy_s_per_chip="
+            f"{trace['busy_s_per_chip']!r} pace_chip={trace['pace_chip']}")
     log(f"window: grids={len(win['grids'])} events={events} "
         f"wall_s={win['wall_s']:.4f}")
     result = check(cell, win["grids"])
@@ -434,7 +526,7 @@ def run_cell(cell: dict, seed: int, seconds: float, traced: bool,
                v <= LIMITS[k] for k, v in result["numbers"].items()),
            "attempted": result["lanes"], "failed": result["failed"]}
     if traced:
-        reading = Reading(cell, win, trace, compile_s)
+        reading = Reading(cell, win, trace, compile_s, traced_ev)
         metrics = {}
         for m in cell["per_layer"]:
             v = metric_reader(cell["root"], m["name"])(reading)

@@ -1,13 +1,11 @@
-"""Device time of each named part of the sim loop, and the host steps of
-one grid, from a profiler trace of that grid.
+"""Device time of each named part of the sim loop, from a profiler trace
+of one grid.
 
 The program names the parts of its event loop with ``jax.named_scope``
 (``sim.pop``, ``sim.rx``, ``sim.detector``, ``sim.handlers``,
 ``sim.commit``, ``sim.trace_ring``; the handler branches and the beacon
 fan-out nest inside ``sim.handlers``), counts the loop's trips in the
-``iterations`` state leaf, and wraps each step of a grid in a host span
-(``experiment.build``, ``experiment.dispatch``, ``experiment.execute``,
-``experiment.fetch``, inside ``experiment.group``).
+``iterations`` state leaf.
 
 The scopes live in the compiled program, as each instruction's
 ``op_name`` metadata; the trace names instructions.  ``instruction_scopes``
@@ -20,19 +18,15 @@ names after the loop itself, takes no scope.  ``partition`` then splits
 the device self time of the ``_sweep`` program: every copy to ``copy``,
 every other instruction to its scope, the rest to ``None``.
 
-The harness reduces its own trace to a summary that keeps neither all
-operations nor these spans, and removes the trace before the readers
-run; so ``reading`` profiles the traced grid's stimulus set once more,
-in a trace of its own, and every reader of this module reads that.
+``reading`` splits the harness's own trace of the traced grid (the
+lists ``trace_reduce.extract`` returns), once for every reader of this
+module.  A grid of several groups is several programs on several chips,
+and reads nothing here.
 """
 from __future__ import annotations
 
 import functools
-import glob
-import os
 import re
-import shutil
-import tempfile
 from collections import Counter
 
 import numpy as np
@@ -40,7 +34,6 @@ import numpy as np
 import hlo_copies
 import trace_reduce as TRACE
 
-SPAN_PREFIX = "experiment."
 COPY_KINDS = ("copy", "copy-start", "copy-done")
 SWEEP = "_sweep"
 _SCOPE_RE = re.compile(r"(?:^|[/(])(sim\.[A-Za-z_]+)")
@@ -129,28 +122,12 @@ def partition(ops: list, scopes: dict) -> dict:
     return out
 
 
-def host_spans(profile_dir: str) -> list:
-    """``(name, start_ns, duration_ns)`` of the program's ``experiment.``
-    spans in a profile."""
-    from jax.profiler import ProfileData
-    (path,) = glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
-                        recursive=True)
-    return [(e.name, e.start_ns, e.duration_ns)
-            for plane in ProfileData.from_file(path).planes
-            if plane.name.startswith("/host:") for line in plane.lines
-            for e in line.events if e.name.startswith(SPAN_PREFIX)]
-
-
-def summarize(ev: dict, spans: list, scopes: dict, trips: int) -> dict:
+def summarize(ev: dict, scopes: dict, trips: int) -> dict:
     """The readings of one profiled grid: device self seconds of each part
-    of the loop per trip, and host seconds of each step."""
-    steps = {}
-    for name, _, d in spans:
-        steps[name] = steps.get(name, 0.0) + d / 1e9
+    of the loop per trip."""
     return {"trips": trips,
             "per_trip_s": {k: v / trips for k, v in
-                           partition(sweep_ops(ev), scopes).items()},
-            "steps_s": steps}
+                           partition(sweep_ops(ev), scopes).items()}}
 
 
 def max_iterations(state: dict) -> int | None:
@@ -160,32 +137,30 @@ def max_iterations(state: dict) -> int | None:
     return int(np.max(np.asarray(state["iterations"])))
 
 
+def pace_group(run) -> dict | None:
+    """The traced grid's group that ran on the chip that sets the pace
+    (``trace_reduce.summarize``'s ``pace_chip``); a grid's only group
+    wherever it ran.  None where no one group ran on that chip."""
+    groups = run.grids[0]["groups"]
+    if len(groups) == 1:
+        return groups[0]
+    on = [g for g in groups if g["chip"] == run.trace["pace_chip"]]
+    return on[0] if len(on) == 1 else None
+
+
 @functools.lru_cache(maxsize=1)
 def reading(run) -> dict | None:
-    """Profile the traced grid's stimulus set once more and reduce it.
-    None where the program keeps no ``iterations`` leaf."""
-    import jax
-    import run as RUN
-    if max_iterations(run.grids[0]["state"]) is None:
+    """The split of the traced grid's loop by scope.  None where the grid
+    is several groups, the program keeps no ``iterations`` leaf, or the
+    run was not traced."""
+    groups = run.grids[0]["groups"]
+    if len(groups) > 1:
         return None
-    config, traffic = run.cell["config"], run.cell["traffic"]
-    spec = RUN.make_spec(config, traffic, run.grids[0]["seeds"],
-                         config["sim_len"])
-    profile_dir = tempfile.mkdtemp(prefix="bench_scopes_")
-    try:
-        jax.profiler.start_trace(profile_dir)
-        try:
-            with jax.profiler.TraceAnnotation(TRACE.WINDOW_SPAN):
-                frame = spec.run()
-        finally:
-            jax.profiler.stop_trace()
-        ev = TRACE.extract(profile_dir, 1)
-        spans = host_spans(profile_dir)
-    finally:
-        shutil.rmtree(profile_dir, ignore_errors=True)
-    (group,) = frame.groups
-    return summarize(ev, spans, instruction_scopes(run.compiled().as_text()),
-                     max_iterations(group.state))
+    trips = max_iterations(groups[0]["state"])
+    if trips is None or run.events is None:
+        return None
+    return summarize(run.events, instruction_scopes(run.compiled().as_text()),
+                     trips)
 
 
 def per_trip_us(run, key) -> float | None:
@@ -193,10 +168,3 @@ def per_trip_us(run, key) -> float | None:
     if r is None or not r["per_trip_s"]:
         return None
     return 1e6 * r["per_trip_s"].get(key, 0.0)
-
-
-def step_ms(run, *names) -> float | None:
-    r = reading(run)
-    if r is None or not all(n in r["steps_s"] for n in names):
-        return None
-    return 1e3 * sum(r["steps_s"][n] for n in names)

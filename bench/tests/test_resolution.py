@@ -112,10 +112,12 @@ def test_new_cell_is_new_files_only(tmp_path):
     assert c["config"]["k"] == 32 and len(c["traffic"]["stimulus_seeds"]) == 8
     assert "new_metric" in [m["name"] for m in c["per_layer"]]
     assert run.metric_reader(str(tmp_path), "new_metric")(None) == 1.0
-    *stim, faults = run.reference_inputs(c, 5)
+    (shape,) = run.group_shapes(c["config"])
+    *stim, faults = run.reference_inputs(c, shape, 5)
     assert stim == [32, 5, 2.0 * c["config"]["sim_len"]]
     assert faults == [(0.5 * c["config"]["sim_len"], 2, 3, 0)]
     old = run.load_cell(str(tmp_path), CELLS[0])
     assert "new_metric" not in [m["name"] for m in old["per_layer"]]
-    arr, _, _, _ = run.reference_inputs(old, 5)
+    arr, _, _, _ = run.reference_inputs(
+        old, run.group_shapes(old["config"])[0], 5)
     assert arr.shape == (old["config"]["max_apps"],)

@@ -63,3 +63,27 @@ def test_extract_reads_host_spans(tmp_path):
     assert [n for n, _, _ in ev["spans"]] == [TR.WINDOW_SPAN]
     assert ev["ops"] == [[]]
     assert TR.summarize(ev) is None
+
+
+def test_summarize_two_chips():
+    """Busy time and programs per chip; the breakdown and the programs'
+    time from chip 1, the busier, which sets the pace; the gaps named by
+    the harness's spans alone; the program's steps of both groups."""
+    with open(os.path.join(os.path.dirname(__file__), "fixtures",
+                           "trace_two_chips.json")) as f:
+        ev = json.load(f)
+    out = TR.summarize({k: ev[k] for k in ("ops", "modules", "spans")})
+    assert out["busy_s_per_chip"] == pytest.approx([200e-9, 850e-9])
+    assert out["busy_s"] == pytest.approx(525e-9)
+    assert out["pace_chip"] == 1
+    assert out["module_s"] == pytest.approx(
+        {"jit__sweep": 800e-9, "jit_convert": 50e-9})
+    assert out["device_ops"] == [["while.9", pytest.approx(500e-9)],
+                                 ["fusion.2", pytest.approx(300e-9)],
+                                 ["copy.3", pytest.approx(50e-9)]]
+    assert out["idle_gaps"] == [["bench.spec_build", pytest.approx(100e-9)],
+                                ["bench.run", pytest.approx(50e-9)]]
+    # each step summed over the two groups, the last fetch cut at 1000
+    assert out["steps_s"] == pytest.approx({
+        "experiment.build": 40e-9, "experiment.dispatch": 20e-9,
+        "experiment.execute": 710e-9, "experiment.fetch": 130e-9})

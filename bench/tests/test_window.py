@@ -22,12 +22,14 @@ def test_window_events_match_the_reference(run_module):
     """The events the window counts are the reference's events of the
     same lanes, grid by grid, every grid on the traffic's stimulus set."""
     cell = tiny_cell()
+    (shape,) = run_module.group_shapes(cell["config"])
     win = run_module.window(cell, seed=5, seconds=0.2)
     for grid in win["grids"]:
         assert sorted(grid["seeds"]) == TINY_TRAFFIC["stimulus_seeds"]
+        (group,) = grid["groups"]
         want = 0
-        for knobs, seed, lane in run_module.lane_states(grid):
-            ref = run_module.reference_lane(cell, knobs, seed)
+        for knobs, seed, lane in run_module.lane_states(grid, group):
+            ref = run_module.reference_lane(cell, shape, knobs, seed)
             want += ref["events_processed"]
             assert int(lane["events_processed"]) == ref["events_processed"]
         assert grid["events"] == want

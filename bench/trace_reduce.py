@@ -3,10 +3,12 @@
 ``extract`` reads the ``.xplane.pb`` that ``jax.profiler`` writes, with
 JAX's own ``ProfileData``, into three plain lists of ``(name, start_ns,
 duration_ns)``: the operations of each traced chip (the ``XLA Ops`` line
-of ``/device:TPU:<n>``), the programs (``XLA Modules``), and the
-harness's own host spans (``TraceAnnotation`` names starting ``bench.``).
-``summarize`` works on those lists alone, so a small recorded fixture
-checks it without a chip.
+of ``/device:TPU:<n>``), the programs (``XLA Modules``), and the host
+spans of the harness (``TraceAnnotation`` names starting ``bench.``) and
+of the program's steps (``experiment.build``, ``.dispatch``,
+``.execute``, ``.fetch``, one of each per group, ``.group`` around them
+outside pmap).  ``summarize`` works on those lists alone, so a small
+recorded fixture checks it without a chip.
 """
 from __future__ import annotations
 
@@ -15,6 +17,7 @@ import os
 import re
 
 SPAN_PREFIX = "bench."
+STEP_PREFIX = "experiment."
 WINDOW_SPAN = "bench.window"
 _DEVICE_RE = re.compile(r"^/device:TPU:(\d+)$")
 
@@ -45,7 +48,7 @@ def extract(profile_dir: str, chips: int) -> dict:
             for line in plane.lines:
                 spans += [(e.name, e.start_ns, e.duration_ns)
                           for e in line.events
-                          if e.name.startswith(SPAN_PREFIX)]
+                          if e.name.startswith((SPAN_PREFIX, STEP_PREFIX))]
     return {"ops": [ops.get(d, []) for d in range(chips)],
             "modules": [modules.get(d, []) for d in range(chips)],
             "spans": spans}
@@ -102,12 +105,25 @@ def _self_times(events) -> dict:
     return out
 
 
+def step_seconds(spans: list, w0: int, w1: int) -> dict:
+    """Host seconds of each of the program's steps inside the window,
+    summed over the grid's groups."""
+    out = {}
+    for name, s, e in _clip(spans, w0, w1):
+        if name.startswith(STEP_PREFIX):
+            out[name] = out.get(name, 0.0) + (e - s) / 1e9
+    return out
+
+
 def summarize(ev: dict, top: int = 10) -> dict | None:
-    """Busy and window seconds (busy averaged over the traced chips), the
+    """Busy and window seconds (busy averaged over the traced chips), each
+    chip's busy seconds, the host seconds of each of the program's steps,
+    and, on the chip that sets
+    the pace (the most busy time in the window; the first of equals), the
     device seconds of each program, the operations that took most device
-    self time on chip 0, and the longest idle gaps on chip 0, each
-    labelled by the innermost harness span open at its middle.  None when the trace
-    holds no window span or no device operation."""
+    self time, and the longest idle gaps, each labelled by the innermost
+    harness span open at its middle.  With one chip, that chip is chip 0.
+    None when the trace holds no window span or no device operation."""
     win = [(s, s + d) for n, s, d in ev["spans"] if n == WINDOW_SPAN]
     if len(win) != 1 or not any(ev["ops"]):
         return None
@@ -116,13 +132,15 @@ def summarize(ev: dict, top: int = 10) -> dict | None:
     for ops in ev["ops"]:
         iv = _merged((s, e) for _, s, e in _clip(ops, w0, w1))
         busy.append(sum(e - s for s, e in iv))
-    per_op = {}
-    for name, t in _self_times(_clip(ev["ops"][0], w0, w1)).items():
-        per_op[short_name(name)] = per_op.get(short_name(name), 0) + t
+    pace = busy.index(max(busy))
     per_module = {}
-    for name, s, e in _clip(ev["modules"][0], w0, w1):
+    for name, s, e in _clip(ev["modules"][pace], w0, w1):
         per_module[name] = per_module.get(name, 0) + (e - s)
-    iv = _merged((s, e) for _, s, e in _clip(ev["ops"][0], w0, w1))
+    pace_ops = _clip(ev["ops"][pace], w0, w1)
+    per_op = {}
+    for name, t in _self_times(pace_ops).items():
+        per_op[short_name(name)] = per_op.get(short_name(name), 0) + t
+    iv = _merged((s, e) for _, s, e in pace_ops)
     edges = [w0] + [x for s, e in iv for x in (s, e)] + [w1]
     spans = sorted((s, -(s + d), n) for n, s, d in ev["spans"]
                    if n.startswith(SPAN_PREFIX) and n != WINDOW_SPAN)
@@ -136,8 +154,20 @@ def summarize(ev: dict, top: int = 10) -> dict | None:
     return {
         "window_s": (w1 - w0) / 1e9,
         "busy_s": sum(busy) / len(busy) / 1e9,
+        "busy_s_per_chip": [b / 1e9 for b in busy],
+        "steps_s": step_seconds(ev["spans"], w0, w1),
+        "pace_chip": pace,
         "module_s": {n: t / 1e9 for n, t in per_module.items()},
         "device_ops": [[n, t / 1e9] for n, t in
                        sorted(per_op.items(), key=lambda x: -x[1])[:top]],
         "idle_gaps": [list(g) for g in gaps[:top]],
     }
+
+
+def step_ms(run, *names) -> float | None:
+    """Host ms of the named steps of the traced grid, from a reader's
+    run; None where a step is missing."""
+    t = run.trace
+    if t is None or not all(n in t["steps_s"] for n in names):
+        return None
+    return 1e3 * sum(t["steps_s"][n] for n in names)
