@@ -72,7 +72,6 @@ from repro.core import workloads as W
 from repro.core.experiment import ExperimentSpec, WorkloadSpec
 from repro.core.sim import SimParams
 from repro.core.sim import run as sim_run
-from repro.core.transport import TOPOLOGIES
 
 from benchmarks.common import (csv_row, enable_compile_cache, save,
                                timed, topology_meta)
@@ -129,7 +128,8 @@ GRIDS = {
                  queue_cap={16: 2048}, default_queue_cap=1024,
                  c_s=256.0, dn_th=4, sim_len=4e5,
                  pair_periods=(33_000.0,), seeds=(0,),
-                 queue_impl="linear", topologies=TOPOLOGIES),
+                 queue_impl="linear",
+                 topologies=("ideal", "shared_bus", "hier_tree", "mesh2d")),
     # CI proxy for the paper grid: small Q, m=64, tree queue
     "paper_tiny": dict(m=64, ks=(1, 8, 64), n_childs=50, max_apps=128,
                        queue_cap={64: 4096}, default_queue_cap=2048,
@@ -141,7 +141,9 @@ GRIDS = {
                     queue_cap={64: 8192}, default_queue_cap=4096,
                     c_s=40.0, dn_th=4, sim_len=2e6,
                     pair_periods=(26_000.0,), seeds=(1, 2),
-                    queue_impl="linear", topologies=TOPOLOGIES),
+                    queue_impl="linear",
+                    topologies=("ideal", "shared_bus", "hier_tree",
+                                "mesh2d")),
     # the true paper scale (Sec 5 / Table 5): m=256 with the calibrated
     # interference stimulus; k=256 is the fully-distributed extreme whose
     # 255-wide beacon fan-out (hundreds of thousands of BEACON_RX

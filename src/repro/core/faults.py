@@ -57,7 +57,8 @@ DESIGN.md §13):
     messages already left the source and complete).
   - task-start groups and join-exit forwards are *reliable*: a down
     link costs a detour/retransmit penalty (2 extra hops: ``2 * c_hop``
-    on mesh2d, one extra serialized grant pair ``2 * c_b`` elsewhere)
+    on mesh2d and torus2d, one extra serialized grant pair ``2 * c_b``
+    elsewhere)
     counted in ``reroutes`` — management work is never silently lost,
     so every started application still completes under faults.
   - ``downtime`` accumulates the completed outage durations of links

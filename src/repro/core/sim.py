@@ -43,11 +43,12 @@ forwards, status beacons) route through the interconnect transport model
 (``repro.core.transport``, DESIGN.md §10).  The fabric is a fourth
 static axis next to shape and policy: ``Topology("ideal")`` reproduces
 the historical single-global-bus behavior bitwise, while ``shared_bus``
-/ ``hier_tree`` / ``mesh2d`` model contention and per-receiver beacon
-skew — a fired beacon becomes k-1 in-flight entries in the ``(k, k)``
-``bcn_t``/``bcn_val`` delivery matrix plus one BEACON_RX event per
-receiver, so each GMN's ``view_t`` (and hence the staleness ``age`` fed
-to the mapping policies) is genuinely heterogeneous.
+/ ``hier_tree`` / ``mesh2d`` / ``torus2d`` model contention and
+per-receiver beacon skew — a fired beacon becomes k-1 in-flight entries
+in the ``(k, k)`` ``bcn_t``/``bcn_val`` delivery matrix plus one
+BEACON_RX event per receiver, so each GMN's ``view_t`` (and hence the
+staleness ``age`` fed to the mapping policies) is genuinely
+heterogeneous.
 
 Event types:
   ARRIVE(app)             application hits its stimulus GMN; the GMN expands
@@ -187,7 +188,7 @@ class SimKnobs(NamedTuple):
     dn_th: jnp.ndarray           # i32, beacon drift threshold
     T_b: jnp.ndarray             # f32, beacon period/deadline (periodic,
                                  #      hybrid, staleness_weighted)
-    c_hop: jnp.ndarray           # f32, per-hop mesh latency (mesh2d)
+    c_hop: jnp.ndarray           # f32, per-hop latency (mesh2d, torus2d)
     susp_mult: jnp.ndarray       # f32, failure-detector multiplier
                                  #      (DESIGN.md §15): suspect a peer
                                  #      whose beacon is older than
@@ -319,8 +320,9 @@ class _Ctx:
         self.retry_after = knobs.retry_after
         self.policy = policy
         self.topology = topology
-        # static Manhattan hop table (XLA constant; only mesh2d reads it)
-        self.hops = jnp.asarray(T.mesh_hops(shape.k))
+        # the fabric's static hop table (an XLA constant; only the grid
+        # fabrics, transport.MESH_KINDS, read it)
+        self.hops = jnp.asarray(T.hop_table(topology.kind, shape.k))
         self.ns = shape.ns
         self.record_s1 = shape.record_s1
         self.queue_impl = shape.queue_impl
@@ -417,6 +419,10 @@ def make_state(p):
         # maximum over its lanes; a device trace divided by it gives the
         # cost of one iteration.  Pure telemetry, like evq_peak.
         "iterations": jnp.zeros((), jnp.int32),
+        # the trips among them whose popped root is a BEACON_RX: with
+        # beacons_rx it gives the deliveries a cohort trip retires.
+        # Telemetry too: it counts trips, not simulated behaviour.
+        "rx_trips": jnp.zeros((), jnp.int32),
         "dropped": jnp.zeros((), jnp.int32),
         # always-on queue-occupancy telemetry (DESIGN.md §14): the live
         # entry count and its high-water mark.  The peak candidate is
@@ -1457,6 +1463,8 @@ def simulate(shape: SimShape, knobs: SimKnobs, arrivals, arrival_gmns,
             st["events_processed"] = st["events_processed"] + \
                 jnp.sum(okl).astype(jnp.int32)
             st["iterations"] = st["iterations"] + 1
+            st["rx_trips"] = st["rx_trips"] + \
+                (typ == EV_BEACON_RX).astype(jnp.int32)
 
         # -- handlers -----------------------------------------------------
         if rx_on or p.faults_on:

@@ -25,6 +25,13 @@ makes the fabric an explicit, static design-space axis:
                   injection serializes on the source's local port, then
                   delivery costs Manhattan-hops x ``c_hop`` — latency
                   scales with physical distance, no shared medium.
+  ``torus2d``     the same grid with wraparound links in both dimensions
+                  (Kalray MPPA-256's 2D-torus NoC, de Dinechin et al.,
+                  HPEC 2013): the ``mesh2d`` cost model over the shorter
+                  way round each dimension (:func:`hop_table`).
+
+``MESH_KINDS`` names the two grid fabrics; they share every cost rule
+and differ only in their hop table.
 
 Like ``SimPolicy``, a ``Topology`` is hashable and static: each kind
 compiles its own XLA program, and the untaken fabric models cost
@@ -55,8 +62,11 @@ best-effort (a down link or dead receiver drops the delivery into
 ``msgs_lost``, generalizing conservation to ``beacons_rx + msgs_lost
 == (k-1) * beacons_tx``), while task-start groups and join-exit
 forwards are reliable and pay :func:`link_penalty` — a detour
-(``2 * c_hop`` on mesh2d) or retransmit grant pair (``2 * c_b``
-elsewhere) counted in ``reroutes``.  On an all-up mask every penalty is
+(``2 * c_hop`` on the grid fabrics) or retransmit grant pair (``2 * c_b``
+elsewhere) counted in ``reroutes``.  On ``torus2d`` the detour cost is
+an assumption carried over from the mesh: a torus has a second way
+round, which may be shorter or longer than the mesh's two-hop detour,
+and no published figure fixes it.  On an all-up mask every penalty is
 exactly 0.0, so the fault-aware programs reproduce the frozen goldens
 bitwise.
 """
@@ -68,7 +78,9 @@ from dataclasses import dataclass
 import jax.numpy as jnp
 import numpy as np
 
-TOPOLOGIES = ("ideal", "shared_bus", "hier_tree", "mesh2d")
+TOPOLOGIES = ("ideal", "shared_bus", "hier_tree", "mesh2d", "torus2d")
+# the grid fabrics: source-port injection, then hops x c_hop
+MESH_KINDS = ("mesh2d", "torus2d")
 
 
 @dataclass(frozen=True)
@@ -106,6 +118,23 @@ def mesh_hops(k: int) -> np.ndarray:
             + np.abs(y[:, None] - y[None, :])).astype(np.int32)
 
 
+def hop_table(kind: str, k: int) -> np.ndarray:
+    """(k, k) hop counts of a fabric's GMN placement.  ``torus2d`` places
+    GMNs as :func:`mesh_hops` does and wraps each dimension at its own
+    length (rows = ceil(k / side), columns = side), so a hop count is
+    ``min(|dx|, rows - |dx|) + min(|dy|, side - |dy|)``; every other kind
+    takes :func:`mesh_hops` (only the grid fabrics read it)."""
+    if kind != "torus2d":
+        return mesh_hops(k)
+    s = grid_side(k)
+    rows = -(-k // s)
+    pos = np.arange(k)
+    dx = np.abs(pos[:, None] // s - pos[None, :] // s)
+    dy = np.abs(pos[:, None] % s - pos[None, :] % s)
+    return (np.minimum(dx, rows - dx)
+            + np.minimum(dy, s - dy)).astype(np.int32)
+
+
 # ==========================================================================
 # Traced fabric primitives (used by repro.core.sim's event handlers).
 #
@@ -138,7 +167,7 @@ def unicast(topo: Topology, src, dst, t_ready, is_remote, *, gbus, lbus,
         t_in = jnp.maximum(t_g, lbus[dst]) + c_b
         lbus = jnp.where(is_remote, _set1(lbus, dst, t_in), lbus)
         t_arr = jnp.where(is_remote, t_in, t_ready)
-    elif topo.kind == "mesh2d":
+    elif topo.kind in MESH_KINDS:
         # serialized injection at the source port, then hop latency
         t_inj = jnp.maximum(t_ready, lbus[src]) + c_b
         lbus = jnp.where(is_remote, _set1(lbus, src, t_inj), lbus)
@@ -163,9 +192,10 @@ def link_penalty(topo: Topology, up, is_remote, *, c_b, c_hop):
     (DESIGN.md §13).  Reliable messages are never lost — the fabric
     detours them:
 
-      mesh2d     the XY route is blocked; the dimension-ordered detour
-                 around the failed link costs two extra hops
-                 (``2 * c_hop``).
+      mesh2d,    the XY route is blocked; the dimension-ordered detour
+      torus2d    around the failed link costs two extra hops
+                 (``2 * c_hop``; on the torus an assumption, see the
+                 module docstring).
       otherwise  the bus-based fabrics retransmit through the
                  supervisor path: one extra grant pair (``2 * c_b``).
 
@@ -174,7 +204,7 @@ def link_penalty(topo: Topology, up, is_remote, *, c_b, c_hop):
     exact no-op on an all-up mask, which is the bitwise no-fault
     contract the frozen goldens ride on.  ``up`` is the (src, dst) entry
     of the traced ``link_up`` mask."""
-    base = 2.0 * (c_hop if topo.kind == "mesh2d" else c_b)
+    base = 2.0 * (c_hop if topo.kind in MESH_KINDS else c_b)
     hit = jnp.logical_and(is_remote, up == 0)
     return jnp.where(hit, base, 0.0)
 
@@ -207,7 +237,7 @@ def beacon_tx(topo: Topology, g, t, fire, *, gbus, lbus, c_b, c_hop, hops,
         rcv = jnp.arange(k) != g
         lbus = jnp.where(jnp.logical_and(fire, rcv), t_arr, lbus)
         return t_g, t_arr, gbus, lbus
-    if topo.kind == "mesh2d":
+    if topo.kind in MESH_KINDS:
         # one serialized injection, then per-receiver hop latency
         t_inj = jnp.maximum(t, lbus[g]) + c_b
         lbus = jnp.where(fire, _set1(lbus, g, t_inj), lbus)
@@ -230,7 +260,7 @@ def _set1(arr, i, val):
 # The serving engine has no tick-granular bus occupancy; the analog is a
 # stateless per-receiver delay vector with the same *shape* as the
 # tick-domain fabric: shared_bus serializes receivers, hier_tree pays a
-# fixed two-hop crossing, mesh2d pays hop-count latency.  ``ideal``
+# fixed two-hop crossing, the grid fabrics pay hop-count latency.  ``ideal``
 # returns all-zero delays, which FleetSim treats as instant delivery —
 # exactly the pre-transport `_broadcast` fan-out.
 # ==========================================================================
@@ -258,7 +288,7 @@ def max_delivery_delay(kind: str, k: int, *, c_b: float = 1.0,
         return float(max(k - 1, 1)) * c_b            # k-1 serialized unicasts
     if kind == "hier_tree":
         return 2.0 * c_b                             # global + local grant
-    return c_b + float(mesh_hops(k).max()) * c_hop   # mesh2d
+    return c_b + float(hop_table(kind, k).max()) * c_hop   # MESH_KINDS
 
 
 def host_beacon_delays(kind: str, k: int, src: int, *, c_b: float = 1.0,
@@ -276,7 +306,7 @@ def host_beacon_delays(kind: str, k: int, src: int, *, c_b: float = 1.0,
         d = rank * c_b
     elif kind == "hier_tree":
         d = np.full(k, 2.0 * c_b)                    # global + local hop
-    elif kind == "mesh2d":
-        d = c_b + mesh_hops(k)[src] * c_hop
+    elif kind in MESH_KINDS:
+        d = c_b + hop_table(kind, k)[src] * c_hop
     d[src] = 0.0
     return d

@@ -178,7 +178,8 @@ class FleetSim:
     interconnect transport (``core/transport.host_beacon_delays``,
     DESIGN.md §10): under the default ``ideal`` topology every receiver's
     view updates the instant a beacon fires (the historical `_broadcast`
-    fan-out); under ``shared_bus`` / ``hier_tree`` / ``mesh2d`` each
+    fan-out); under ``shared_bus`` / ``hier_tree`` / ``mesh2d`` /
+    ``torus2d`` each
     receiver sees the update only after its per-receiver delay, so remote
     views age heterogeneously just like in the tick-domain simulator."""
 

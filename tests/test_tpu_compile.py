@@ -75,10 +75,10 @@ def test_flash_attention_compiles(one_chip):
     assert _kernel_in_hlo(compiled)
 
 
-def test_paper_point_sweep_compiles_and_fits(one_chip):
-    """The batched event loop at the paper's m=256 deployment (k=16,
-    hier_tree fabric, tree queue, batch_pop=64) over 4 thresholds x 2
-    seeds: the program ``ExperimentSpec.run`` dispatches on a chip."""
+def _paper_point_sweep(one_chip, topology):
+    """The batched event loop at the paper's m=256 deployment (k=16, tree
+    queue, batch_pop=64) over 4 thresholds x 2 seeds on ``topology``: the
+    program ``ExperimentSpec.run`` dispatches on a chip, compiled."""
     from repro.core import sweep as SW
     from repro.core.sim import SimParams
     from repro.core.transport import Topology
@@ -86,11 +86,22 @@ def test_paper_point_sweep_compiles_and_fits(one_chip):
                   queue_impl="tree", batch_pop=64)
     b, s = 4, 2
     knobs = jax.tree.map(lambda x: _sds((b,), x.dtype, one_chip), p.knobs)
-    compiled = SW._sweep.lower(
+    return SW._sweep.lower(
         p.shape, knobs,
         _sds((s, p.max_apps), jnp.float32, one_chip),
         _sds((s, p.max_apps), jnp.int32, one_chip),
         _sds((s, p.max_apps, p.n_childs), jnp.float32, one_chip),
         _sds((), jnp.float32, one_chip),
-        p.policy, Topology("hier_tree"), None, None).compile()
+        p.policy, Topology(topology), None, None).compile()
+
+
+def test_paper_point_sweep_compiles_and_fits(one_chip):
+    """The paper's point on the hier_tree fabric."""
+    compiled = _paper_point_sweep(one_chip, "hier_tree")
+    assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_torus_point_sweep_compiles_and_fits(one_chip):
+    """The same deployment on the MPPA-256's 2D torus (torus2d)."""
+    compiled = _paper_point_sweep(one_chip, "torus2d")
     assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM_BYTES
