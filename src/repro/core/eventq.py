@@ -130,8 +130,12 @@ QUEUE_IMPLS = ("linear", "tree", "calendar")
 
 # Free-slot accounting granularity: one counter per ALLOC_SEG queue
 # slots.  64 keeps the per-push cumsum at Q/64 elements (512 at the
-# paper-scale Q=32768) while the within-segment search stays one small
-# (n, 64) gathered window.
+# paper-scale Q=32768).  The within-segment search reads each entry's
+# 64 free flags as one row of a dense (S, 64) table built from the leaf
+# times in one pass, not as n x 64 gathered leaf times: a TPU gather
+# costs about 10 ns per index, and each leaf time sits in its own
+# (..., 6) row, padded to 128 lanes, so a per-slot window cost more than
+# a whole pass over the leaves (DESIGN.md §11).
 ALLOC_SEG = 64
 
 # Segments per super counter.  Kept in sync on every pop/push; the
@@ -213,14 +217,20 @@ def _root(tree, queue_cap: int):
     return tree[jnp.argmin(tree[:queue_cap, 0])]
 
 
-def build_freecnt(free_mask):
-    """(queue_cap,) bool free mask -> (S,) i32 per-segment free-slot
-    counts (the last segment may cover fewer than ALLOC_SEG slots)."""
+def _seg_rows(free_mask):
+    """(queue_cap,) bool free mask -> (S, ALLOC_SEG) bool, one row per
+    segment; the slots past queue_cap in the last row read taken."""
     q = free_mask.shape[0]
     s = seg_count(q)
     pad = jnp.zeros((s * ALLOC_SEG - q,), bool)
-    return jnp.concatenate([jnp.asarray(free_mask, bool), pad]) \
-        .reshape(s, ALLOC_SEG).sum(axis=1).astype(jnp.int32)
+    return jnp.concatenate([free_mask, pad]).reshape(s, ALLOC_SEG)
+
+
+def build_freecnt(free_mask):
+    """(queue_cap,) bool free mask -> (S,) i32 per-segment free-slot
+    counts (the last segment may cover fewer than ALLOC_SEG slots)."""
+    return _seg_rows(jnp.asarray(free_mask, bool)).sum(axis=1) \
+        .astype(jnp.int32)
 
 
 def build_cal(times, typ=None, a=None, width=None):
@@ -392,11 +402,10 @@ def _alloc(arr, leaf_base, cnt_base, sup_base, s, queue_cap, mask, times):
         below = jnp.take_along_axis(
             cs, jnp.maximum(seg_off - 1, 0)[:, None], axis=1)[:, 0]
         r = r_sup - jnp.where(seg_off > 0, below, 0)
-    # the (r+1)-th free slot inside the segment, from a window of leaf
-    # times (INF = free)
-    cols = segc[:, None] * ALLOC_SEG + jnp.arange(ALLOC_SEG)[None, :]
-    window = arr[leaf_base + jnp.minimum(cols, q - 1), 0]
-    free_w = jnp.logical_and(window >= INF, cols < q)
+    # the (r+1)-th free slot inside the segment: one dense pass marks
+    # the free leaves (INF time) as an (S, 64) table, and each entry
+    # takes its segment's row (see ALLOC_SEG)
+    free_w = _seg_rows(arr[leaf_base:leaf_base + q, 0] >= INF)[segc]
     hit = jnp.logical_and(free_w,
                           jnp.cumsum(free_w, axis=1) == r[:, None] + 1)
     slot = segc * ALLOC_SEG + jnp.argmax(hit, axis=1).astype(jnp.int32)

@@ -7,6 +7,7 @@ under queue_impl="tree"."""
 import re
 
 import jax
+import jax.extend.core as jex
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -230,6 +231,70 @@ def test_overflow_drops_match_linear_accounting():
     # full queue: everything drops
     q = _push(q, np.array([99.0], np.float32), cap=cap)
     assert int(q["dropped"]) == 4
+
+
+def _alloc_case(rng, q, n, leaf_base, fill):
+    """One allocator input: the queue array (tree rows at ``leaf_base``
+    0, calendar rows at 1) with its counter bases, and a push batch."""
+    times = rng.uniform(1, 1e6, q).astype(np.float32)
+    n_free = q // 2 if fill == "sparse" else max(1, n // 3)
+    times[rng.choice(q, min(n_free, q), replace=False)] = INF
+    if leaf_base == 0:
+        arr = EQ.build_tree(jnp.asarray(times))
+        cnt_base = q
+    else:
+        arr = EQ.build_cal(jnp.asarray(times))
+        cnt_base = EQ._cal_bases(q)[2]
+    mask = rng.random(n) < 0.8
+    push_t = np.where(rng.random(n) < 0.1, INF,
+                      rng.uniform(1, 1e6, n)).astype(np.float32)
+    return arr, cnt_base, times >= INF, mask, push_t
+
+
+def _first_free(free, mask):
+    """The linear impl's rule: the j-th masked entry takes the j-th
+    lowest free slot; masked entries past the free count drop."""
+    slots = np.flatnonzero(free)
+    rank = np.cumsum(mask) - 1
+    ok = mask & (rank < len(slots))
+    want = np.where(ok, slots[np.minimum(rank, len(slots) - 1)], -1)
+    return want, ok, max(int(mask.sum()) - len(slots), 0)
+
+
+@pytest.mark.parametrize("q,n,leaf_base,fill,lanes", [
+    (q, n, lb, fill, 1) for q in (100, 1024, 8192) for n in (31, 356)
+    for lb in (0, 1) for fill in ("sparse", "near_full")]
+    + [(1024, 356, 0, "near_full", 8)])
+def test_alloc_matches_first_free_rule(q, n, leaf_base, fill, lanes):
+    """``_alloc``'s slots, ``ok`` and drop count equal a plain NumPy
+    first-free-slot rule: a partial last segment (q=100), the tree's and
+    the calendar's leaf base, a near-full queue that overflows, pushes
+    timed at INF, and 8 vmapped lanes of distinct queues."""
+    rng = np.random.default_rng(q + n + leaf_base)
+    cases = [_alloc_case(rng, q, n, leaf_base, fill) for _ in range(lanes)]
+    s = EQ.seg_count(q)
+    cnt_base = cases[0][1]
+
+    def alloc(arr, mask, times):
+        return EQ._alloc(arr, leaf_base, cnt_base, cnt_base + s, s, q,
+                         mask, times)
+
+    arr, mask, push_t = (jnp.stack([jnp.asarray(c[i]) for c in cases])
+                         for i in (0, 3, 4))
+    if lanes == 1:
+        got = jax.jit(alloc)(arr[0], mask[0], push_t[0])
+        got = jax.tree.map(lambda x: np.asarray(x)[None], got)
+    else:
+        got = jax.tree.map(np.asarray, jax.jit(jax.vmap(alloc))(
+            arr, mask, push_t))
+    slot, _, ok, n_drop = got
+    for lane, (_, _, free, m, _) in enumerate(cases):
+        want, want_ok, want_drop = _first_free(free, m)
+        assert np.array_equal(ok[lane], want_ok)
+        assert np.array_equal(slot[lane][want_ok], want[want_ok])
+        assert int(n_drop[lane]) == want_drop
+    if fill == "near_full":
+        assert int(n_drop.max()) > 0
 
 
 def _params(**kw):
@@ -580,3 +645,41 @@ def test_tree_commit_structure_does_not_grow_with_depth():
     counts = {(cap, b): _commit_scatter_count(cap, b)
               for cap in (1024, 32768) for b in (False, True)}
     assert len(set(counts.values())) == 1, counts
+
+
+def _gather_index_counts(jaxpr):
+    """Indices of every gather in a jaxpr, nested jaxprs included."""
+    counts = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            counts.append(int(np.prod(eqn.invars[1].aval.shape[:-1])))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, jex.ClosedJaxpr):
+                    counts += _gather_index_counts(sub.jaxpr)
+                elif isinstance(sub, jex.Jaxpr):
+                    counts += _gather_index_counts(sub)
+    return counts
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_tree_commit_alloc_window_is_one_index_per_entry(batched):
+    """A commit at the k=256 push width (n=356, Q=32768) gathers no
+    n * ALLOC_SEG leaf times index by index: each entry's 64-slot window
+    is one row of the dense free table (one index per entry).  A
+    structural guard on the traced program, not a speed."""
+    cap, nb, n = 32768, 64, 356
+    st = EQ.empty(cap)
+    args = (jnp.zeros((nb,), jnp.int32), jnp.ones((nb,), bool),
+            jnp.ones((n,), bool), jnp.ones((n,), jnp.float32),
+            jnp.zeros((n,)), jnp.zeros((n,)), jnp.zeros((n,)),
+            jnp.zeros((n,)))
+
+    def f(st, *a):
+        return EQ.commit(st, *a, cap)
+
+    if batched:
+        f = jax.vmap(f)
+        st, args = jax.tree.map(lambda x: jnp.stack([x, x]), (st, args))
+    counts = _gather_index_counts(jax.make_jaxpr(f)(st, *args).jaxpr)
+    assert counts and max(counts) < n * EQ.ALLOC_SEG, counts
