@@ -1,126 +1,77 @@
 """Event-queue structures for the TLM simulator (DESIGN.md §11).
 
 The simulator's hot loop pops the earliest pending event once per
-iteration.  The historical implementation (``queue_impl="linear"``) finds
-it with ``jnp.argmin`` over the whole ``(queue_cap,)`` ``ev_time`` array,
-checks termination with a queue-wide ``min``, and inserts batches with a
-queue-wide stable ``argsort`` — O(Q)-to-O(Q log Q) work per event, which
-ROADMAP.md names as the blocker for the paper's m=256/k=256 distributed
-configuration on non-ideal fabrics (every beacon there fans out into k-1
-BEACON_RX events, so Q must be large exactly where the per-event scan
-hurts most).
-
-This module provides two replacements, selected through the static
-``queue_impl`` axis on ``SimShape`` (one XLA program per value):
+iteration (or one same-timestamp BEACON_RX cohort, see ``batch_take``)
+and commits that iteration's pushes.  This module owns the queue's
+format: ``sim.py`` calls ``resolve`` once and then only the functions
+of the ``Queue`` it returns, without knowing which format is
+underneath.  Three formats sit behind it (one XLA program each):
 
 ``"tree"`` — the name is kept for the configurations and goldens that
-name it; the structure is a **flat leaf array with a dense root**.  The
-whole queue lives in ONE ``(Q + S + S2, 6)`` f32 array ``evq_tree``
-(Q = queue_cap; S per-segment free counters; S2 super-segment counters):
+name it, though no tree is left.  The whole queue is ONE ``(Q, 6)`` f32
+array ``evq_tree`` (Q = queue_cap) of per-slot leaf rows [time, slot,
+ev_type, a0, a1, a2], slot j at row j; INF time marks a free slot.
+Slot indices and payloads are small exact integers in f32 (queue_cap is
+capped at 2**24, event arguments are app/cluster/PE indices and counts
+far below it).  The next event is the ``(6,)`` root mirror
+``evq_root``: every commit ends with ONE ``jnp.argmin`` over the leaf
+times and copies that leaf's row, so the loop condition and the pop
+read the mirror and never the big buffer.
 
-  rows 0..Q       per-slot leaf rows [time, slot, ev_type, a0, a1, a2],
-                  slot j at row j; INF time marks a free slot.  Slot
-                  indices and payloads are small exact integers in f32
-                  (queue_cap is capped at 2**24, event arguments are
-                  app/cluster/PE indices and counts far below it).
-  rows Q..Q+S     per-ALLOC_SEG-slot free counters (column 0).
-  rows Q+S..+S2   per-SUPER_SEG-segment super counters (column 0) —
-                  each the sum of its 64 segment counters, kept in sync
-                  by every pop/push so large-Q allocation can search the
-                  counter hierarchy instead of cumsum-ing all S segments
-                  (see ``_alloc``).
+``"calendar"`` — the same leaf rows inside ONE ``(1 + Q + NB, 6)``
+array ``evq_cal``: row 0 the root row, rows 1..1+Q the leaves, then NB
+bucket-summary rows.  Bucket b = floor(t/W) mod NB holds the
+lexmin-(time, slot) row among its live events (W = the traced
+``time_scale``, NB = CAL_BUCKETS).  A summary is the min over ALL years
+mapped to its bucket, so the root (min over summaries) is exact
+whatever W is — W only shapes the work.  A commit rebuilds the popped
+bucket (all pops of a trip share one time, hence one bucket) with one
+masked O(Q) pass, merges the pushed rows into their buckets with a
+scatter-min, and sets the root from the NB summaries.
 
-  The next event is the ``(6,)`` root mirror ``evq_root``: every commit
-  ends with ONE ``jnp.argmin`` over the Q leaf times and copies that
-  leaf's row.  An earlier layout kept a tournament tree of winner rows
-  above the leaves and repaired the touched root paths level by level.
-  On a TPU v5e each level's gather and scatter ran one after another
-  (13-15 levels of 81-262 us each at Q = 8192 / 32768), while one dense
-  reduction over the leaf times reads a few MB; the internal rows also
-  doubled every whole-buffer copy and select of the batched loop.
+``"linear"`` — the historical ``ev_time``/``ev_type``/``ev_a`` arrays,
+kept operation for operation as the test reference and the golden
+anchor every frozen sha in tests/test_sweep.py gates: an O(Q)
+``jnp.argmin`` pop, a queue-wide ``min`` loop condition and a
+queue-wide stable ``argsort`` push.
 
-``"calendar"`` — a **bucketed calendar prototype** for the Q=32k+
-regime: ONE ``(1 + Q + NB + S + S2, 6)`` f32 array ``evq_cal``:
+One array per queue is the point, not a convenience: a chain of
+gathers-then-scatters on a single buffer updates in place, but a
+second scatter whose indices derive from a read of another array
+forces a full copy of the big buffer per event.  A commit applies a
+whole body iteration's pops AND pushes as one scatter chain (clear the
+popped leaves -> allocate -> write the push leaves), then sets the
+root.  Handlers stage their push requests instead of pushing
+mid-handler, so the body makes exactly one commit and no queue
+scatter sits inside a ``lax.switch`` branch.
 
-  row 0           the root row — the global lexmin-(time, slot) event,
-                  maintained by every commit so cond/peek/pop read it
-                  O(1) exactly like the tree root.
-  rows 1..1+Q     per-slot leaf rows, same [time, slot, type, a0..a2]
-                  record as the tree leaves.
-  rows 1+Q..+NB   bucket summary rows: bucket b = floor(t/W) mod NB
-                  holds the lexmin-(time, slot) row among its live
-                  events (W = ``cal_width``, tuned from the c_b/c_s tick
-                  granularity; NB = CAL_BUCKETS).  Buckets partition by
-                  time-of-year, but a summary is the min over ALL years
-                  mapped to it, so the root (min over summaries) is
-                  exact regardless of wrapping — W only shapes the
-                  work distribution, never correctness.
-  remaining rows  the same segment/super free counters as the tree.
-
-  Push maintenance is incremental (scatter-min of the pushed rows into
-  their buckets, O(batch + NB)); a pop rebuilds only the popped bucket
-  (all same-timestamp pops share one bucket) with a masked O(Q)
-  reduction — ~2 fused passes instead of linear's argmin-plus-argsort,
-  amortized further by ``batch_pop``.  Classic calendar-queue O(1) does
-  not materialize under fixed-shape XLA (no host-side resizing or
-  data-dependent bucket walks), so this stays an honest O(Q/commit)
-  prototype; see benchmarks/README.md for the measured linear/tree/
-  calendar crossover.
-
-One array per queue is the point, not a convenience: XLA:CPU updates a
-chain of gathers-then-scatters on a single buffer in place, but a
-second scatter whose indices derive from a read of another array forces
-a full copy of the big buffer per event (measured ~60-100 us at
-Q=32768 — more than the whole pop).  Fusing payloads, summaries and
-counters into one buffer keeps every per-event write on one array:
-
-  cond/peek  read the root mirror: O(1) instead of a queue-wide ``min``;
-             pop needs no payload gathers at all.
-  pop        the root IS (t, slot, type, args).
-  commit     ``commit`` applies a whole body iteration's pops AND pushes
-             as one scatter chain (clear pops -> return counters ->
-             allocate -> write push leaves -> take counters), then sets
-             the root from the final leaf times.  Handlers stage their
-             push requests instead of pushing mid-handler, so the body
-             issues exactly one commit — the fused-commit architecture
-             that keeps every queue scatter out of ``lax.switch``
-             branches (a scatter inside a branch region forces XLA:CPU
-             to copy the whole buffer in the executed branch; measured
-             ~1.4 ms/iter at 4 MB vs ~40 us staged).
-  bulk push  allocate slots from the free counters: a cumsum +
-             ``searchsorted`` over the segment counters finds each
-             entry's segment, a gathered (n, 64) window of leaf times
-             finds the exact slot — so the j-th masked entry takes the
-             j-th lowest free slot, bitwise the linear impl's
-             first-free-slot rule — then one scatter writes the leaves.
-
-Everything is fixed-shape with no data-dependent control flow: updates
-are ``.at[].set`` writes with traced indices (out-of-range lanes dropped
-via ``mode="drop"``), so masked entries simply write nothing.  That
-keeps the structure vmap-able and scan-friendly — ``sweep.py``'s "vmap"
-and "seq" modes stay bitwise identical under ``queue_impl="tree"``
-(tests/test_eventq.py), and the whole queue state is one ordinary
-state-dict leaf.
+Slot rule, shared by every format: the j-th masked entry of a push
+batch takes the j-th lowest free slot, and masked entries past the
+free count drop (counted in ``dropped``).  ``_alloc`` finds the slots
+from a dense ``(S, ALLOC_SEG)`` table of free flags: a cumsum of its
+row sums and ``searchsorted`` give each entry's segment, and the
+entry's row of the table gives the slot within it.
 
 Tie-breaking contract: ``jnp.argmin`` returns the LOWEST index among
-equal minima, and same-timestamp events must pop in identical order
-under every impl.  The tree's root is that argmin over the leaf times
-itself, so it is the lexmin of (time, slot)
-(tests/test_eventq.py::test_pop_slot_matches_argmin_under_ties); the
-calendar keeps the same contract through its explicit (time, slot)
-lexmin.  ``batch_take`` extends the contract to same-timestamp batching:
-the selected window is the contiguous slot-order prefix of the
-root-time cohort consisting of BEACON_RX events only, stopping at the
-first tied non-RX slot — exactly the events a singleton loop would pop
-consecutively (BEACON_RX handlers push nothing, so the cohort cannot
-change mid-prefix).
+equal minima, so every format pops the lexmin of (time, slot)
+(tests/test_eventq.py::test_pop_slot_matches_argmin_under_ties; the
+calendar takes the lexmin explicitly).  ``batch_take`` extends it to
+same-timestamp batching: the selected window is the contiguous
+slot-order prefix of the root-time cohort consisting of BEACON_RX
+events only, stopping at the first tied non-RX slot — exactly the
+events a singleton loop would pop consecutively (BEACON_RX handlers
+push nothing, so the cohort cannot change mid-prefix).
 
-``"linear"`` keeps the historical code operation-for-operation — the
-golden anchor every frozen sha in tests/test_sweep.py gates — and the
-other impls route pop/push through this module with bitwise-identical
-results.
+Everything is fixed-shape with no data-dependent control flow: updates
+are ``.at[].set`` writes with traced indices (out-of-range lanes
+dropped via ``mode="drop"``), so masked entries write nothing, and the
+queue stays vmap-able — ``sweep.py``'s "vmap" and "seq" modes are
+bitwise identical under every format.
 """
 from __future__ import annotations
+
+import functools
+from typing import Callable, NamedTuple
 
 import jax.numpy as jnp
 
@@ -128,56 +79,66 @@ INF = jnp.float32(1e18)
 
 QUEUE_IMPLS = ("linear", "tree", "calendar")
 
-# Free-slot accounting granularity: one counter per ALLOC_SEG queue
-# slots.  64 keeps the per-push cumsum at Q/64 elements (512 at the
-# paper-scale Q=32768).  The within-segment search reads each entry's
-# 64 free flags as one row of a dense (S, 64) table built from the leaf
-# times in one pass, not as n x 64 gathered leaf times: a TPU gather
-# costs about 10 ns per index, and each leaf time sits in its own
-# (..., 6) row, padded to 128 lanes, so a per-slot window cost more than
-# a whole pass over the leaves (DESIGN.md §11).
+# Free-slot search granularity: the allocator's cumsum runs over
+# Q / ALLOC_SEG segment counts (512 at the paper-scale Q=32768), and
+# each entry reads its segment's 64 free flags as one row of a dense
+# (S, 64) table built from the leaf times in one pass, not as n x 64
+# gathered leaf times: a TPU gather costs about 10 ns per index, and
+# each leaf time sits in its own (..., 6) row, padded to 128 lanes
+# (DESIGN.md §11).
 ALLOC_SEG = 64
-
-# Segments per super counter.  Kept in sync on every pop/push; the
-# allocator only *searches* through the super level once the flat
-# segment cumsum would be wider than HIER_MIN_SEGS (below that the flat
-# cumsum is cheaper than the per-entry (n, 64) segment window the
-# hierarchy needs — measured in DESIGN.md §11).
-SUPER_SEG = 64
-HIER_MIN_SEGS = 1024
 
 # Calendar-queue bucket count (static; capped by queue_cap).
 CAL_BUCKETS = 256
 
 # Queue slots (and event payloads) are stored as exact small integers in
-# the tree's f32 columns.
+# the leaf rows' f32 columns.
 MAX_QUEUE_CAP = 1 << 24
 
 # Row layout: [time, slot, ev_type, a0, a1, a2].
 ROW_W = 6
 
 
-def seg_count(queue_cap: int) -> int:
-    """Number of ALLOC_SEG-slot segments covering the queue."""
-    return -(-queue_cap // ALLOC_SEG)
+class Queue(NamedTuple):
+    """The seam between the simulator and one queue format at one
+    capacity (``resolve``).
 
+    state()                 the empty queue's state-dict leaves
+    live(st)                whether any event is pending (loop condition)
+    root(st)                the next event: ``(t, slot, typ, a)``, with
+                            ``slot``/``typ`` i32 and ``a`` (3,) i32
+    leaves(st)              (Q,) per-slot times (INF = free) and (Q, 4)
+                            f32 payloads [ev_type, a0, a1, a2], for the
+                            same-timestamp cohort scan
+    held                    the state keys the body holds out of its
+                            ``lax.switch`` (written only by the commit)
+    commit(st, pop_slots, pop_ok, mask, times, typ, a0, a1, a2)
+                            one iteration's pops (all at the root's
+                            time), then its pushes
+    bulk_push(st, mask, times, typ, a0, a1, a2)
+                            pushes alone (the set-up's seeding)
 
-def super_count(queue_cap: int) -> int:
-    """Number of SUPER_SEG-segment super counters."""
-    return -(-seg_count(queue_cap) // SUPER_SEG)
-
-
-def cal_buckets(queue_cap: int) -> int:
-    """Static calendar bucket count for a queue (never exceeds Q)."""
-    return min(CAL_BUCKETS, max(1, queue_cap))
+    ``typ`` may be a scalar or a per-entry array."""
+    state: Callable
+    live: Callable
+    root: Callable
+    leaves: Callable
+    held: tuple
+    commit: Callable
+    bulk_push: Callable
 
 
 # --------------------------------------------------------------------------
-# Full builds (vectorized, O(Q)): initial state + test fixtures.
+# Shared by the tree and the calendar: leaf rows, root mirror, allocator.
 # --------------------------------------------------------------------------
 
-def _leaf_rows(times, typ, a):
+def build_tree(times, typ=None, a=None):
+    """(queue_cap,) event times (+ optional payloads: ``typ`` (Q,) and
+    ``a`` (Q, 3)) -> the ``evq_tree`` leaf rows."""
     q = times.shape[0]
+    if q > MAX_QUEUE_CAP:
+        raise ValueError(f"queue_cap {q} exceeds the exact-f32 slot-index "
+                         f"range ({MAX_QUEUE_CAP})")
     times = jnp.asarray(times, jnp.float32)
     typ = jnp.zeros((q,), jnp.float32) if typ is None \
         else jnp.asarray(typ, jnp.float32)
@@ -188,303 +149,350 @@ def _leaf_rows(times, typ, a):
         a], axis=-1)
 
 
-def _counter_rows(times):
-    """Segment + super free-counter rows from the leaf times."""
-    free = jnp.asarray(times, jnp.float32) >= INF
-    segc = build_freecnt(free).astype(jnp.float32)
-    s = segc.shape[0]
-    s2 = -(-s // SUPER_SEG)
-    sup = jnp.concatenate([segc, jnp.zeros((s2 * SUPER_SEG - s,))]) \
-        .reshape(s2, SUPER_SEG).sum(axis=1)
-    rows = jnp.zeros((s + s2, ROW_W))
-    return rows.at[:, 0].set(jnp.concatenate([segc, sup]))
+def _root_event(st):
+    """The next event from the ``evq_root`` mirror."""
+    root = st["evq_root"]
+    return (root[0], root[1].astype(jnp.int32), root[2].astype(jnp.int32),
+            root[3:].astype(jnp.int32))
 
 
-def build_tree(times, typ=None, a=None):
-    """(queue_cap,) event times (+ optional payloads: ``typ`` (Q,) and
-    ``a`` (Q, 3)) -> the full ``evq_tree`` array: the leaf rows, then the
-    free counters."""
-    q = times.shape[0]
-    if q > MAX_QUEUE_CAP:
-        raise ValueError(f"queue_cap {q} exceeds the exact-f32 slot-index "
-                         f"range ({MAX_QUEUE_CAP})")
-    return jnp.concatenate([_leaf_rows(times, typ, a), _counter_rows(times)])
+def _live(st):
+    return st["evq_root"][0] < INF
 
 
-def _root(tree, queue_cap: int):
+def _clear_rows(pop_slots):
+    """The free leaf rows the popped slots are reset to."""
+    return jnp.zeros((pop_slots.shape[0], ROW_W)) \
+        .at[:, 0].set(INF) \
+        .at[:, 1].set(pop_slots.astype(jnp.float32))
+
+
+def _push_rows(times, slot, typ, a0, a1, a2):
+    """The leaf rows a push batch writes at its allocated slots."""
+    shape = times.shape
+    return jnp.stack([
+        times, slot.astype(jnp.float32),
+        jnp.broadcast_to(jnp.asarray(typ, jnp.float32), shape),
+        jnp.broadcast_to(jnp.asarray(a0, jnp.float32), shape),
+        jnp.broadcast_to(jnp.asarray(a1, jnp.float32), shape),
+        jnp.broadcast_to(jnp.asarray(a2, jnp.float32), shape)], -1)
+
+
+def _alloc(leaf_t, mask):
+    """Slot allocator: the j-th masked entry takes the j-th lowest free
+    slot of the (Q,) leaf times (INF = free; bitwise the linear format's
+    first-free-slot rule).  Returns ``(slot, ok, n_dropped)``.
+
+    One dense pass marks the free leaves as an (S, 64) table, padded
+    with taken slots past Q; the cumsum of its row sums and
+    ``searchsorted`` find each entry's segment, and the entry's row of
+    the table the slot within it (see ALLOC_SEG)."""
+    mask = jnp.asarray(mask, bool)
+    rank = jnp.cumsum(mask.astype(jnp.int32)) - 1    # rank among masked
+    pad = jnp.zeros((-leaf_t.shape[0] % ALLOC_SEG,), bool)
+    free = jnp.concatenate([leaf_t >= INF, pad]).reshape(-1, ALLOC_SEG)
+    csum = jnp.cumsum(free.sum(axis=1, dtype=jnp.int32))
+    total_free = csum[-1]
+    # first segment whose cumulative free count reaches rank+1
+    seg = jnp.searchsorted(csum, rank + 1, side="left").astype(jnp.int32)
+    segc = jnp.minimum(seg, csum.shape[0] - 1)       # clamped (overflow)
+    r = rank - jnp.where(segc > 0, csum[segc - 1], 0)  # rank in segment
+    free_w = free[segc]
+    hit = jnp.logical_and(free_w,
+                          jnp.cumsum(free_w, axis=1) == r[:, None] + 1)
+    slot = segc * ALLOC_SEG + jnp.argmax(hit, axis=1).astype(jnp.int32)
+    ok = jnp.logical_and(mask, rank < total_free)
+    return slot, ok, jnp.maximum(mask.sum() - total_free, 0)
+
+
+# --------------------------------------------------------------------------
+# "tree": the (Q, 6) leaf array with a dense root.
+# --------------------------------------------------------------------------
+
+def _root(tree):
     """The next event of an ``evq_tree`` array: the row of the leaf at
     the lowest slot among equal minimum times (``jnp.argmin``'s rule)."""
-    return tree[jnp.argmin(tree[:queue_cap, 0])]
-
-
-def _seg_rows(free_mask):
-    """(queue_cap,) bool free mask -> (S, ALLOC_SEG) bool, one row per
-    segment; the slots past queue_cap in the last row read taken."""
-    q = free_mask.shape[0]
-    s = seg_count(q)
-    pad = jnp.zeros((s * ALLOC_SEG - q,), bool)
-    return jnp.concatenate([free_mask, pad]).reshape(s, ALLOC_SEG)
-
-
-def build_freecnt(free_mask):
-    """(queue_cap,) bool free mask -> (S,) i32 per-segment free-slot
-    counts (the last segment may cover fewer than ALLOC_SEG slots)."""
-    return _seg_rows(jnp.asarray(free_mask, bool)).sum(axis=1) \
-        .astype(jnp.int32)
-
-
-def build_cal(times, typ=None, a=None, width=None):
-    """(queue_cap,) event times (+ optional payloads) -> the full
-    ``evq_cal`` array: root row, leaf rows, bucket summaries, counters."""
-    q = times.shape[0]
-    if q > MAX_QUEUE_CAP:
-        raise ValueError(f"queue_cap {q} exceeds the exact-f32 slot-index "
-                         f"range ({MAX_QUEUE_CAP})")
-    nb = cal_buckets(q)
-    width = jnp.float32(1.0) if width is None \
-        else jnp.asarray(width, jnp.float32)
-    leaves = _leaf_rows(times, typ, a)
-    cal = jnp.concatenate([
-        jnp.zeros((1, ROW_W)).at[0, 0].set(INF),
-        leaves,
-        jnp.zeros((nb, ROW_W)).at[:, 0].set(INF),
-        _counter_rows(times)])
-    # exact summaries + root via the commit path's full-rebuild mode
-    st = {"evq_cal": cal, "dropped": jnp.zeros((), jnp.int32)}
-    st = cal_commit(st, jnp.zeros((0,), jnp.int32), jnp.zeros((0,), bool),
-                    INF, leaves[:, 0] < INF, leaves[:, 0], leaves[:, 2],
-                    leaves[:, 3], leaves[:, 4], leaves[:, 5], q, width,
-                    _prebuilt_slots=True)
-    return st["evq_cal"]
+    return tree[jnp.argmin(tree[:, 0])]
 
 
 def queue_state(queue_cap: int) -> dict:
-    """The state-dict leaf of ``queue_impl="tree"`` (an empty queue: all
-    times INF, all slots free).  The linear impl's ``ev_time`` /
-    ``ev_type`` / ``ev_a`` arrays do not exist in tree mode — times and
-    payloads live in the tree rows (``leaf_times``/``leaf_payloads``).
+    """The state-dict leaves of ``queue_impl="tree"`` (an empty queue:
+    all times INF, all slots free).
 
-    ``evq_root`` holds the root row (``_root``) in a separate
-    (ROW_W,) buffer, maintained by ``pop``/``commit``.  The simulator
-    body and loop condition read the next event from it, NEVER from the
-    big tree buffer: any read of the tree outside the commit chain gets
+    ``evq_root`` holds the root row (``_root``) in a separate (ROW_W,)
+    buffer, maintained by ``commit``.  The simulator body and loop
+    condition read the next event from it, NEVER from the big leaf
+    buffer: any read of the buffer outside the commit chain gets
     rematerialized by XLA fusion at its consumers, and a read of the
     original value after the in-place chain has started makes
     copy-insertion clone the whole buffer every iteration (measured
     ~850 us/event at the k=256 paper point — the entire cost of the
     pre-fused simulator)."""
     tr = build_tree(jnp.full((queue_cap,), INF))
-    return {"evq_tree": tr, "evq_root": _root(tr, queue_cap)}
-
-
-def cal_state(queue_cap: int) -> dict:
-    """The state-dict leaf of ``queue_impl="calendar"`` (empty queue).
-    ``evq_root`` mirrors row 0 (see ``queue_state``)."""
-    c = build_cal(jnp.full((queue_cap,), INF))
-    return {"evq_cal": c, "evq_root": c[0]}
-
-
-# --------------------------------------------------------------------------
-# Views (tests, debugging).
-# --------------------------------------------------------------------------
-
-def leaf_times(st, queue_cap: int):
-    """(Q,) per-slot event times from the leaf rows — INF marks a free
-    slot.  Authoritative in tree mode (there is no ``ev_time``)."""
-    return st["evq_tree"][:queue_cap, 0]
-
-
-def leaf_payloads(st, queue_cap: int):
-    """(Q, 4) per-slot [ev_type, a0, a1, a2] from the leaf rows."""
-    return st["evq_tree"][:queue_cap, 2:]
-
-
-def freecnt(st, queue_cap: int):
-    """(S,) i32 per-segment free counts from the counter rows."""
-    s = seg_count(queue_cap)
-    return st["evq_tree"][queue_cap:queue_cap + s, 0].astype(jnp.int32)
-
-
-def supercnt(st, queue_cap: int):
-    """(S2,) i32 super-segment free counts from the counter rows."""
-    return st["evq_tree"][queue_cap + seg_count(queue_cap):, 0] \
-        .astype(jnp.int32)
-
-
-def cal_leaf_times(st, queue_cap: int):
-    """(Q,) per-slot event times from the calendar leaf rows."""
-    return st["evq_cal"][1:1 + queue_cap, 0]
-
-
-def cal_leaf_payloads(st, queue_cap: int):
-    """(Q, 4) per-slot [ev_type, a0, a1, a2] from the calendar leaves."""
-    return st["evq_cal"][1:1 + queue_cap, 2:]
-
-
-def cal_freecnt(st, queue_cap: int):
-    """(S,) i32 per-segment free counts from the calendar counters."""
-    base = 1 + queue_cap + cal_buckets(queue_cap)
-    s = seg_count(queue_cap)
-    return st["evq_cal"][base:base + s, 0].astype(jnp.int32)
-
-
-# --------------------------------------------------------------------------
-# Queue operations on the simulator state dict.
-# --------------------------------------------------------------------------
-
-def peek_time(st):
-    """Earliest pending event time — the root mirror, O(1).  The
-    tree-mode while-loop condition is ``peek_time(st) < INF``."""
-    return st["evq_root"][0]
-
-
-def cal_peek_time(st):
-    """Calendar twin of ``peek_time``: the maintained root row."""
-    return st["evq_cal"][0, 0]
-
-
-def pop(st, queue_cap: int):
-    """Pop the earliest event: the root mirror IS the event — no payload
-    gathers.  Commits the pop alone: clear the leaf, return its
-    counters, set the dense root.  Returns ``(st, t, slot, typ, a)``
-    with ``typ`` i32 and ``a`` (3,) i32 — exactly the values linear mode
-    reads from ``ev_type``/``ev_a``."""
-    root = st["evq_root"]
-    t = root[0]
-    slot = root[1].astype(jnp.int32)
-    typ = root[2].astype(jnp.int32)
-    a = root[3:].astype(jnp.int32)
-    z = jnp.zeros((0,), jnp.float32)
-    st = commit(st, slot[None], jnp.ones((1,), bool), jnp.zeros((0,), bool),
-                z, z, z, z, z, queue_cap)
-    return st, t, slot, typ, a
-
-
-def _alloc(arr, leaf_base, cnt_base, sup_base, s, queue_cap, mask, times):
-    """Shared slot allocator: j-th masked entry -> j-th lowest free slot
-    (bitwise the linear impl's first-free-slot rule), reading the free
-    counters at ``cnt_base`` and the leaf times at ``leaf_base`` of one
-    queue array.  Returns ``(slot, segc, ok, n_dropped)``.
-
-    Two search strategies, chosen statically: below HIER_MIN_SEGS
-    segments a flat cumsum over all S segment counters is cheapest; at
-    large Q the allocator cumsums the S2 = S/64 super counters instead
-    and only gathers the (n, 64) segment-counter window of each entry's
-    super — O(S/64 + 64n) vs O(S), the touched-segments fix of the old
-    unconditional O(Q/64) cumsum (DESIGN.md §11)."""
-    q = queue_cap
-    mask = jnp.asarray(mask, bool)
-    times = jnp.asarray(times, jnp.float32)
-    rank = jnp.cumsum(mask.astype(jnp.int32)) - 1    # rank among masked
-    cnt = mask.sum()
-    if s < HIER_MIN_SEGS:
-        csum = jnp.cumsum(arr[cnt_base:cnt_base + s, 0].astype(jnp.int32))
-        total_free = csum[-1]
-        # first segment whose cumulative free count reaches rank+1
-        seg = jnp.searchsorted(csum, rank + 1, side="left").astype(jnp.int32)
-        segc = jnp.minimum(seg, s - 1)               # clamped (overflow)
-        r = rank - jnp.where(segc > 0, csum[segc - 1], 0)  # rank in segment
-    else:
-        s2 = -(-s // SUPER_SEG)
-        csup = jnp.cumsum(arr[sup_base:sup_base + s2, 0].astype(jnp.int32))
-        total_free = csup[-1]
-        sup = jnp.searchsorted(csup, rank + 1, side="left").astype(jnp.int32)
-        supc = jnp.minimum(sup, s2 - 1)
-        r_sup = rank - jnp.where(supc > 0, csup[supc - 1], 0)
-        segw_cols = supc[:, None] * SUPER_SEG \
-            + jnp.arange(SUPER_SEG)[None, :]         # (n, 64) segment ids
-        segw = arr[cnt_base + jnp.minimum(segw_cols, s - 1), 0] \
-            .astype(jnp.int32) * (segw_cols < s)
-        cs = jnp.cumsum(segw, axis=1)
-        seg_off = jnp.argmax(cs >= r_sup[:, None] + 1, axis=1) \
-            .astype(jnp.int32)
-        segc = jnp.minimum(supc * SUPER_SEG + seg_off, s - 1)
-        below = jnp.take_along_axis(
-            cs, jnp.maximum(seg_off - 1, 0)[:, None], axis=1)[:, 0]
-        r = r_sup - jnp.where(seg_off > 0, below, 0)
-    # the (r+1)-th free slot inside the segment: one dense pass marks
-    # the free leaves (INF time) as an (S, 64) table, and each entry
-    # takes its segment's row (see ALLOC_SEG)
-    free_w = _seg_rows(arr[leaf_base:leaf_base + q, 0] >= INF)[segc]
-    hit = jnp.logical_and(free_w,
-                          jnp.cumsum(free_w, axis=1) == r[:, None] + 1)
-    slot = segc * ALLOC_SEG + jnp.argmax(hit, axis=1).astype(jnp.int32)
-    ok = jnp.logical_and(mask, rank < total_free)
-    return slot, segc, ok, jnp.maximum(cnt - total_free, 0)
+    return {"evq_tree": tr, "evq_root": _root(tr)}
 
 
 def commit(st, pop_slots, pop_ok, mask, times, typ, a0, a1, a2,
            queue_cap: int):
     """Apply one body iteration's pops AND pushes as ONE scatter chain on
-    ``evq_tree``: clear the popped leaves, return their free counters,
-    allocate push slots (the allocator sees the just-freed slots, exactly
-    the sequential pop-then-push semantics), write the push leaves, take
-    their counters, then set the root mirror from the final leaf times.
-    ``typ`` may be a scalar or a per-entry array.
+    ``evq_tree``: clear the popped leaves, allocate push slots (the
+    allocator sees the just-freed slots, exactly the sequential
+    pop-then-push semantics), write the push leaves, then set the root
+    mirror from the final leaf times.  An entry timed at INF takes its
+    slot in the assignment order (as in the linear format) but leaves
+    it free.
 
     ``pop_slots``/``pop_ok`` are (B,) — B = 0 makes this a pure bulk
     push; masked lanes (``pop_ok`` False) are dropped from every write."""
-    q = queue_cap
     tree = st["evq_tree"]
-    s = seg_count(q)
-    cnt_base = q
-    sup_base = q + s
-    oob = tree.shape[0]
     pop_slots = jnp.asarray(pop_slots, jnp.int32)
     pop_ok = jnp.asarray(pop_ok, bool)
-    nb = pop_slots.shape[0]
+    tree = tree.at[jnp.where(pop_ok, pop_slots, queue_cap)] \
+        .set(_clear_rows(pop_slots), mode="drop")
 
-    # -- clear popped leaves + return their counters ---------------------
-    clear_rows = jnp.zeros((nb, ROW_W)) \
-        .at[:, 0].set(INF) \
-        .at[:, 1].set(pop_slots.astype(jnp.float32))
-    tree = tree.at[jnp.where(pop_ok, pop_slots, oob)] \
-        .set(clear_rows, mode="drop")
-    pseg = pop_slots // ALLOC_SEG
-    inc_idx = jnp.concatenate([
-        jnp.where(pop_ok, cnt_base + pseg, oob),
-        jnp.where(pop_ok, sup_base + pseg // SUPER_SEG, oob)])
-    tree = tree.at[inc_idx, 0].add(
-        jnp.ones((2 * nb,), jnp.float32), mode="drop")
-
-    # -- allocation (sees the freed slots) + push leaf writes ------------
-    mask = jnp.asarray(mask, bool)
     times = jnp.asarray(times, jnp.float32)
-    slot, segc, ok, n_drop = _alloc(tree, 0, cnt_base, sup_base, s, q,
-                                    mask, times)
+    slot, ok, n_drop = _alloc(tree[:, 0], mask)
     st = dict(st)
     st["dropped"] = st["dropped"] + n_drop
-    leaf_rows = jnp.stack([
-        times, slot.astype(jnp.float32),
-        jnp.broadcast_to(jnp.asarray(typ, jnp.float32), mask.shape),
-        jnp.broadcast_to(jnp.asarray(a0, jnp.float32), mask.shape),
-        jnp.broadcast_to(jnp.asarray(a1, jnp.float32), mask.shape),
-        jnp.broadcast_to(jnp.asarray(a2, jnp.float32), mask.shape)], -1)
-    tree = tree.at[jnp.where(ok, slot, oob)].set(leaf_rows, mode="drop")
-    # an ok entry with time >= INF takes its slot in the assignment order
-    # (as in linear mode) but leaves the leaf free, so it must not
-    # decrement the segment counter — counters always equal the number
-    # of INF leaves per segment (tests/test_eventq.py)
-    live = jnp.logical_and(ok, times < INF)
-    dec_idx = jnp.concatenate([
-        jnp.where(live, cnt_base + segc, oob),
-        jnp.where(live, sup_base + segc // SUPER_SEG, oob)])
-    tree = tree.at[dec_idx, 0].add(
-        jnp.full((2 * mask.shape[0],), -1.0), mode="drop")
+    tree = tree.at[jnp.where(ok, slot, queue_cap)] \
+        .set(_push_rows(times, slot, typ, a0, a1, a2), mode="drop")
     st["evq_tree"] = tree
     # the dense root reads the buffer only after its last in-place write,
     # so copy-insertion clones nothing (queue_state)
-    st["evq_root"] = _root(tree, q)
+    st["evq_root"] = _root(tree)
     return st
 
 
 def bulk_push(st, mask, times, typ, a0, a1, a2, queue_cap: int):
-    """Tree-mode twin of ``sim._bulk_push``: insert the masked entries of
-    an event batch with the identical slot-assignment rule (the j-th
-    masked entry takes the j-th lowest free slot) and identical overflow
-    accounting (excess masked entries drop).  A pure-push ``commit``."""
+    """Insert the masked entries of an event batch: a pure-push
+    ``commit``."""
     return commit(st, jnp.zeros((0,), jnp.int32), jnp.zeros((0,), bool),
                   mask, times, typ, a0, a1, a2, queue_cap)
+
+
+def empty(queue_cap: int) -> dict:
+    """A minimal standalone tree queue state (no simulator around it) —
+    the harness tests/test_eventq.py and the scatter check drive."""
+    return {"dropped": jnp.zeros((), jnp.int32)} | queue_state(queue_cap)
+
+
+# --------------------------------------------------------------------------
+# "calendar": the leaf rows under a root row and NB bucket summaries.
+# --------------------------------------------------------------------------
+
+def cal_buckets(queue_cap: int) -> int:
+    """Static calendar bucket count for a queue (never exceeds Q)."""
+    return min(CAL_BUCKETS, max(1, queue_cap))
+
+
+def _bucket(t, width, nb):
+    """floor(t / W) mod NB — only ever evaluated on finite times."""
+    return jnp.mod(jnp.floor(t / width), nb).astype(jnp.int32)
+
+
+def cal_state(queue_cap: int) -> dict:
+    """The state-dict leaves of ``queue_impl="calendar"`` (an empty
+    queue: free leaves, empty summaries, the empty root row [INF, 0, 0,
+    0, 0, 0] that an all-free bucket search returns).  ``evq_root``
+    mirrors row 0 (see ``queue_state``)."""
+    empty_row = jnp.zeros((1, ROW_W)).at[0, 0].set(INF)
+    cal = jnp.concatenate([
+        empty_row, build_tree(jnp.full((queue_cap,), INF)),
+        jnp.tile(empty_row, (cal_buckets(queue_cap), 1))])
+    return {"evq_cal": cal, "evq_root": cal[0]}
+
+
+def cal_commit(st, pop_slots, pop_ok, root_t, mask, times, typ, a0, a1, a2,
+               queue_cap: int, width):
+    """Calendar twin of ``commit``: clear pops, allocate (shared
+    ``_alloc`` — identical slot rule and drop accounting), write push
+    leaves, then maintain the summaries: rebuild the single popped
+    bucket from its leaves (all pops in a batch share the root time,
+    hence one bucket), lexmin-merge the pushed rows into theirs, and
+    rewrite the root row from the NB summaries.  ``root_t`` is the
+    shared timestamp of the popped lanes (any value when B = 0)."""
+    q = queue_cap
+    nb = cal_buckets(q)
+    sum_base = 1 + q
+    cal = st["evq_cal"]
+    oob = cal.shape[0]
+    pop_slots = jnp.asarray(pop_slots, jnp.int32)
+    pop_ok = jnp.asarray(pop_ok, bool)
+    width = jnp.asarray(width, jnp.float32)
+    cal = cal.at[jnp.where(pop_ok, 1 + pop_slots, oob)] \
+        .set(_clear_rows(pop_slots), mode="drop")
+
+    times = jnp.asarray(times, jnp.float32)
+    slot, ok, n_drop = _alloc(cal[1:1 + q, 0], mask)
+    st = dict(st)
+    st["dropped"] = st["dropped"] + n_drop
+    cal = cal.at[jnp.where(ok, 1 + slot, oob)] \
+        .set(_push_rows(times, slot, typ, a0, a1, a2), mode="drop")
+
+    # -- summary maintenance (value space) -------------------------------
+    # The summary block and root row are COMPUTED from reads of the
+    # buffer and written exactly once at the end.  Interleaving summary
+    # writes with reads of older buffer values keeps the pre-write value
+    # live across each in-place update, so XLA's copy-insertion
+    # materializes the WHOLE array per loop iteration — the §11
+    # pathology, caught by `repro.analysis` hlo_lint.
+    leaf_t = cal[1:1 + q, 0]
+    slots_f = jnp.arange(q, dtype=jnp.float32)
+    alive = leaf_t < INF
+    empty_row = jnp.zeros((ROW_W,)).at[0].set(INF)
+    # (a) rebuild the popped bucket from its leaves (sees the new pushes;
+    # exact because the pops share root_t)
+    b_pop = _bucket(jnp.where(jnp.any(pop_ok), root_t, 0.0), width, nb)
+    in_pop = jnp.logical_and(
+        alive, _bucket(jnp.where(alive, leaf_t, 0.0), width, nb) == b_pop)
+    t_in = jnp.where(in_pop, leaf_t, INF)
+    tmin = jnp.min(t_in)
+    smin = jnp.min(jnp.where(jnp.logical_and(in_pop, leaf_t == tmin),
+                             slots_f, INF))
+    found = smin < INF
+    row = cal[1 + jnp.minimum(smin, q - 1).astype(jnp.int32)]
+    new_sum = jnp.where(found, row, empty_row)
+    hit = jnp.logical_and(jnp.any(pop_ok), jnp.arange(nb) == b_pop)
+    summ = jnp.where(hit[:, None], new_sum[None, :],
+                     cal[sum_base:sum_base + nb])
+    # (b) lexmin-merge the pushed rows into their buckets
+    plive = jnp.logical_and(ok, times < INF)
+    pb = jnp.where(plive, _bucket(jnp.where(plive, times, 0.0), width, nb),
+                   nb)
+    pt = jnp.full((nb + 1,), INF).at[pb].min(
+        jnp.where(plive, times, INF), mode="drop")
+    ps = jnp.full((nb + 1,), INF).at[pb].min(
+        jnp.where(jnp.logical_and(plive, times == pt[jnp.minimum(pb, nb)]),
+                  slot.astype(jnp.float32), INF), mode="drop")
+    cand_s = ps[:nb]
+    cand_ok = cand_s < INF
+    cand = cal[1 + jnp.minimum(cand_s, q - 1).astype(jnp.int32)]
+    take_new = jnp.logical_and(cand_ok, jnp.logical_or(
+        cand[:, 0] < summ[:, 0],
+        jnp.logical_and(cand[:, 0] == summ[:, 0], cand[:, 1] < summ[:, 1])))
+    summ = jnp.where(take_new[:, None], cand, summ)
+
+    # -- root from the NB summaries, then the single block write ---------
+    best_t = jnp.min(summ[:, 0])
+    root_row = summ[jnp.argmin(jnp.where(summ[:, 0] == best_t, summ[:, 1],
+                                         INF))]
+    cal = cal.at[sum_base:sum_base + nb].set(summ)
+    cal = cal.at[0].set(root_row)
+    st["evq_cal"] = cal
+    st["evq_root"] = root_row                        # mirror (queue_state)
+    return st
+
+
+def cal_bulk_push(st, mask, times, typ, a0, a1, a2, queue_cap: int, width):
+    """Calendar twin of ``bulk_push``: a pure-push ``cal_commit``."""
+    return cal_commit(st, jnp.zeros((0,), jnp.int32), jnp.zeros((0,), bool),
+                      jnp.float32(0.0), mask, times, typ, a0, a1, a2,
+                      queue_cap, width)
+
+
+def cal_empty(queue_cap: int) -> dict:
+    """Standalone calendar queue state for the test harness and the
+    scatter check."""
+    return {"dropped": jnp.zeros((), jnp.int32)} | cal_state(queue_cap)
+
+
+# --------------------------------------------------------------------------
+# "linear": the historical arrays, the test reference.
+# --------------------------------------------------------------------------
+
+def _linear_state(queue_cap: int) -> dict:
+    return {
+        "ev_time": jnp.full((queue_cap,), INF),
+        "ev_type": jnp.zeros((queue_cap,), jnp.int32),
+        "ev_a": jnp.zeros((queue_cap, 3), jnp.int32),  # (app, gmn/cluster, pe/cnt)
+    }
+
+
+def _linear_live(st):
+    return st["ev_time"].min() < INF                 # O(Q) linear scan
+
+
+def _linear_root(st):
+    slot = jnp.argmin(st["ev_time"]).astype(jnp.int32)  # O(Q)
+    return st["ev_time"][slot], slot, st["ev_type"][slot], st["ev_a"][slot]
+
+
+def _linear_leaves(st):
+    return st["ev_time"], jnp.concatenate(
+        [st["ev_type"][:, None].astype(jnp.float32),
+         st["ev_a"].astype(jnp.float32)], -1)
+
+
+def _linear_bulk_push(st, mask, times, typ, a0, a1, a2):
+    """One vectorized pass over the whole queue (cumsum of the free mask
+    + a stable argsort), O(Q log Q) per batch."""
+    n = times.shape[0]
+    typb = jnp.broadcast_to(jnp.asarray(typ, jnp.int32), (n,))
+    free = st["ev_time"] >= INF
+    free_rank = jnp.cumsum(free) - 1                 # slot's rank among free
+    cnt = mask.sum()
+    order = jnp.argsort(jnp.logical_not(mask))       # stable: pushed first
+    idx = jnp.minimum(free_rank, n - 1)
+    ct = times[order][idx]
+    ctyp = typb[order][idx]
+    ca = jnp.stack([jnp.asarray(a0, jnp.int32)[order][idx],
+                    jnp.asarray(a1, jnp.int32)[order][idx],
+                    jnp.asarray(a2, jnp.int32)[order][idx]], -1)
+    write = free & (free_rank < cnt)
+    st = dict(st)
+    st["ev_time"] = jnp.where(write, ct, st["ev_time"])
+    st["ev_type"] = jnp.where(write, ctyp, st["ev_type"])
+    st["ev_a"] = jnp.where(write[:, None], ca, st["ev_a"])
+    st["dropped"] = st["dropped"] + jnp.maximum(cnt - free.sum(), 0)
+    return st
+
+
+def _linear_commit(st, pop_slots, pop_ok, mask, times, typ, a0, a1, a2,
+                   queue_cap: int):
+    st = dict(st)
+    st["ev_time"] = st["ev_time"].at[
+        jnp.where(pop_ok, pop_slots, queue_cap)].set(INF, mode="drop")
+    return _linear_bulk_push(st, mask, times, typ, a0, a1, a2)
+
+
+# --------------------------------------------------------------------------
+# The seam.
+# --------------------------------------------------------------------------
+
+def resolve(queue_impl: str, queue_cap: int, time_scale=1.0) -> Queue:
+    """The ``Queue`` of one format (``QUEUE_IMPLS``) at one capacity.
+    ``time_scale`` is the spacing at which event times cluster (traced);
+    it sets the calendar's bucket width and nothing else."""
+    q = queue_cap
+    if queue_impl == "tree":
+        return Queue(
+            state=lambda: queue_state(q), live=_live, root=_root_event,
+            leaves=lambda st: (st["evq_tree"][:, 0], st["evq_tree"][:, 2:]),
+            held=("evq_tree",),
+            commit=functools.partial(commit, queue_cap=q),
+            bulk_push=functools.partial(bulk_push, queue_cap=q))
+    if queue_impl == "calendar":
+        return Queue(
+            state=lambda: cal_state(q), live=_live, root=_root_event,
+            leaves=lambda st: (st["evq_cal"][1:1 + q, 0],
+                               st["evq_cal"][1:1 + q, 2:]),
+            held=("evq_cal",),
+            # every pop of a trip is at the root's time, which the
+            # mirror still holds until this commit sets the next root
+            commit=lambda st, pop_slots, pop_ok, *push: cal_commit(
+                st, pop_slots, pop_ok, st["evq_root"][0], *push, q,
+                time_scale),
+            bulk_push=lambda st, *push: cal_bulk_push(st, *push, q,
+                                                      time_scale))
+    if queue_impl == "linear":
+        return Queue(
+            state=lambda: _linear_state(q), live=_linear_live,
+            root=_linear_root, leaves=_linear_leaves,
+            held=("ev_time", "ev_type", "ev_a"),
+            commit=functools.partial(_linear_commit, queue_cap=q),
+            bulk_push=_linear_bulk_push)
+    raise ValueError(f"unknown queue_impl {queue_impl!r}; choose from "
+                     f"{QUEUE_IMPLS}")
 
 
 def batch_take(leaf_t, leaf_typ, root_t, root_slot, rx_typ, batch_pop: int):
@@ -512,190 +520,3 @@ def batch_take(leaf_t, leaf_typ, root_t, root_slot, rx_typ, batch_pop: int):
     slots = sel.at[0].set(root_slot)
     ok = jnp.arange(batch_pop) < jnp.maximum(b, 1)
     return slots, ok
-
-
-# --------------------------------------------------------------------------
-# Calendar queue.
-# --------------------------------------------------------------------------
-
-def _cal_bases(queue_cap: int):
-    nb = cal_buckets(queue_cap)
-    sum_base = 1 + queue_cap
-    cnt_base = sum_base + nb
-    sup_base = cnt_base + seg_count(queue_cap)
-    return nb, sum_base, cnt_base, sup_base
-
-
-def _bucket(t, width, nb):
-    """floor(t / W) mod NB — only ever evaluated on finite times."""
-    return jnp.mod(jnp.floor(t / width), nb).astype(jnp.int32)
-
-
-def cal_commit(st, pop_slots, pop_ok, root_t, mask, times, typ, a0, a1, a2,
-               queue_cap: int, width, _prebuilt_slots: bool = False):
-    """Calendar twin of ``commit``: clear pops, return counters, allocate
-    (shared ``_alloc`` — identical slot rule and drop accounting), write
-    push leaves, then maintain the summaries: rebuild the single popped
-    bucket from its leaves (all pops in a batch share the root time,
-    hence one bucket), lexmin-merge the pushed rows into theirs, and
-    rewrite the root row from the NB summaries.  ``root_t`` is the
-    shared timestamp of the popped lanes (any value when B = 0).
-
-    ``_prebuilt_slots=True`` is the ``build_cal`` path: entry j IS slot
-    j (the leaves are already written), so allocation is skipped and
-    every bucket is rebuilt."""
-    q = queue_cap
-    nb, sum_base, cnt_base, sup_base = _cal_bases(q)
-    s = seg_count(q)
-    cal = st["evq_cal"]
-    oob = cal.shape[0]
-    pop_slots = jnp.asarray(pop_slots, jnp.int32)
-    pop_ok = jnp.asarray(pop_ok, bool)
-    npop = pop_slots.shape[0]
-    width = jnp.asarray(width, jnp.float32)
-
-    # -- clear popped leaves + return their counters ---------------------
-    clear_rows = jnp.zeros((npop, ROW_W)) \
-        .at[:, 0].set(INF) \
-        .at[:, 1].set(pop_slots.astype(jnp.float32))
-    cal = cal.at[jnp.where(pop_ok, 1 + pop_slots, oob)] \
-        .set(clear_rows, mode="drop")
-    pseg = pop_slots // ALLOC_SEG
-    inc_idx = jnp.concatenate([
-        jnp.where(pop_ok, cnt_base + pseg, oob),
-        jnp.where(pop_ok, sup_base + pseg // SUPER_SEG, oob)])
-    cal = cal.at[inc_idx, 0].add(
-        jnp.ones((2 * npop,), jnp.float32), mode="drop")
-
-    # -- allocation + push leaf writes -----------------------------------
-    mask = jnp.asarray(mask, bool)
-    times = jnp.asarray(times, jnp.float32)
-    st = dict(st)
-    if _prebuilt_slots:
-        slot = jnp.arange(q, dtype=jnp.int32)
-        ok = mask
-    else:
-        slot, segc, ok, n_drop = _alloc(cal, 1, cnt_base, sup_base, s, q,
-                                        mask, times)
-        st["dropped"] = st["dropped"] + n_drop
-        leaf_rows = jnp.stack([
-            times, slot.astype(jnp.float32),
-            jnp.broadcast_to(jnp.asarray(typ, jnp.float32), mask.shape),
-            jnp.broadcast_to(jnp.asarray(a0, jnp.float32), mask.shape),
-            jnp.broadcast_to(jnp.asarray(a1, jnp.float32), mask.shape),
-            jnp.broadcast_to(jnp.asarray(a2, jnp.float32), mask.shape)], -1)
-        cal = cal.at[jnp.where(ok, 1 + slot, oob)] \
-            .set(leaf_rows, mode="drop")
-        live = jnp.logical_and(ok, times < INF)
-        dec_idx = jnp.concatenate([
-            jnp.where(live, cnt_base + segc, oob),
-            jnp.where(live, sup_base + segc // SUPER_SEG, oob)])
-        cal = cal.at[dec_idx, 0].add(
-            jnp.full((2 * mask.shape[0],), -1.0), mode="drop")
-
-    # -- summary maintenance (value space) -------------------------------
-    # The summary block and root row are COMPUTED from reads of the
-    # buffer and written exactly once at the end.  Interleaving summary
-    # writes with reads of older buffer values (the previous layout:
-    # write popped-bucket row, read leaves + summaries, write block,
-    # read block, write root) keeps the pre-write value live across
-    # each in-place update, so XLA's copy-insertion materializes the
-    # WHOLE (1+Q+NB+S+S2, 6) array per loop iteration — the §11
-    # pathology, caught by `repro.analysis` hlo_lint.  All values are
-    # bit-identical: selects replace scatters, no arithmetic changes.
-    leaf_t = cal[1:1 + q, 0]
-    slots_f = jnp.arange(q, dtype=jnp.float32)
-    alive = leaf_t < INF
-    empty_row = jnp.zeros((ROW_W,)).at[0].set(INF)
-    if _prebuilt_slots:
-        # full rebuild: exact lexmin-(time, slot) winner per bucket
-        tb = jnp.where(alive, _bucket(jnp.where(alive, leaf_t, 0.0),
-                                      width, nb), nb)
-        tmin = jnp.full((nb + 1,), INF).at[tb].min(
-            jnp.where(alive, leaf_t, INF), mode="drop")
-        smin = jnp.full((nb + 1,), INF).at[tb].min(
-            jnp.where(jnp.logical_and(alive, leaf_t == tmin[
-                jnp.minimum(tb, nb)]), slots_f, INF), mode="drop")
-        win = smin[:nb]
-        found = win < INF
-        rows = cal[1 + jnp.minimum(win, q - 1).astype(jnp.int32)]
-        summ = jnp.where(found[:, None], rows, empty_row[None, :])
-    else:
-        # rebuild only the popped bucket (exact: pops share root_t)
-        b_pop = _bucket(jnp.where(jnp.any(pop_ok), root_t, 0.0), width, nb)
-        in_pop = jnp.logical_and(
-            alive, _bucket(jnp.where(alive, leaf_t, 0.0), width, nb) == b_pop)
-        # (a) popped-bucket rebuild from the leaves (sees the new pushes)
-        t_in = jnp.where(in_pop, leaf_t, INF)
-        tmin = jnp.min(t_in)
-        smin = jnp.min(jnp.where(jnp.logical_and(in_pop, leaf_t == tmin),
-                                 slots_f, INF))
-        found = smin < INF
-        row = cal[1 + jnp.minimum(smin, q - 1).astype(jnp.int32)]
-        new_sum = jnp.where(found, row, empty_row)
-        hit = jnp.logical_and(jnp.any(pop_ok),
-                              jnp.arange(nb) == b_pop)
-        summ = jnp.where(hit[:, None], new_sum[None, :],
-                         cal[sum_base:sum_base + nb])
-        # (b) lexmin-merge the pushed rows into their buckets
-        plive = jnp.logical_and(ok, times < INF)
-        pb = jnp.where(plive, _bucket(jnp.where(plive, times, 0.0),
-                                      width, nb), nb)
-        pt = jnp.full((nb + 1,), INF).at[pb].min(
-            jnp.where(plive, times, INF), mode="drop")
-        ps = jnp.full((nb + 1,), INF).at[pb].min(
-            jnp.where(jnp.logical_and(plive, times == pt[
-                jnp.minimum(pb, nb)]), slot.astype(jnp.float32), INF),
-            mode="drop")
-        cand_s = ps[:nb]
-        cand_ok = cand_s < INF
-        cand = cal[1 + jnp.minimum(cand_s, q - 1).astype(jnp.int32)]
-        take_new = jnp.logical_and(cand_ok, jnp.logical_or(
-            cand[:, 0] < summ[:, 0],
-            jnp.logical_and(cand[:, 0] == summ[:, 0],
-                            cand[:, 1] < summ[:, 1])))
-        summ = jnp.where(take_new[:, None], cand, summ)
-
-    # -- root from the NB summaries, then the single block write ---------
-    best_t = jnp.min(summ[:, 0])
-    bsel = jnp.where(summ[:, 0] == best_t, summ[:, 1], INF)
-    bi = jnp.argmin(bsel)
-    root_row = summ[bi]
-    cal = cal.at[sum_base:sum_base + nb].set(summ)
-    cal = cal.at[0].set(root_row)
-    st["evq_cal"] = cal
-    st["evq_root"] = root_row                        # mirror (queue_state)
-    return st
-
-
-def cal_bulk_push(st, mask, times, typ, a0, a1, a2, queue_cap: int, width):
-    """Calendar twin of ``bulk_push``: a pure-push ``cal_commit``."""
-    return cal_commit(st, jnp.zeros((0,), jnp.int32), jnp.zeros((0,), bool),
-                      jnp.float32(0.0), mask, times, typ, a0, a1, a2,
-                      queue_cap, width)
-
-
-def cal_pop(st, queue_cap: int, width):
-    """Singleton calendar pop (tests/harness): read the root row, commit
-    the pop with no pushes.  Returns ``(st, t, slot, typ, a)`` exactly
-    like ``pop``."""
-    root = st["evq_cal"][0]
-    t = root[0]
-    slot = root[1].astype(jnp.int32)
-    typ = root[2].astype(jnp.int32)
-    a = root[3:].astype(jnp.int32)
-    z = jnp.zeros((0,), jnp.float32)
-    st = cal_commit(st, slot[None], jnp.ones((1,), bool), t,
-                    jnp.zeros((0,), bool), z, z, z, z, z, queue_cap, width)
-    return st, t, slot, typ, a
-
-
-def empty(queue_cap: int) -> dict:
-    """A minimal standalone queue state (no simulator around it) — the
-    harness tests/test_eventq.py drives push/pop against directly."""
-    return {"dropped": jnp.zeros((), jnp.int32)} | queue_state(queue_cap)
-
-
-def cal_empty(queue_cap: int) -> dict:
-    """Standalone calendar queue state for the test harness."""
-    return {"dropped": jnp.zeros((), jnp.int32)} | cal_state(queue_cap)

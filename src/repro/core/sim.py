@@ -10,14 +10,13 @@ Faithful JAX re-implementation of the paper's TLM evaluation (Sec 5):
              synchronization (Tab 2).
 
 All state lives in fixed-shape arrays; the run is one ``lax.while_loop``
-over a bounded event queue.  The queue's priority structure is itself a
-static axis (``queue_impl``, core/eventq.py, DESIGN.md §11): ``"linear"``
-pops with an O(queue_cap) ``jnp.argmin`` scan — the historical code,
-kept operation-for-operation as the golden anchor — while ``"tree"``
-keeps the next event in a root row refreshed by one dense reduction
-per fused commit, with bitwise-identical results, which is what makes
-the paper-scale m=256/k=256 distributed runs tractable on CPU
-(benchmarks/topology_frontier.py --grid paper).
+over a bounded event queue.  The queue's format is itself a static
+axis (``queue_impl``), owned by core/eventq.py (DESIGN.md §11), which
+the loop reaches only through one ``eventq.Queue``: ``"linear"`` pops
+with an O(queue_cap) ``jnp.argmin`` scan — the historical code, kept
+operation-for-operation as the golden anchor — while ``"tree"`` and
+``"calendar"`` keep the next event in a root row refreshed by one fused
+commit per iteration, with bitwise-identical results.
 
 Parameters are split into three objects (see DESIGN.md §7/§9):
 
@@ -295,9 +294,9 @@ class _Ctx:
     __slots__ = ("m", "k", "mpk", "n_childs", "queue_cap", "max_apps",
                  "c_b", "c_s", "c_join", "dn_th", "T_b", "c_hop",
                  "susp_mult", "retry_after", "policy",
-                 "topology", "hops", "ns", "record_s1", "queue_impl",
+                 "topology", "hops", "ns", "record_s1", "queue",
                  "sel_global", "sel_local", "faults_on",
-                 "batch_pop", "stage_fan", "stage_h", "cal_width", "trace")
+                 "batch_pop", "stage_fan", "stage_h", "trace")
 
     def __init__(self, shape: SimShape, knobs: SimKnobs,
                  policy: SimPolicy = DEFAULT_POLICY,
@@ -325,7 +324,13 @@ class _Ctx:
         self.hops = jnp.asarray(T.hop_table(topology.kind, shape.k))
         self.ns = shape.ns
         self.record_s1 = shape.record_s1
-        self.queue_impl = shape.queue_impl
+        # the event queue's format, behind its one seam (core/eventq.py).
+        # Event times cluster at multiples of the coarser of c_b (buses)
+        # and c_s (decisions), which sets the calendar's bucket width
+        # (traced: only the work depends on it, never a result).
+        self.queue = EQ.resolve(shape.queue_impl, shape.queue_cap,
+                                jnp.maximum(jnp.maximum(knobs.c_b, knobs.c_s),
+                                            jnp.float32(1.0)))
         self.sel_global = knobs.c_s * _log2_levels(shape.k)
         self.sel_local = knobs.c_s * _log2_levels(shape.mpk)
         # static: whether the fault machinery (mask state, fault event
@@ -349,34 +354,11 @@ class _Ctx:
         self.stage_fan = (shape.k if topology.kind != "ideal" else 0) \
             + (shape.k if faults_on else 0)
         self.stage_h = max(shape.ns, _spawn_group_bound(shape))
-        # calendar bucket width, tuned from the tick granularity: c_b
-        # serializes buses and c_s serializes decisions, so event times
-        # cluster at multiples of the coarser of the two (traced — only
-        # the work distribution depends on it, never correctness)
-        self.cal_width = jnp.maximum(jnp.maximum(knobs.c_b, knobs.c_s),
-                                     jnp.float32(1.0))
 
 
 def make_state(p):
-    k, mpk, Q, A = p.k, p.mpk, p.queue_cap, p.max_apps
-    qi = getattr(p, "queue_impl", "linear")
-    if qi == "tree":
-        # tree queue (core/eventq.py, DESIGN.md §11): times AND
-        # payloads live in the leaf rows; the linear ev_* arrays do not
-        # exist in tree mode
-        queue = EQ.queue_state(Q)
-    elif qi == "calendar":
-        # calendar queue (core/eventq.py): root row + leaves + bucket
-        # summaries + free counters in one array
-        queue = EQ.cal_state(Q)
-    else:
-        # event queue (slot-recycled)
-        queue = {
-            "ev_time": jnp.full((Q,), INF),
-            "ev_type": jnp.zeros((Q,), jnp.int32),
-            "ev_a": jnp.zeros((Q, 3), jnp.int32),  # (app, gmn/cluster, pe/cnt)
-        }
-    return queue | {
+    k, mpk, A = p.k, p.mpk, p.max_apps
+    return p.queue.state() | {
         # infra
         "pe_free": jnp.zeros((k, mpk), jnp.float32),
         "gmn_free": jnp.zeros((k,), jnp.float32),
@@ -526,51 +508,10 @@ def _hist_add(st, p, key, vals, mask, weight=None):
 
 def _bulk_push(st, p, mask, times, typ, a0, a1, a2):
     """Insert the masked entries of an event batch, exactly equivalent to
-    pushing them one by one in order (the j-th masked entry takes the j-th
-    free queue slot, matching the historical first-free-slot search).
-
-    Three implementations sit behind the static ``p.queue_impl`` axis
-    with bitwise-identical results (same slot assignment, same drop
-    accounting — tests/test_eventq.py):
-
-      "linear"    one vectorized pass over the whole queue (cumsum of
-                  the free mask + a stable argsort), O(Q log Q) per
-                  batch.  Kept operation-for-operation as the golden
-                  anchor.
-      "tree"      leaf writes plus one dense argmin for the root row
-                  (core/eventq.py): O(n) scatters and one O(Q)
-                  reduction per batch.
-      "calendar"  the bucket-summary merge (core/eventq.py): O(n + NB)
-                  incremental maintenance per batch.
-
-    ``typ`` may be a scalar or a per-entry (n,) array — the fused
-    end-of-body commit mixes BEACON_RX fan-out entries with handler
-    pushes in one batch.
-    """
-    if p.queue_impl == "tree":
-        return EQ.bulk_push(st, mask, times, typ, a0, a1, a2, p.queue_cap)
-    if p.queue_impl == "calendar":
-        return EQ.cal_bulk_push(st, mask, times, typ, a0, a1, a2,
-                                p.queue_cap, p.cal_width)
-    n = times.shape[0]
-    typb = jnp.broadcast_to(jnp.asarray(typ, jnp.int32), (n,))
-    free = st["ev_time"] >= INF
-    free_rank = jnp.cumsum(free) - 1                 # slot's rank among free
-    cnt = mask.sum()
-    order = jnp.argsort(jnp.logical_not(mask))       # stable: pushed first
-    idx = jnp.minimum(free_rank, n - 1)
-    ct = times[order][idx]
-    ctyp = typb[order][idx]
-    ca = jnp.stack([jnp.asarray(a0, jnp.int32)[order][idx],
-                    jnp.asarray(a1, jnp.int32)[order][idx],
-                    jnp.asarray(a2, jnp.int32)[order][idx]], -1)
-    write = free & (free_rank < cnt)
-    st = dict(st)
-    st["ev_time"] = jnp.where(write, ct, st["ev_time"])
-    st["ev_type"] = jnp.where(write, ctyp, st["ev_type"])
-    st["ev_a"] = jnp.where(write[:, None], ca, st["ev_a"])
-    st["dropped"] = st["dropped"] + jnp.maximum(cnt - free.sum(), 0)
-    return st
+    pushing them one by one in order: the j-th masked entry takes the
+    j-th lowest free queue slot, and masked entries past the free count
+    drop (core/eventq.py, the same rule under every queue format)."""
+    return p.queue.bulk_push(st, mask, times, typ, a0, a1, a2)
 
 
 # --------------------------------------------------------------------------
@@ -1338,15 +1279,7 @@ def simulate(shape: SimShape, knobs: SimKnobs, arrivals, arrival_gmns,
         st["evq_len"] = seeded - st["dropped"]
         st["evq_peak"] = st["evq_len"]
 
-    qi = p.queue_impl
-    if qi in ("tree", "calendar"):
-        def cond(st):
-            # O(1) root-row mirror — never touch the big queue buffer
-            # outside the commit chain (core/eventq.py queue_state)
-            return st["evq_root"][0] < INF
-    else:
-        def cond(st):
-            return st["ev_time"].min() < INF           # O(Q) linear scan
+    cond = p.queue.live
 
     # Every branch returns (state, staged record) — the queue pushes and
     # big-matrix writes happen AFTER the switch (see the fused-commit
@@ -1390,27 +1323,10 @@ def simulate(shape: SimShape, knobs: SimKnobs, arrivals, arrival_gmns,
             s, p, t, a[0], sim_len)))
 
     bp = p.batch_pop if rx_on else 1    # only BEACON_RX events batch
-    q = p.queue_cap
 
     def body(st):
         with jax.named_scope("sim.pop"):
-            # -- root read (the root row IS the event; no payload gathers) ----
-            # Tree/calendar read the small evq_root mirror, NOT the queue
-            # buffer: tree slices get rematerialized by fusion at their
-            # consumers, and a consumer sitting after the commit chain's
-            # first in-place write forces copy-insertion to clone the whole
-            # buffer every iteration.
-            if qi in ("tree", "calendar"):
-                root = st["evq_root"]
-                t = root[0]
-                slot = root[1].astype(jnp.int32)
-                typ = root[2].astype(jnp.int32)
-                a = root[3:].astype(jnp.int32)
-            else:
-                slot = jnp.argmin(st["ev_time"]).astype(jnp.int32)  # O(Q)
-                t = st["ev_time"][slot]
-                typ = st["ev_type"][slot]
-                a = st["ev_a"][slot]
+            t, slot, typ, a = p.queue.root(st)
 
             # -- same-timestamp BEACON_RX batch selection ---------------------
             if bp > 1:
@@ -1421,17 +1337,7 @@ def simulate(shape: SimShape, knobs: SimKnobs, arrivals, arrival_gmns,
                 # (reads in a cond branch are safe — only scatters force
                 # branch-region buffer copies).
                 def _rx_cohort():
-                    if qi == "tree":
-                        lt = EQ.leaf_times(st, q)
-                        lpay = EQ.leaf_payloads(st, q)
-                    elif qi == "calendar":
-                        lt = EQ.cal_leaf_times(st, q)
-                        lpay = EQ.cal_leaf_payloads(st, q)
-                    else:
-                        lt = st["ev_time"]
-                        lpay = jnp.concatenate(
-                            [st["ev_type"][:, None].astype(jnp.float32),
-                             st["ev_a"].astype(jnp.float32)], -1)
+                    lt, lpay = p.queue.leaves(st)
                     sel, ok = EQ.batch_take(lt, lpay[:, 0], t, slot,
                                             EV_BEACON_RX, bp)
                     return sel, ok, lpay[sel].astype(jnp.int32)
@@ -1524,7 +1430,7 @@ def simulate(shape: SimShape, knobs: SimKnobs, arrivals, arrival_gmns,
         # writes columns in-branch — the golden program, not the perf
         # target).  Fault-state arrays stay in the outputs: the fault
         # handlers write them in-branch (rare event types).
-        held_keys = ["evq_tree", "evq_cal", "ev_time", "ev_type", "ev_a"]
+        held_keys = list(p.queue.held)
         if rx_on:
             held_keys += ["view", "view_t", "bcn_t"]
         if p.faults_on:
@@ -1562,15 +1468,7 @@ def simulate(shape: SimShape, knobs: SimKnobs, arrivals, arrival_gmns,
             pty = stg["push_typ"]
             pa0, pa1, pa2 = stg["push_a0"], stg["push_a1"], stg["push_a2"]
             d0 = st["dropped"]
-            if qi == "tree":
-                st = EQ.commit(st, slots, okl, pm, pt, pty, pa0, pa1, pa2, q)
-            elif qi == "calendar":
-                st = EQ.cal_commit(st, slots, okl, t, pm, pt, pty, pa0, pa1,
-                                   pa2, q, p.cal_width)
-            else:
-                st["ev_time"] = st["ev_time"].at[
-                    jnp.where(okl, slots, q)].set(INF, mode="drop")
-                st = _bulk_push(st, p, pm, pt, pty, pa0, pa1, pa2)
+            st = p.queue.commit(st, slots, okl, pm, pt, pty, pa0, pa1, pa2)
             # live-entry accounting: accepted pushes minus retired pops
             accepted = jnp.sum(pm).astype(jnp.int32) - (st["dropped"] - d0)
             st["evq_len"] = st["evq_len"] + accepted \

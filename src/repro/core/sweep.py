@@ -162,9 +162,9 @@ def sweep(shape, knobs: SimKnobs, workload, sim_len: float = 1e7,
               DESIGN.md §11): "linear", "tree" or "calendar".  Part of
               the static shape; None (default) keeps
               ``shape.queue_impl``.  Results are bitwise identical
-              across impls — "tree"/"calendar" replace the
-              O(queue_cap) argmin per event with O(log Q)/O(Q per
-              commit) structures, the difference is wall-clock only.
+              across impls — "tree"/"calendar" replace the per-event
+              O(Q log Q) push with one fused commit per iteration; the
+              difference is wall-clock only.
     batch_pop  same-timestamp BEACON_RX batching override (DESIGN.md
               §11): pop up to this many root-equal-time RX events per
               body iteration.  Static; None keeps ``shape.batch_pop``.

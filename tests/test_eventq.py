@@ -1,9 +1,11 @@
-"""Tree event queue (core/eventq.py, DESIGN.md §11), a flat leaf array
-with a dense root: pop order equals sorted order under ties, the root
-mirror equals numpy's first-index argmin row after every commit, the
-counters equal the free leaves, drop parity with the linear impl, a
-commit whose structure does not grow with Q, and vmap == seq bitwise
-under queue_impl="tree"."""
+"""Event queues (core/eventq.py, DESIGN.md §11).  The tree, a flat
+leaf array with a dense root: pop order equals sorted order under ties,
+the root mirror equals numpy's first-index argmin row after every
+commit, drop parity with the linear impl, a commit whose structure does
+not grow with Q, and vmap == seq bitwise under queue_impl="tree".  The
+calendar against the tree, and the seam every format sits behind:
+pops, cohorts and drops against a heapq model."""
+import heapq
 import re
 
 import jax
@@ -20,13 +22,33 @@ from repro.core.sim import SimParams
 
 INF = float(EQ.INF)
 
-_jit_pop = jax.jit(EQ.pop, static_argnums=1)
 _jit_push = jax.jit(EQ.bulk_push, static_argnums=(3, 7))
+
+
+def _pop(q, cap, impl="tree", width=8.0):
+    """Singleton pop through the seam: read the root, commit its pop
+    alone.  Returns ``(q, t, slot, typ, a)``."""
+    qu = EQ.resolve(impl, cap, width)
+    t, slot, typ, a = qu.root(q)
+    z = jnp.zeros((0,), jnp.float32)
+    q = qu.commit(q, slot[None], jnp.ones((1,), bool), jnp.zeros((0,), bool),
+                  z, z, z, z, z)
+    return q, t, slot, typ, a
+
+
+_jit_pop = jax.jit(_pop, static_argnums=(1, 2))
+
+
+def _leaves(q, cap, impl="tree"):
+    """Per-slot (times, payloads) through the seam."""
+    return EQ.resolve(impl, cap).leaves(q)
 
 
 def _times(q, cap):
     """Per-slot event times from the leaf rows (INF = free)."""
-    return np.asarray(EQ.leaf_times(q, cap))
+    lt = np.asarray(_leaves(q, cap)[0])
+    assert lt.shape == (cap,)
+    return lt
 
 
 def _from_times(cap, times):
@@ -48,31 +70,21 @@ def _push(q, times, mask=None, typ=1, cap=None):
 def _drain(q, cap):
     """Pop until empty; returns [(t, slot), ...]."""
     out = []
-    while float(EQ.peek_time(q)) < INF:
+    while float(q["evq_root"][0]) < INF:
         q, t, slot, typ, a = _jit_pop(q, cap)
         out.append((float(t), int(slot)))
     return q, out
 
 
-def _assert_root_and_counters(q, cap):
-    """The oracle after every commit: ``evq_root`` is the leaf row at
-    numpy's first-index argmin of the leaf times, and the segment and
-    super counters equal the INF leaves they cover."""
+def _assert_root(q, cap):
+    """The oracle after every commit: ``evq_tree`` is exactly the Q leaf
+    rows, each carrying its own slot index, and ``evq_root`` is the leaf
+    row at numpy's first-index argmin of the leaf times."""
     tree = np.asarray(q["evq_tree"])
-    lt = tree[:cap, 0]
+    assert tree.shape == (cap, EQ.ROW_W)
+    assert np.array_equal(tree[:, 1], np.arange(cap))
     assert np.array_equal(np.asarray(q["evq_root"]),
-                          tree[int(np.argmin(lt))])
-    free = lt >= INF
-    segs = -(-cap // EQ.ALLOC_SEG)
-    seg = np.zeros(segs * EQ.ALLOC_SEG, int)
-    seg[:cap] = free
-    seg = seg.reshape(segs, EQ.ALLOC_SEG).sum(1)
-    sups = -(-segs // EQ.SUPER_SEG)
-    sup = np.zeros(sups * EQ.SUPER_SEG, int)
-    sup[:segs] = seg
-    sup = sup.reshape(sups, EQ.SUPER_SEG).sum(1)
-    assert np.array_equal(seg, np.asarray(EQ.freecnt(q, cap)))
-    assert np.array_equal(sup, np.asarray(EQ.supercnt(q, cap)))
+                          tree[int(np.argmin(tree[:, 0]))])
 
 
 def test_pop_order_is_sorted_with_ties():
@@ -111,7 +123,7 @@ def test_pop_returns_payload():
 def test_interleaved_push_pop_matches_heap(seed, cap):
     """Random interleaving of batch pushes and pops behaves as a priority
     queue with (time, slot) ordering; after every commit the root mirror
-    is the first-index argmin row and the counters match the leaves."""
+    is the first-index argmin row."""
     rng = np.random.default_rng(seed)
     q = EQ.empty(cap)
     live = {}                               # slot -> time (host reference)
@@ -121,14 +133,14 @@ def test_interleaved_push_pop_matches_heap(seed, cap):
         mask = rng.random(n) < 0.8
         before_free = sorted(s for s in range(cap) if s not in live)
         q = _push(q, times, mask=mask, cap=cap)
-        _assert_root_and_counters(q, cap)
+        _assert_root(q, cap)
         for j, s in zip(np.flatnonzero(mask), before_free):
             live[int(s)] = float(times[j])
         for _ in range(int(rng.integers(0, 8))):
             if not live:
                 break
             q, t, slot, _, _ = _jit_pop(q, cap)
-            _assert_root_and_counters(q, cap)
+            _assert_root(q, cap)
             exp_t = min(live.values())
             exp_s = min(s for s, tv in live.items() if tv == exp_t)
             assert (float(t), int(slot)) == (exp_t, exp_s)
@@ -140,8 +152,8 @@ def test_interleaved_push_pop_matches_heap(seed, cap):
 def test_bulk_push_path_repair_equals_full_rebuild():
     """After a large masked batch lands on scattered free slots, the
     incrementally written buffer is identical to a full build from its
-    own leaf rows (payloads and counters included), and the root mirror
-    is the first-index argmin row."""
+    own leaf rows (payloads included), and the root mirror is the
+    first-index argmin row."""
     rng = np.random.default_rng(3)
     cap = 256
     q = _push(EQ.empty(cap), rng.uniform(1, 1e6, 200).astype(np.float32),
@@ -151,10 +163,10 @@ def test_bulk_push_path_repair_equals_full_rebuild():
     times = rng.uniform(1, 1e6, 64).astype(np.float32)
     q = _push(q, times, mask=rng.random(64) < 0.5, typ=2, cap=cap)
     lt = jnp.asarray(_times(q, cap))
-    pl = np.asarray(EQ.leaf_payloads(q, cap))
+    pl = np.asarray(_leaves(q, cap)[1])
     rebuilt = EQ.build_tree(lt, typ=pl[:, 0], a=pl[:, 1:])
     assert np.array_equal(np.asarray(rebuilt), np.asarray(q["evq_tree"]))
-    _assert_root_and_counters(q, cap)
+    _assert_root(q, cap)
 
 
 def test_pop_slot_matches_argmin_under_ties():
@@ -168,7 +180,7 @@ def test_pop_slot_matches_argmin_under_ties():
             times[:] = INF                  # empty: slot 0's row
         q = _push(EQ.empty(cap), times, cap=cap)
         assert int(q["evq_root"][1]) == int(np.argmin(times))
-        assert float(EQ.peek_time(q)) == float(times.min())
+        assert float(q["evq_root"][0]) == float(times.min())
         if times.min() < INF:
             _, t, slot, _, _ = _jit_pop(q, cap)
             assert int(slot) == int(np.argmin(times))
@@ -200,22 +212,22 @@ def test_slot_assignment_matches_linear_rule():
 
 def test_inf_time_push_keeps_counters_in_sync():
     """A masked entry with time >= INF takes its slot in the assignment
-    order (linear parity) but leaves the slot free — the segment
-    counters must keep matching the INF-leaf count exactly."""
+    order (linear parity) but leaves the slot free: the queue keeps no
+    counter rows, so the allocator, which counts free slots from the
+    leaf times, hands that slot out again."""
     cap = 128
     q = _push(EQ.empty(cap), [5.0, INF, 7.0], cap=cap)
+    assert q["evq_tree"].shape == (cap, EQ.ROW_W)
     lt = _times(q, cap)
     # entry 1 consumed slot 1 in the assignment order but left it free
     assert (float(lt[0]), float(lt[2])) == (5.0, 7.0) and lt[1] >= INF
-    assert np.array_equal(np.asarray(EQ.build_freecnt(lt >= INF)),
-                          np.asarray(EQ.freecnt(q, cap)))
-    # the freed-looking slot is allocatable again, counters still exact
-    q = _push(q, [9.0], cap=cap)
+    assert int((lt >= INF).sum()) == cap - 2
+    # the freed-looking slot is allocatable again
+    q = _push(q, [9.0, 11.0], cap=cap)
     lt = _times(q, cap)
-    assert float(lt[1]) == 9.0
-    assert np.array_equal(np.asarray(EQ.build_freecnt(lt >= INF)),
-                          np.asarray(EQ.freecnt(q, cap)))
+    assert (float(lt[1]), float(lt[3])) == (9.0, 11.0)
     assert int(q["dropped"]) == 0
+    _assert_root(q, cap)
 
 
 def test_overflow_drops_match_linear_accounting():
@@ -233,22 +245,14 @@ def test_overflow_drops_match_linear_accounting():
     assert int(q["dropped"]) == 4
 
 
-def _alloc_case(rng, q, n, leaf_base, fill):
-    """One allocator input: the queue array (tree rows at ``leaf_base``
-    0, calendar rows at 1) with its counter bases, and a push batch."""
+def _alloc_case(rng, q, n, fill):
+    """One allocator input: the queue's leaf rows and a push batch."""
     times = rng.uniform(1, 1e6, q).astype(np.float32)
     n_free = q // 2 if fill == "sparse" else max(1, n // 3)
     times[rng.choice(q, min(n_free, q), replace=False)] = INF
-    if leaf_base == 0:
-        arr = EQ.build_tree(jnp.asarray(times))
-        cnt_base = q
-    else:
-        arr = EQ.build_cal(jnp.asarray(times))
-        cnt_base = EQ._cal_bases(q)[2]
+    arr = EQ.build_tree(jnp.asarray(times))
     mask = rng.random(n) < 0.8
-    push_t = np.where(rng.random(n) < 0.1, INF,
-                      rng.uniform(1, 1e6, n)).astype(np.float32)
-    return arr, cnt_base, times >= INF, mask, push_t
+    return arr, times >= INF, mask
 
 
 def _first_free(free, mask):
@@ -261,34 +265,29 @@ def _first_free(free, mask):
     return want, ok, max(int(mask.sum()) - len(slots), 0)
 
 
-@pytest.mark.parametrize("q,n,leaf_base,fill,lanes", [
-    (q, n, lb, fill, 1) for q in (100, 1024, 8192) for n in (31, 356)
-    for lb in (0, 1) for fill in ("sparse", "near_full")]
-    + [(1024, 356, 0, "near_full", 8)])
-def test_alloc_matches_first_free_rule(q, n, leaf_base, fill, lanes):
+@pytest.mark.parametrize("q,n,fill,lanes", [
+    (q, n, fill, 1) for q in (64, 65, 100, 1024, 8192, 32768)
+    for n in (31, 356) for fill in ("sparse", "near_full")]
+    + [(1024, 356, "near_full", 8), (32768, 356, "near_full", 8)])
+def test_alloc_matches_first_free_rule(q, n, fill, lanes):
     """``_alloc``'s slots, ``ok`` and drop count equal a plain NumPy
-    first-free-slot rule: a partial last segment (q=100), the tree's and
-    the calendar's leaf base, a near-full queue that overflows, pushes
-    timed at INF, and 8 vmapped lanes of distinct queues."""
-    rng = np.random.default_rng(q + n + leaf_base)
-    cases = [_alloc_case(rng, q, n, leaf_base, fill) for _ in range(lanes)]
-    s = EQ.seg_count(q)
-    cnt_base = cases[0][1]
-
-    def alloc(arr, mask, times):
-        return EQ._alloc(arr, leaf_base, cnt_base, cnt_base + s, s, q,
-                         mask, times)
-
-    arr, mask, push_t = (jnp.stack([jnp.asarray(c[i]) for c in cases])
-                         for i in (0, 3, 4))
+    first-free-slot rule: exactly one segment (q=64), one slot into a
+    second (q=65), a partial last segment (q=100), the benchmark cells'
+    queues (8192, 32768), a near-full queue that overflows, and 8
+    vmapped lanes of distinct queues, at the k=256 cell's Q and push
+    width among them."""
+    rng = np.random.default_rng(q + n)
+    cases = [_alloc_case(rng, q, n, fill) for _ in range(lanes)]
+    arr, mask = (jnp.stack([jnp.asarray(c[i]) for c in cases])
+                 for i in (0, 2))
     if lanes == 1:
-        got = jax.jit(alloc)(arr[0], mask[0], push_t[0])
+        got = jax.jit(EQ._alloc)(arr[0, :, 0], mask[0])
         got = jax.tree.map(lambda x: np.asarray(x)[None], got)
     else:
-        got = jax.tree.map(np.asarray, jax.jit(jax.vmap(alloc))(
-            arr, mask, push_t))
-    slot, _, ok, n_drop = got
-    for lane, (_, _, free, m, _) in enumerate(cases):
+        got = jax.tree.map(np.asarray, jax.jit(jax.vmap(EQ._alloc))(
+            arr[:, :, 0], mask))
+    slot, ok, n_drop = got
+    for lane, (_, free, m) in enumerate(cases):
         want, want_ok, want_drop = _first_free(free, m)
         assert np.array_equal(ok[lane], want_ok)
         assert np.array_equal(slot[lane][want_ok], want[want_ok])
@@ -322,18 +321,18 @@ def test_tree_vmap_equals_seq_bitwise(topology):
 
 def test_tree_queue_state_shapes_and_cap_guard():
     qs = EQ.queue_state(512)
-    s = 512 // EQ.ALLOC_SEG
-    s2 = -(-s // EQ.SUPER_SEG)
-    # leaf rows then counter rows: no internal nodes
-    assert qs["evq_tree"].shape == (512 + s + s2, EQ.ROW_W)
-    assert int(np.asarray(EQ.freecnt(qs, 512)).sum()) == 512
-    assert int(np.asarray(EQ.supercnt(qs, 512)).sum()) == 512
+    # the leaf rows and nothing else: no internal nodes, no counter rows
+    assert qs["evq_tree"].shape == (512, EQ.ROW_W)
+    assert int((_times(qs, 512) >= INF).sum()) == 512
     # an empty queue's root is slot 0's free row
     assert np.asarray(qs["evq_root"]).tolist() == [INF, 0, 0, 0, 0, 0]
     # non-power-of-two caps keep exactly queue_cap leaves (no padding)
     q100 = EQ.queue_state(100)
-    assert q100["evq_tree"].shape == (100 + 2 + 1, EQ.ROW_W)
+    assert q100["evq_tree"].shape == (100, EQ.ROW_W)
     assert _times(q100, 100).shape == (100,)
+    # the calendar: root row, leaf rows, bucket summaries, no counters
+    assert EQ.cal_state(512)["evq_cal"].shape == (1 + 512 + EQ.CAL_BUCKETS,
+                                                  EQ.ROW_W)
     with pytest.raises(ValueError):
         EQ.build_tree(jnp.zeros((EQ.MAX_QUEUE_CAP + 1,), jnp.float32))
 
@@ -358,11 +357,11 @@ def test_sim_rejects_bad_batch_pop():
 
 
 # --------------------------------------------------------------------------
-# PR 7: fused end-of-body commit, batch_take, calendar queue.
+# The fused end-of-body commit, batch_take, the calendar queue and the
+# seam.
 # --------------------------------------------------------------------------
 
 _jit_commit = jax.jit(EQ.commit, static_argnums=9)
-_jit_cal_pop = jax.jit(EQ.cal_pop, static_argnums=1)
 
 
 def _cal_push(q, times, mask=None, typ=1, cap=None, width=8.0):
@@ -376,8 +375,8 @@ def _cal_push(q, times, mask=None, typ=1, cap=None, width=8.0):
 
 def _cal_drain(q, cap, width=8.0):
     out = []
-    while float(EQ.cal_peek_time(q)) < INF:
-        q, t, slot, typ, a = _jit_cal_pop(q, cap, jnp.float32(width))
+    while float(q["evq_root"][0]) < INF:
+        q, t, slot, typ, a = _jit_pop(q, cap, "calendar", width)
         out.append((float(t), int(slot)))
     return q, out
 
@@ -387,7 +386,7 @@ def _cal_drain(q, cap, width=8.0):
 def test_fused_commit_equals_sequential_pop_then_push(seed):
     """One ``commit`` call (pops + pushes fused into one scatter chain)
     is bitwise the sequential reference: pop each slot with ``pop``,
-    then ``bulk_push`` the batch — tree, counters, root mirror and drop
+    then ``bulk_push`` the batch — leaf rows, root mirror and drop
     accounting all identical.  This is the end-of-body contract sim.py
     relies on (DESIGN.md §11)."""
     rng = np.random.default_rng(seed)
@@ -413,7 +412,7 @@ def test_fused_commit_equals_sequential_pop_then_push(seed):
     assert int(qs["dropped"]) == int(qf["dropped"])
     assert np.array_equal(np.asarray(qs["evq_root"]),
                           np.asarray(qf["evq_root"]))
-    _assert_root_and_counters(qf, cap)
+    _assert_root(qf, cap)
 
 
 @given(st.integers(0, 2 ** 31 - 1))
@@ -463,7 +462,7 @@ def test_calendar_pop_order_is_sorted_with_ties():
     expect = sorted((t, s) for s, t in enumerate(times.tolist()))
     assert popped == [(t, s) for t, s in expect]
     assert int(q["dropped"]) == 0
-    assert bool((np.asarray(EQ.cal_leaf_times(q, cap)) >= INF).all())
+    assert bool((np.asarray(_leaves(q, cap, "calendar")[0]) >= INF).all())
 
 
 def test_calendar_drop_parity_with_tree():
@@ -474,7 +473,7 @@ def test_calendar_drop_parity_with_tree():
                    np.arange(1.0, 7.0).astype(np.float32), cap=cap)
     qc = _cal_push(qc, np.arange(10.0, 15.0).astype(np.float32), cap=cap)
     assert int(qc["dropped"]) == 3
-    ev = np.asarray(EQ.cal_leaf_times(qc, cap))
+    ev = np.asarray(_leaves(qc, cap, "calendar")[0])
     assert float(ev[6]) == 10.0 and float(ev[7]) == 11.0
     qc = _cal_push(qc, np.array([99.0], np.float32), cap=cap)
     assert int(qc["dropped"]) == 4
@@ -503,49 +502,108 @@ def test_calendar_interleaved_matches_tree(seed):
             if not live:
                 break
             qt, tt, ts, _, _ = _jit_pop(qt, cap)
-            qc, ct, cs, _, _ = _jit_cal_pop(qc, cap, jnp.float32(8.0))
+            qc, ct, cs, _, _ = _jit_pop(qc, cap, "calendar", 8.0)
             assert (float(tt), int(ts)) == (float(ct), int(cs))
             live -= 1
         # the evq_root mirror is the contract sim.py's cond/body read —
         # it must equal the owner array's root row after every op
-        _assert_root_and_counters(qt, cap)
+        _assert_root(qt, cap)
         assert np.array_equal(np.asarray(qc["evq_root"]),
                               np.asarray(qc["evq_cal"])[0])
 
 
-def test_hier_super_counter_alloc_matches_flat(monkeypatch):
-    """Force the super-counter allocation path (normally only active at
-    S >= HIER_MIN_SEGS segments) on a small queue and check it lands
-    pushes on exactly the slots the flat-cumsum path picks."""
-    cap = 1024                              # s=16 ALLOC_SEG=64 segments
+def _seam_trip(qu, bp, cap, rx):
+    """One simulator trip through the seam alone, as ``sim.py``'s body
+    makes it: read the root, take the same-time BEACON_RX cohort when
+    the root is one (``bp`` > 1), then commit the pops and a push batch."""
+    def trip(st, mask, times, typ, a0):
+        t, slot, rtyp, a = qu.root(st)
+        if bp > 1:
+            lt, lpay = qu.leaves(st)
+            slots, ok = EQ.batch_take(lt, lpay[:, 0], t, slot, rx, bp)
+            ok = jnp.where(rtyp == rx, ok, jnp.arange(bp) < 1)
+        else:
+            slots, ok = slot[None], jnp.ones((1,), bool)
+        z = jnp.zeros_like(a0)
+        st = qu.commit(st, slots, ok, mask, times, typ, a0, z, z)
+        return st, t, slots, ok, rtyp, a[0]
+    return jax.jit(trip)
 
-    def push_raw(q, times, mask=None):
-        # un-jitted: each call re-traces, reading the monkeypatched
-        # HIER_MIN_SEGS (the jitted _push would serve a cached program)
-        n = len(times)
-        m = jnp.ones((n,), bool) if mask is None else jnp.asarray(mask, bool)
-        z = jnp.zeros((n,), jnp.int32)
-        return EQ.bulk_push(q, m, jnp.asarray(times, jnp.float32), 1,
-                            z, z, z, cap)
 
-    def build(q):
-        rng = np.random.default_rng(5)
-        q = push_raw(q, rng.uniform(1, 1e6, 900).astype(np.float32))
-        for _ in range(200):                # scatter frees across segments
-            q, _, _, _, _ = _jit_pop(q, cap)
-        return push_raw(q, rng.uniform(1, 1e6, 300).astype(np.float32),
-                        mask=rng.random(300) < 0.6)
+@pytest.mark.parametrize("impl,bp", [(i, b) for i in EQ.QUEUE_IMPLS
+                                     for b in (1, 64)])
+def test_queue_seam_matches_heap(impl, bp):
+    """Interleaved push batches and trips, driven through the ``Queue``
+    functions only, against a ``heapq`` model: each trip pops the
+    lexmin (time, slot) event with its payload, or, when that is a
+    BEACON_RX and ``bp`` > 1, the run of same-time BEACON_RX events a
+    singleton loop would pop next (at most ``bp``); the j-th masked push
+    takes the j-th lowest free slot, an INF-timed one leaves it free,
+    and pushes past the free slots drop."""
+    cap, rx, n = 96, 3, 24
+    qu = EQ.resolve(impl, cap, 4.0)
+    rng = np.random.default_rng(bp)
+    trip = _seam_trip(qu, bp, cap, rx)
+    z = jnp.zeros((n,), jnp.int32)
 
-    monkeypatch.setattr(EQ, "HIER_MIN_SEGS", 1)
-    qh = build(EQ.empty(cap))
-    monkeypatch.setattr(EQ, "HIER_MIN_SEGS", 10 ** 9)
-    qf = build(EQ.empty(cap))               # flat-cumsum reference
-    assert np.array_equal(np.asarray(qh["evq_tree"]),
-                          np.asarray(qf["evq_tree"]))
+    def push(st, mask, times, typ, a0):
+        return qu.bulk_push(st, mask, times, typ, a0, z, z)
+
+    push = jax.jit(push)
+    st = {"dropped": jnp.zeros((), jnp.int32)} | qu.state()
+    assert set(qu.held) <= set(st)
+    heap, payload, dropped = [], {}, 0
+    widths = []
+
+    def batch(now):
+        mask = rng.random(n) < 0.7
+        times = (now + rng.integers(0, 6, n)).astype(np.float32)
+        times[rng.random(n) < 0.05] = INF
+        typ = np.where(rng.random(n) < 0.6, rx,
+                       rng.integers(0, 3, n)).astype(np.int32)
+        a0 = rng.integers(0, 1000, n).astype(np.int32)
+        return mask, times, typ, a0
+
+    def model_push(mask, times, typ, a0):
+        taken = {s for _, s in heap}
+        free = [s for s in range(cap) if s not in taken]
+        for j, i in enumerate(np.flatnonzero(mask)):
+            if j < len(free) and times[i] < INF:
+                heapq.heappush(heap, (float(times[i]), free[j]))
+                payload[free[j]] = (int(typ[i]), int(a0[i]))
+        return max(int(mask.sum()) - len(free), 0)
+
+    now = 0.0
+    for _ in range(8):
+        args = batch(now)
+        st = push(st, *map(jnp.asarray, args))
+        dropped += model_push(*args)
+        assert int(qu.live(st)) == bool(heap)
+        for _ in range(int(rng.integers(1, 8))):
+            if not heap:
+                break
+            t0, s0 = heapq.heappop(heap)
+            cohort = [s0]
+            while bp > 1 and payload[s0][0] == rx and heap \
+                    and len(cohort) < bp and heap[0][0] == t0 \
+                    and payload[heap[0][1]][0] == rx:
+                cohort.append(heapq.heappop(heap)[1])
+            widths.append(len(cohort))
+            now = t0
+            args = batch(now)
+            st, t, slots, ok, rtyp, ra0 = trip(st, *map(jnp.asarray, args))
+            assert (float(t), int(np.asarray(slots)[0])) == (t0, s0)
+            assert (int(rtyp), int(ra0)) == payload[s0]
+            assert np.asarray(slots)[np.asarray(ok)].tolist() == cohort
+            dropped += model_push(*args)
+            assert int(st["dropped"]) == dropped
+    assert int(st["dropped"]) == dropped > 0
+    assert max(widths) > 1 if bp > 1 else max(widths) == 1
 
 
 @pytest.mark.parametrize("qi,bp", [("tree", 8), ("calendar", 1),
-                                   ("calendar", 8)])
+                                   ("calendar", 8), ("tree", 64),
+                                   ("linear", 64)])
 def test_queue_impl_and_batch_pop_match_linear_bitwise(qi, bp):
     """Full-sim gate: every queue_impl x batch_pop point is bitwise the
     linear singleton baseline on a non-ideal fabric (the tie-heavy
@@ -569,8 +627,7 @@ def test_k256_shape_cohort_commits_match_host_model():
     batch (a 255-wide fan-out plus handler pushes) in each commit.  After
     every commit the leaf times equal a host model of the queue (pops
     free their slots, the j-th pushed entry takes the j-th lowest free
-    slot), the root is the first-index argmin row, and the counters
-    match the leaves."""
+    slot), and the root is the first-index argmin row."""
     rng = np.random.default_rng(11)
     cap, bp, n, rx = 32768, 64, 356, 3
     commit = jax.jit(EQ.commit, static_argnums=9)
@@ -582,8 +639,9 @@ def test_k256_shape_cohort_commits_match_host_model():
     for _ in range(6):
         root = np.asarray(q["evq_root"])
         if root[0] < INF:
+            lt, lpay = _leaves(q, cap)
             slots, ok = EQ.batch_take(
-                EQ.leaf_times(q, cap), EQ.leaf_payloads(q, cap)[:, 0],
+                lt, lpay[:, 0],
                 jnp.float32(root[0]), jnp.int32(root[1]), rx, bp)
             t_now = float(root[0])
         else:
@@ -613,9 +671,9 @@ def test_k256_shape_cohort_commits_match_host_model():
         model[land] = times[mask]
         typs[land] = ptyp[mask]
         assert np.array_equal(_times(q, cap), model)
-        assert np.array_equal(np.asarray(EQ.leaf_payloads(q, cap))[:, 0]
+        assert np.array_equal(np.asarray(_leaves(q, cap)[1])[:, 0]
                               [model < INF], typs[model < INF])
-        _assert_root_and_counters(q, cap)
+        _assert_root(q, cap)
     assert widths.count(bp) >= 3, widths
     assert int(q["dropped"]) == 0
 

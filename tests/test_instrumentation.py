@@ -158,7 +158,8 @@ def test_iterations_equal_the_reference(bp, dn_th, seed):
 
 
 @pytest.mark.parametrize("qi,bp", [("tree", 1), ("tree", 8),
-                                   ("calendar", 8), ("linear", 8)])
+                                   ("calendar", 8), ("linear", 8),
+                                   ("tree", 64)])
 def test_iterations_bounded_by_events(qi, bp):
     """A trip retires at least one event and at most batch_pop, so
     ceil(events / batch_pop) <= iterations <= events, per lane."""
